@@ -212,6 +212,13 @@ def init_orca_context(cluster_mode: str = "local",
     _sanitize_host_env()
     import jax
 
+    # before this process's first compile, so every jit after it —
+    # module.init included — lands in the persistent cache
+    from analytics_zoo_tpu.common.compile_ahead import (
+        configure_persistent_cache,
+    )
+    configure_persistent_cache()
+
     if cluster_mode in ("multihost", "tpu_pod"):
         if coordinator_address:
             jax.distributed.initialize(

@@ -46,18 +46,16 @@ def _as_tuple(x):
 def _warm_many_async(todo):
     """Daemon thread warming ``(cache, avals)`` pairs — warm_decode's
     grid may span the plain and the paged executable caches. Smallest
-    first, same as ``ExecutableCache.warm_async``; a failed build must
-    not kill the thread (the shape just compiles in-band later)."""
+    first, same as ``ExecutableCache.warm_async``; a failed build does
+    not kill the thread (``warm`` logs and counts it on the cache, and
+    the shape compiles in-band later)."""
     def size(item):
         _, avals = item
         return int(np.prod(avals[1].shape)) if len(avals) > 1 else 0
 
     def work():
         for cache, avals in sorted(todo, key=size):
-            try:
-                cache.warm(*avals)
-            except Exception:
-                pass
+            cache.warm(*avals)
 
     t = threading.Thread(target=work, name="zoo-warm-decode", daemon=True)
     t.start()
@@ -68,6 +66,8 @@ class InferenceModel:
     """Thread-safe inference holder with a jitted-executable cache."""
 
     def __init__(self, concurrent_num: int = 1):
+        # before the first compile: a loader's module.init is cached too
+        compile_ahead.configure_persistent_cache()
         self.concurrent_num = int(concurrent_num)
         self._sem = threading.Semaphore(self.concurrent_num)
         self._lock = threading.Lock()
@@ -365,28 +365,12 @@ class InferenceModel:
         return self
 
     def _aot_avals(self, params, spec, rung):
-        import jax
-
         with self._lock:
             sharded = self._sharded
-
-        def aval(a):
-            # carry the leaf's sharding: an AOT build lowered without it
-            # compiles a different executable than the live dispatch
-            # needs, so the "warm" rung silently recompiles on first use
-            sh = getattr(a, "sharding", None)
-            if sh is not None:
-                try:
-                    return jax.ShapeDtypeStruct(tuple(a.shape), a.dtype,
-                                                sharding=sh)
-                except TypeError:       # older jax: no sharding kwarg
-                    pass
-            if hasattr(a, "shape") and hasattr(a, "dtype"):
-                return jax.ShapeDtypeStruct(tuple(a.shape), a.dtype)
-            arr = np.asarray(a)
-            return jax.ShapeDtypeStruct(arr.shape, arr.dtype)
-
-        p_avals = jax.tree_util.tree_map(aval, params)
+        # the avals carry each leaf's sharding: an AOT build lowered
+        # without it compiles a different executable than the live
+        # dispatch needs, so the "warm" rung would recompile on first use
+        p_avals = compile_ahead.tree_avals(params)
         if sharded is not None:
             return (p_avals,) + sharded.batch_avals(spec, rung)
         return (p_avals,) + compile_ahead.batch_avals(spec, rung)

@@ -654,10 +654,10 @@ class JaxEstimator:
             # plain jit path handles the first window instead
             logger.debug("step precompile skipped: streaming dataset")
             return None
-        compile_ahead.configure_persistent_cache()
         bs = int(batch_size)
 
         def batched(extra_lead):
+            from jax.sharding import NamedSharding, PartitionSpec as P
             mesh = self._ensure_mesh()
 
             def f(a):
@@ -672,18 +672,11 @@ class JaxEstimator:
                 # without that sharding lowers a different executable,
                 # so the "precompiled" step silently recompiles on its
                 # first real batch
-                try:
-                    from jax.sharding import (
-                        NamedSharding, PartitionSpec as P,
-                    )
-                    base = self.strategy.batch_spec(
-                        len(shp) - len(extra_lead))
-                    spec = P(*([None] * len(extra_lead)), *base) \
-                        if extra_lead else base
-                    return jax.ShapeDtypeStruct(
-                        shp, dtype, sharding=NamedSharding(mesh, spec))
-                except TypeError:   # older jax: no sharding kwarg
-                    return jax.ShapeDtypeStruct(shp, dtype)
+                base = self.strategy.batch_spec(len(shp) - len(extra_lead))
+                spec = P(*([None] * len(extra_lead)), *base) \
+                    if extra_lead else base
+                return jax.ShapeDtypeStruct(
+                    shp, dtype, sharding=NamedSharding(mesh, spec))
             return f
 
         def state_avals(with_sharding: bool):
@@ -691,11 +684,8 @@ class JaxEstimator:
                 if with_sharding:
                     sh = getattr(a, "sharding", None)
                     if sh is not None:
-                        try:
-                            return jax.ShapeDtypeStruct(
-                                a.shape, a.dtype, sharding=sh)
-                        except TypeError:  # older jax: no sharding kwarg
-                            pass
+                        return jax.ShapeDtypeStruct(
+                            a.shape, a.dtype, sharding=sh)
                 arr = a if hasattr(a, "shape") else np.asarray(a)
                 return jax.ShapeDtypeStruct(
                     tuple(arr.shape), arr.dtype)

@@ -102,13 +102,13 @@ def embedding_bag_ragged(table, flat_ids, offsets, mode: str = "sum"):
 def _fused_lookup_kernel(ids_ref, *refs, dims: Tuple[int, ...],
                          combine: str):
     # refs = (row_ref per table ..., o_ref); each row_ref holds the ONE
-    # [1, d_t] row the index_map below DMA'd for this batch element
+    # [1, 1, d_t] row the index_map below DMA'd for this batch element
     o_ref = refs[-1]
-    rows = [refs[t][...] for t in range(len(dims))]
+    rows = [refs[t][0] for t in range(len(dims))]             # [1, d_t]
     if combine == "concat":
         off = 0
         for d_t, row in zip(dims, rows):
-            o_ref[0, off:off + d_t] = row[0].astype(o_ref.dtype)
+            o_ref[0, :, off:off + d_t] = row.astype(o_ref.dtype)
             off += d_t
         return
     acc = rows[0].astype(jnp.float32)
@@ -119,8 +119,14 @@ def _fused_lookup_kernel(ids_ref, *refs, dims: Tuple[int, ...],
             acc = acc + row.astype(jnp.float32)
     if combine == "mean":
         acc = acc * jnp.float32(1.0 / len(dims))  # see _fused_ref
-    o_ref[...] = acc.astype(o_ref.dtype)
+    o_ref[0] = acc.astype(o_ref.dtype)
 
+
+# Rows ride as [n, 1, d] arrays with [1, 1, d] blocks: a [1, d] block of a
+# 2-D array is not a legal TPU tile, a block whose last two dims are the
+# whole array's is. The prefetched id matrix goes in batch-minor: SMEM pads
+# the last dim to 128 words, so [batch, n] ids at NCF's batch of 8000 would
+# take 4 MB of the 1 MB there is.
 
 def _fused_pallas(tables, ids, combine: str):
     import jax.experimental.pallas as pl
@@ -135,21 +141,22 @@ def _fused_pallas(tables, ids, combine: str):
         # the scalar-prefetched id matrix drives the DMA: grid step b
         # pulls row ids[b, t] of table t — a gather executed by the
         # pipeline, not by kernel-body loads
-        return pl.BlockSpec((1, d_t), lambda b, ids_ref, _t=t: (
-            ids_ref[b, _t], 0))
+        return pl.BlockSpec((1, 1, d_t), lambda b, ids_ref, _t=t: (
+            ids_ref[_t, b], 0, 0))
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(batch,),
         in_specs=[row_spec(t, d_t) for t, d_t in enumerate(dims)],
-        out_specs=pl.BlockSpec((1, d_out), lambda b, ids_ref: (b, 0)),
+        out_specs=pl.BlockSpec((1, 1, d_out), lambda b, ids_ref: (b, 0, 0)),
     )
-    return pl.pallas_call(
+    out = pl.pallas_call(
         functools.partial(_fused_lookup_kernel, dims=dims, combine=combine),
-        out_shape=jax.ShapeDtypeStruct((batch, d_out), tables[0].dtype),
+        out_shape=jax.ShapeDtypeStruct((batch, 1, d_out), tables[0].dtype),
         grid_spec=grid_spec,
         **_interp_kw(),
-    )(ids, *tables)
+    )(ids.T, *(t[:, None, :] for t in tables))
+    return out[:, 0, :]
 
 
 def _bag_kernel(ids_ref, len_ref, row_ref, o_ref, acc_ref, *, bag: int,
@@ -164,14 +171,14 @@ def _bag_kernel(ids_ref, len_ref, row_ref, o_ref, acc_ref, *, bag: int,
 
     @pl.when(l < len_ref[b])
     def _accum():
-        acc_ref[...] += row_ref[...].astype(jnp.float32)
+        acc_ref[...] += row_ref[0].astype(jnp.float32)
 
     @pl.when(l == bag - 1)
     def _flush():
         acc = acc_ref[...]
         if mean:
             acc = acc / jnp.maximum(len_ref[b], 1).astype(jnp.float32)
-        o_ref[...] = acc.astype(o_ref.dtype)
+        o_ref[0] = acc.astype(o_ref.dtype)
 
 
 def _bag_pallas(table, ids, lengths, mean: bool):
@@ -184,18 +191,19 @@ def _bag_pallas(table, ids, lengths, mean: bool):
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(batch, bag),
-        in_specs=[pl.BlockSpec((1, d), lambda b, l, ids_ref, len_ref: (
-            ids_ref[b, l], 0))],
-        out_specs=pl.BlockSpec((1, d), lambda b, l, ids_ref, len_ref: (
-            b, 0)),
+        in_specs=[pl.BlockSpec((1, 1, d), lambda b, l, ids_ref, len_ref: (
+            ids_ref[l, b], 0, 0))],
+        out_specs=pl.BlockSpec((1, 1, d), lambda b, l, ids_ref, len_ref: (
+            b, 0, 0)),
         scratch_shapes=[pltpu.VMEM((1, d), jnp.float32)],
     )
-    return pl.pallas_call(
+    out = pl.pallas_call(
         functools.partial(_bag_kernel, bag=bag, mean=mean),
-        out_shape=jax.ShapeDtypeStruct((batch, d), table.dtype),
+        out_shape=jax.ShapeDtypeStruct((batch, 1, d), table.dtype),
         grid_spec=grid_spec,
         **_interp_kw(),
-    )(ids, lengths, table)
+    )(ids.T, lengths, table[:, None, :])
+    return out[:, 0, :]
 
 
 # ------------------------------------------------------------- custom VJPs

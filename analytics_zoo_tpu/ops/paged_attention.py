@@ -182,8 +182,8 @@ def _attn_kernel(tbl_ref, len_ref, ks_ref, vs_ref, q_ref, k_ref, v_ref,
     @pl.when(p == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
-        m_ref[0, 0] = NEG_INF
-        l_ref[0, 0] = 0.0
+        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+        l_ref[...] = jnp.zeros_like(l_ref)
 
     k = k_ref[0].astype(jnp.float32)                            # [ps, d]
     v = v_ref[0].astype(jnp.float32)
@@ -191,30 +191,30 @@ def _attn_kernel(tbl_ref, len_ref, ks_ref, vs_ref, q_ref, k_ref, v_ref,
         page = tbl_ref[b, p]
         k = k * ks_ref[page]                 # dequant fused in-loop
         v = v * vs_ref[page]
-    q = q_ref[...].astype(jnp.float32)                          # [1, d]
-    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32)  # [1, ps]
-    s = s * softmax_scale
-    pos = p * page_size + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+    q = q_ref[0].astype(jnp.float32)                            # [1, d]
+    # one query row per sequence: the scores are a multiply and a lane
+    # reduction, not a one-row matmul. Everything stays 2-D — scores and
+    # weights are [ps, 1] columns, the running max and sum [1, 1]
+    s = jnp.sum(k * q, axis=1, keepdims=True) * softmax_scale   # [ps, 1]
+    pos = p * page_size + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
     live = pos < len_ref[b]
     s = jnp.where(live, s, NEG_INF)
-    m_prev = m_ref[0, 0]
-    m_cur = jnp.maximum(m_prev, jnp.max(s))
+    m_prev = m_ref[...]
+    m_cur = jnp.maximum(m_prev, jnp.max(s, axis=0, keepdims=True))
     alpha = jnp.exp(m_prev - m_cur)
     # explicit zero at masked slots: a fully-masked (recycled/padded) page
     # contributes nothing — exp(NEG_INF - NEG_INF) would be 1, not 0
-    w = jnp.where(live, jnp.exp(s - m_cur), 0.0)                # [1, ps]
-    m_ref[0, 0] = m_cur
-    l_ref[0, 0] = l_ref[0, 0] * alpha + jnp.sum(w)
-    acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
-        w, v, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
+    w = jnp.where(live, jnp.exp(s - m_cur), 0.0)                # [ps, 1]
+    m_ref[...] = m_cur
+    l_ref[...] = l_ref[...] * alpha + jnp.sum(w, axis=0, keepdims=True)
+    acc_ref[...] = acc_ref[...] * alpha + jnp.sum(
+        w * v, axis=0, keepdims=True)                           # [1, d]
 
     @pl.when(p == width - 1)
     def _flush():
-        l = l_ref[0, 0]
-        o_ref[...] = (acc_ref[...]
-                      / jnp.where(l == 0.0, 1.0, l)).astype(o_ref.dtype)
+        l = l_ref[...]
+        o_ref[0] = (acc_ref[...]
+                    / jnp.where(l == 0.0, 1.0, l)).astype(o_ref.dtype)
 
 
 def _attn_pallas(q, k_pool, v_pool, table, lengths, k_scales, v_scales,
@@ -226,29 +226,30 @@ def _attn_pallas(q, k_pool, v_pool, table, lengths, k_scales, v_scales,
     ps, d = int(k_pool.shape[1]), int(k_pool.shape[2])
     page_spec = pl.BlockSpec(
         (1, ps, d), lambda b, p, tbl, ln, ks, vs: (tbl[b, p], 0, 0))
+    # q and the output ride as [batch, 1, d]: a [1, d] block of a 2-D
+    # array is not a legal TPU tile, a [1, 1, d] block whose last two
+    # dims are the whole array's is
+    row_spec = pl.BlockSpec((1, 1, d),
+                            lambda b, p, tbl, ln, ks, vs: (b, 0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=4,
         grid=(batch, width),
-        in_specs=[
-            pl.BlockSpec((1, d), lambda b, p, tbl, ln, ks, vs: (b, 0)),
-            page_spec,
-            page_spec,
-        ],
-        out_specs=pl.BlockSpec((1, d),
-                               lambda b, p, tbl, ln, ks, vs: (b, 0)),
+        in_specs=[row_spec, page_spec, page_spec],
+        out_specs=row_spec,
         scratch_shapes=[
             pltpu.VMEM((1, d), jnp.float32),
-            pltpu.SMEM((1, 1), jnp.float32),
-            pltpu.SMEM((1, 1), jnp.float32),
+            pltpu.VMEM((1, 1), jnp.float32),
+            pltpu.VMEM((1, 1), jnp.float32),
         ],
     )
-    return pl.pallas_call(
+    out = pl.pallas_call(
         functools.partial(_attn_kernel, page_size=ps,
                           softmax_scale=softmax_scale, quantized=quantized),
-        out_shape=jax.ShapeDtypeStruct((batch, d), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((batch, 1, d), jnp.float32),
         grid_spec=grid_spec,
         **_interp_kw(),
-    )(table, lengths, k_scales, v_scales, q, k_pool, v_pool)
+    )(table, lengths, k_scales, v_scales, q[:, None, :], k_pool, v_pool)
+    return out[:, 0, :]
 
 
 def paged_attention_ref(q, k_pool, v_pool, table, lengths, *,
@@ -370,8 +371,9 @@ def tune_paged_gather(batch: int, width: int, page_size: int, dim: int,
                       n_pages: int, dtype=jnp.float32,
                       iters: Optional[int] = None) -> dict:
     """Synchronously tune the gather kernel vs the pure-jax reference on
-    synthetic data at one shape; persists the verdict. Safe anywhere:
-    where the kernel cannot build, the verdict is "reference"."""
+    synthetic data at one shape; persists the verdict. Off the TPU the
+    kernel cannot build and the (unpersisted) verdict is "reference"; on
+    the TPU a kernel that fails to build raises."""
     from analytics_zoo_tpu.ops import autotune
     pool, table, lengths, scales = _synth_args(
         batch, width, page_size, dim, n_pages, dtype)

@@ -160,10 +160,6 @@ def ring_attention(q, k, v, mesh=None, axis_name: str = mesh_lib.SEQ_AXIS,
     disqualifies a shape anymore; the remaining blockwise fallbacks are
     economic (tiny local blocks), not correctness limits.
     """
-    # cross-version shard_map (jax >= 0.8 top-level with check_vma,
-    # older jax under experimental with check_rep)
-    from analytics_zoo_tpu.parallel.pipeline import _shard_map
-
     if mesh is None:
         mesh = mesh_lib.get_default_mesh()
     axes = dict(zip(mesh.axis_names, mesh.devices.shape))
@@ -185,5 +181,5 @@ def ring_attention(q, k, v, mesh=None, axis_name: str = mesh_lib.SEQ_AXIS,
     else:
         fn = functools.partial(_ring_attention_local, axis_name=axis_name,
                                causal=causal, n_shards=p)
-    return _shard_map()(fn, mesh=mesh, in_specs=(spec, spec, spec),
-                        out_specs=spec)(q, k, v)
+    return jax.shard_map(fn, mesh=mesh, in_specs=(spec, spec, spec),
+                         out_specs=spec, check_vma=False)(q, k, v)

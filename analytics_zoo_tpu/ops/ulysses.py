@@ -26,7 +26,6 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from analytics_zoo_tpu.parallel import mesh as mesh_lib
-from analytics_zoo_tpu.parallel.pipeline import _shard_map
 
 
 def _attention(q, k, v, causal: bool):
@@ -77,7 +76,7 @@ def ulysses_attention(q, k, v, *, mesh=None, causal: bool = False,
         use_flash = default_use_flash(s, d)
 
     spec = P(batch_axis, axis, None, None)
-    smap = _shard_map()
+    smap = partial(jax.shard_map, check_vma=False)
 
     @partial(smap, mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec)
     def run(q_loc, k_loc, v_loc):

@@ -24,24 +24,6 @@ import numpy as np
 from analytics_zoo_tpu.parallel import mesh as mesh_lib
 
 
-def _shard_map():
-    """shard_map with the replication check disabled, across jax versions
-    (jax >= 0.8 renamed check_rep → check_vma; older jax keeps it under
-    experimental)."""
-    import inspect
-    try:
-        from jax import shard_map as smap
-    except ImportError:  # pragma: no cover - older jax
-        from jax.experimental.shard_map import shard_map as smap
-    sig = inspect.signature(smap).parameters
-    kw = {}
-    if "check_rep" in sig:
-        kw["check_rep"] = False
-    elif "check_vma" in sig:
-        kw["check_vma"] = False
-    return partial(smap, **kw)
-
-
 def _batch_layout(mesh, axis, batch: int, n_microbatches: int):
     """(pipe size S, dp size, batch spec axis, microbatch rows mb); raises
     when the batch does not divide over microbatches × dp."""
@@ -80,7 +62,7 @@ def gpipe(stage_fn: Callable, stacked_params, x, *, mesh=None,
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
 
-    shard_map = _shard_map()
+    shard_map = partial(jax.shard_map, check_vma=False)
     if mesh is None:
         mesh = mesh_lib.get_default_mesh()
     if mesh_lib.mesh_axis_size(mesh, axis) < 2:
@@ -185,7 +167,7 @@ def gpipe_hetero(stage_fns, unravels, sizes, packed, feed, *, mesh=None,
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
 
-    smap = _shard_map()
+    smap = partial(jax.shard_map, check_vma=False)
     if mesh is None:
         mesh = mesh_lib.get_default_mesh()
     if mesh_lib.mesh_axis_size(mesh, axis) != len(stage_fns):

@@ -85,19 +85,7 @@ class ShardedExecutable:
     def param_avals(self):
         """Params as avals that carry their shardings, so an AOT build
         lowers to exactly the executable the live dispatch needs."""
-        import jax
-
-        def aval(a):
-            sh = getattr(a, "sharding", None)
-            if sh is not None:
-                try:
-                    return jax.ShapeDtypeStruct(a.shape, a.dtype,
-                                                sharding=sh)
-                except TypeError:       # older jax: no sharding kwarg
-                    pass
-            return jax.ShapeDtypeStruct(tuple(a.shape), a.dtype)
-
-        return jax.tree_util.tree_map(aval, self.params)
+        return compile_ahead.tree_avals(self.params)
 
     def batch_avals(self, spec: Sequence[Tuple], rung: int):
         """Batch avals for one ladder rung, carrying the strategy's

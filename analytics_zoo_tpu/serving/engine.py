@@ -17,9 +17,9 @@ Per-stage latency stats mirror serving ``Timer.scala:26``.
 The serve loop is a produce → staged-dispatch → drain pipeline
 (common/pipeline_io.py): dequeue/decode/preprocess of batch N+1 overlaps
 batch N's device compute through a bounded in-flight window, and results
-are only fetched when the window is full or the stream idles — round-5
-on-chip profiling showed the synchronous loop left the accelerator idle
-during every broker round-trip (VERDICT.md weak #5/#7).
+are only fetched when the window is full or the stream idles — a
+synchronous loop leaves the accelerator idle during every broker
+round-trip.
 """
 
 from __future__ import annotations
@@ -139,10 +139,9 @@ class ClusterServing:
     after sustained idle (defaults to ``batch_size``: no shrinking).
 
     ``warmup``: AOT-compile the whole bucket ladder on a background
-    thread at ``start()`` (and wire the persistent compile cache), so a
-    backlog-driven bucket change is a stall-free swap to an
-    already-compiled rung instead of an in-band XLA compile on the serve
-    thread. On by default for models that support it (InferenceModel);
+    thread at ``start()``, so a backlog-driven bucket change is a
+    stall-free swap to an already-compiled rung instead of an in-band XLA
+    compile on the serve thread. On by default for models that support it (InferenceModel);
     ``ZOO_WARMUP_BUCKETS=0`` disables it process-wide, any other integer
     caps how many rungs (smallest first) are warmed.
 
@@ -227,6 +226,9 @@ class ClusterServing:
                  warmup: bool = True,
                  replica_id: Optional[str] = None,
                  draft_model=None, spec_k: int = 4):
+        # before this process's first compile (a duck-typed model never
+        # went through InferenceModel's constructor)
+        compile_ahead.configure_persistent_cache()
         self.model = model
         self.batch_size = int(batch_size)
         self.pipeline_window = int(pipeline_window)
@@ -1625,9 +1627,8 @@ class ClusterServing:
                 self._supervisor = sup
             sup.ensure_started()
         if self._warmup_enabled:
-            # persistent XLA cache + background AOT over the whole ladder:
-            # the serve thread then swaps buckets without ever compiling
-            compile_ahead.configure_persistent_cache()
+            # background AOT over the whole ladder: the serve thread then
+            # swaps buckets without ever compiling
             self._kick_warmup()
         self._stop.clear()
         self._thread = threading.Thread(target=self._run, daemon=True)

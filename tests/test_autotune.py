@@ -83,6 +83,65 @@ def test_tune_records_candidate_errors(tuner_env):
     assert rec2["best_ms"] is None
 
 
+def test_errored_verdict_stays_in_the_process(tuner_env):
+    """A verdict that carries an error serves this process (no re-measure
+    per dispatch) but never reaches the file: on disk it would read as a
+    measured "reference wins" for every later process."""
+    def broken(x):
+        raise RuntimeError("cannot build here")
+
+    tuner = autotune.get_tuner()
+    rec = tuner.tune("demo", "demo|errored", {"broken": broken}, _light,
+                     (X,), iters=2)
+    assert rec["errors"] and tuner.lookup("demo|errored") == rec
+    assert not os.path.exists(tuner_env)
+    clean = tuner.tune("demo", "demo|clean", {"light": _light}, _heavy,
+                       (X,), iters=2)
+    with open(tuner_env) as fh:
+        on_disk = json.load(fh)
+    assert on_disk == {"demo|clean": clean}     # the errored one filtered
+    autotune.reset_tuner()                      # "next process"
+    assert autotune.get_tuner().lookup("demo|errored") is None
+    assert autotune.get_tuner().lookup("demo|clean") == clean
+
+
+def test_errored_verdict_on_disk_is_not_loaded(tuner_env):
+    """Files written before errored verdicts were kept off the disk."""
+    with open(tuner_env, "w") as fh:
+        json.dump({"old|bad": {"use_kernel": False,
+                               "errors": {"pallas": "boom"}},
+                   "old|good": {"use_kernel": True, "errors": {}}}, fh)
+    assert autotune.get_tuner().lookup("old|bad") is None
+    assert autotune.get_tuner().lookup("old|good")["use_kernel"] is True
+
+
+def test_failing_candidate_is_reraised_on_a_tpu_backend(tuner_env,
+                                                       monkeypatch, caplog):
+    """On the chip the kernels are meant to compile: a candidate that
+    raises is a bug — logged at error level and re-raised from the sync
+    path, with nothing recorded — never a quiet "reference wins"."""
+    import logging
+
+    def broken(x):
+        raise RuntimeError("mosaic refused the kernel")
+
+    monkeypatch.setattr(autotune, "on_tpu", lambda: True)
+    tuner = autotune.get_tuner()
+    with caplog.at_level(logging.ERROR, logger=autotune.logger.name):
+        with pytest.raises(RuntimeError, match="mosaic refused"):
+            tuner.tune("demo", "demo|tpu", {"broken": broken}, _light,
+                       (X,), iters=2)
+        with pytest.raises(RuntimeError, match="mosaic refused"):
+            tuner.tune_thunks("demo", "demo|tpu_thunk",
+                              {"broken": lambda: broken(None)},
+                              lambda: np.zeros(2), iters=2)
+    assert tuner.lookup("demo|tpu") is None
+    assert tuner.lookup("demo|tpu_thunk") is None
+    assert not os.path.exists(tuner_env)
+    logged = [r for r in caplog.records if r.levelno == logging.ERROR]
+    assert len(logged) == 2 and all(r.exc_info for r in logged)
+
+
 # ------------------------------------------------------- host-thunk timing
 
 def test_tune_thunks_times_host_callables(tuner_env):
@@ -162,6 +221,22 @@ def test_pending_thunk_failure_is_contained(tuner_env):
     autotune.enqueue_tune("q|boom", boom)
     assert autotune.tune_pending() == 1         # no raise
     assert autotune.pending_count() == 0
+
+
+def test_pending_thunk_failure_is_logged_with_its_traceback(tuner_env,
+                                                            caplog):
+    """The warmup worker survives a failed measurement, but not in
+    silence."""
+    import logging
+
+    def boom():
+        raise RuntimeError("tuning exploded")
+
+    autotune.enqueue_tune("q|loud", boom)
+    with caplog.at_level(logging.ERROR, logger=autotune.logger.name):
+        assert autotune.tune_pending() == 1
+    (rec,) = [r for r in caplog.records if "q|loud" in r.getMessage()]
+    assert rec.exc_info and "tuning exploded" in str(rec.exc_info[1])
 
 
 def test_enqueue_noop_when_off_or_already_cached(tuner_env, monkeypatch):

@@ -81,45 +81,115 @@ def test_batch_avals():
     assert [a.dtype for a in avals] == [np.float32, np.int32]
 
 
-# -------------------------------------------------- persistent cache latch
-def test_persistent_cache_latch_and_disable(tmp_path):
+# ----------------------------------------------- persistent cache placement
+@pytest.fixture
+def cache_config(monkeypatch):
+    """A clean slate for the cache-placement tests: no env var, no config
+    value, latch reset — and the session's own setting restored after."""
     import jax
-    old = getattr(jax.config, "jax_compilation_cache_dir", None)
-    try:
-        jax.config.update("jax_compilation_cache_dir", None)
-        compile_ahead._reset_cache_config_for_tests()
-        target = str(tmp_path / "xla_cache")
-        got = configure_persistent_cache(target)
-        assert got == target and os.path.isdir(target)
-        # latched: a second call with a different path is a no-op
-        assert configure_persistent_cache(str(tmp_path / "other")) == target
-        assert getattr(jax.config, "jax_compilation_cache_dir") == target
-
-        compile_ahead._reset_cache_config_for_tests()
-        jax.config.update("jax_compilation_cache_dir", None)
-        assert configure_persistent_cache("off") is None
-        assert getattr(jax.config, "jax_compilation_cache_dir", None) is None
-    finally:
-        jax.config.update("jax_compilation_cache_dir", old)
-        compile_ahead._reset_cache_config_for_tests()
-        if old:
-            configure_persistent_cache(old)
+    old = jax.config.jax_compilation_cache_dir
+    thresholds = (jax.config.jax_persistent_cache_min_compile_time_secs,
+                  jax.config.jax_persistent_cache_min_entry_size_bytes)
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    jax.config.update("jax_compilation_cache_dir", None)
+    compile_ahead._reset_cache_config_for_tests()
+    yield jax.config
+    jax.config.update("jax_compilation_cache_dir", old)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs",
+                      thresholds[0])
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes",
+                      thresholds[1])
+    compile_ahead._reset_cache_config_for_tests()
 
 
-def test_persistent_cache_respects_existing_config(tmp_path):
-    import jax
-    old = getattr(jax.config, "jax_compilation_cache_dir", None)
-    try:
-        mine = str(tmp_path / "user_cache")
-        jax.config.update("jax_compilation_cache_dir", mine)
-        compile_ahead._reset_cache_config_for_tests()
-        # a user-configured directory is adopted, never overwritten
-        assert configure_persistent_cache(str(tmp_path / "zoo")) == mine
-    finally:
-        jax.config.update("jax_compilation_cache_dir", old)
-        compile_ahead._reset_cache_config_for_tests()
-        if old:
-            configure_persistent_cache(old)
+def test_cache_dir_defaults_to_the_checkout_whatever_the_cwd(
+        cache_config, tmp_path, monkeypatch):
+    """Unset, the cache lands at <checkout>/zoo_tpu_logs/xla_cache resolved
+    from the package's location: the directory is part of the cache key,
+    so a path that follows the cwd never hits."""
+    checkout = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    want = os.path.join(checkout, "zoo_tpu_logs", "xla_cache")
+    monkeypatch.chdir(tmp_path)
+    assert configure_persistent_cache() == want
+    assert cache_config.jax_compilation_cache_dir == want
+    assert os.path.isabs(want) and os.path.isdir(want)
+    assert not os.path.exists(tmp_path / "zoo_tpu_logs")
+    # every entry is kept: the ladder's rungs are small, fast compiles
+    assert cache_config.jax_persistent_cache_min_compile_time_secs == 0.0
+    assert cache_config.jax_persistent_cache_min_entry_size_bytes == 0
+    # latched: later calls answer without touching the config again
+    cache_config.update("jax_compilation_cache_dir", None)
+    assert configure_persistent_cache() == want
+    assert cache_config.jax_compilation_cache_dir is None
+
+
+def test_cache_dir_from_the_environment_is_left_alone(
+        cache_config, tmp_path, monkeypatch):
+    """JAX_COMPILATION_CACHE_DIR set: the program sets no directory and no
+    threshold in code (JAX reads its own variables)."""
+    placed = str(tmp_path / "placed_from_outside")
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", placed)
+    before = (cache_config.jax_compilation_cache_dir,
+              cache_config.jax_persistent_cache_min_compile_time_secs,
+              cache_config.jax_persistent_cache_min_entry_size_bytes)
+    assert configure_persistent_cache() == placed
+    assert (cache_config.jax_compilation_cache_dir,
+            cache_config.jax_persistent_cache_min_compile_time_secs,
+            cache_config.jax_persistent_cache_min_entry_size_bytes) == before
+    assert not os.path.exists(placed)        # not even created here
+
+
+def test_cache_dir_set_in_code_is_left_alone(cache_config, tmp_path):
+    mine = str(tmp_path / "user_cache")
+    cache_config.update("jax_compilation_cache_dir", mine)
+    assert configure_persistent_cache() == mine
+    assert cache_config.jax_compilation_cache_dir == mine
+
+
+def test_no_second_cache_knob_is_left():
+    """ZOO_COMPILE_CACHE is gone (JAX's own variables cover it) and the
+    function takes no path."""
+    import inspect
+    assert not inspect.signature(configure_persistent_cache).parameters
+    with open(compile_ahead.__file__) as fh:
+        assert "ZOO_COMPILE_CACHE" not in fh.read()
+
+
+def test_init_orca_context_configures_the_cache_first(cache_config):
+    """Before the process's first compile, not at the first fit."""
+    from analytics_zoo_tpu import init_orca_context
+    init_orca_context(cluster_mode="local")
+    assert cache_config.jax_compilation_cache_dir == \
+        compile_ahead.DEFAULT_CACHE_DIR
+
+
+def test_inference_model_and_engine_construction_configure_the_cache(
+        cache_config):
+    from analytics_zoo_tpu.inference import InferenceModel
+    from analytics_zoo_tpu.serving import ClusterServing
+    InferenceModel()
+    assert cache_config.jax_compilation_cache_dir == \
+        compile_ahead.DEFAULT_CACHE_DIR
+    cache_config.update("jax_compilation_cache_dir", None)
+    compile_ahead._reset_cache_config_for_tests()
+
+    class Duck:
+        def predict(self, x):
+            return x
+
+    ClusterServing(Duck(), broker_port=1)
+    assert cache_config.jax_compilation_cache_dir == \
+        compile_ahead.DEFAULT_CACHE_DIR
+
+
+def test_log_paths_are_anchored_at_the_checkout():
+    from analytics_zoo_tpu.common import profiling
+    from analytics_zoo_tpu.ops import autotune
+    checkout = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    logs = os.path.join(checkout, "zoo_tpu_logs")
+    assert profiling.DUMP_DIR == logs
+    assert autotune.DEFAULT_CACHE_PATH == os.path.join(logs, "autotune.json")
+    assert compile_ahead.DEFAULT_CACHE_DIR == os.path.join(logs, "xla_cache")
 
 
 # --------------------------------------------------------- executable cache
@@ -180,6 +250,63 @@ def test_cache_falls_back_to_callable_without_lower(orca_ctx):
     import jax
     assert not cache.warm(jax.ShapeDtypeStruct((3,), np.float32))
     assert len(cache) == 0
+
+
+def test_cache_fallbacks_are_counted_and_warned_once(orca_ctx, caplog):
+    """Leaving the AOT path keeps working but is never silent: every such
+    dispatch counts on ``cache.fallbacks``, the first per signature logs a
+    warning with the traceback."""
+    import logging
+    cache = ExecutableCache(lambda x: x * 4.0, name="t_count",
+                            registry=telemetry.MetricsRegistry(),
+                            tracer=telemetry.Tracer())
+    assert cache.fallbacks == 0
+    x = np.ones(3, np.float32)
+    with caplog.at_level(logging.WARNING,
+                         logger=compile_ahead.logger.name):
+        for _ in range(3):
+            np.testing.assert_array_equal(cache(x), x * 4.0)
+        cache(np.ones(5, np.float32))            # a second signature
+    assert cache.fallbacks == 4
+    warned = [r for r in caplog.records if "t_count" in r.getMessage()]
+    assert len(warned) == 2                      # once per signature
+    assert all(r.exc_info for r in warned)       # with the traceback
+
+
+def test_cache_counts_an_executable_that_rejects_its_call(orca_ctx):
+    """An executable whose live arguments never match what it was built
+    for (here: an int32 build called with float32) recompiles through jit
+    on every dispatch — the result is right, the count says so."""
+    import jax
+    cache, reg, _ = _fresh_cache(lambda x: x + 1, "t_reject")
+    x = np.ones((2, 2), np.float32)
+    sig = cache.signature((x,))
+    exe = jax.jit(lambda x: x + 1).lower(
+        jax.ShapeDtypeStruct((2, 2), np.int32)).compile()
+    with cache._lock:
+        cache._execs[sig] = exe
+    for n in (1, 2):
+        np.testing.assert_array_equal(np.asarray(cache(x)), x + 1)
+        assert cache.fallbacks == n
+
+
+def test_in_band_compile_keeps_a_placed_arguments_sharding(orca_ctx):
+    """A miss builds for the live arguments' shardings: built for the
+    default device instead, the executable would reject a mesh-placed
+    argument on every dispatch (seen with load_zoo params on four
+    devices)."""
+    import jax
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    from analytics_zoo_tpu.parallel.mesh import get_default_mesh
+    mesh = get_default_mesh()
+    assert mesh.devices.size > 1
+    w = jax.device_put(np.ones((4, 4), np.float32), NamedSharding(mesh, P()))
+    cache, _, _ = _fresh_cache(lambda w, x: x @ w, "t_placed")
+    x = np.ones((2, 4), np.float32)
+    for _ in range(2):
+        np.testing.assert_array_equal(np.asarray(cache(w, x)), x @ np.ones(
+            (4, 4), np.float32))
+    assert cache.fallbacks == 0
 
 
 def test_process_exits_cleanly_during_warmup():

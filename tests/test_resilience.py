@@ -490,3 +490,44 @@ def test_serving_failover_subprocess_healthz_never_503(orca_ctx):
             except subprocess.TimeoutExpired:
                 proc.kill()
                 proc.wait(timeout=10)
+
+
+# ------------------------------------------------- one process per chip
+
+def test_replica_subprocess_is_pinned_to_cpu_under_a_tpu_parent(monkeypatch):
+    """A chip belongs to one process. The replica's model is a numpy
+    doubler: whatever JAX_PLATFORMS the parent was started with, the child
+    gets ``cpu`` — ``setdefault`` used to hand a ``tpu`` parent's setting
+    down, and the child would take (or hang on) the parent's device."""
+    from analytics_zoo_tpu.common import resilience
+
+    seen = {}
+
+    class FakeProc:
+        pid = 0
+
+        class stdout:
+            @staticmethod
+            def readline():
+                return "READY 1234 replica-x\n"
+
+        def poll(self):
+            return 0
+
+        def wait(self, timeout=None):
+            return 0
+
+    def fake_popen(cmd, **kw):
+        seen["env"] = kw["env"]
+        return FakeProc()
+
+    monkeypatch.setenv("JAX_PLATFORMS", "tpu")
+    monkeypatch.setattr(subprocess, "Popen", fake_popen)
+    rep = resilience.ServingReplicaProc(broker_port=1, env_extra={"K": "v"})
+    assert seen["env"]["JAX_PLATFORMS"] == "cpu"
+    assert seen["env"]["K"] == "v"
+    assert (rep.http_port, rep.replica_id) == (1234, "replica-x")
+    # an explicit env_extra still wins: the caller owns that choice
+    resilience.ServingReplicaProc(
+        broker_port=1, env_extra={"JAX_PLATFORMS": "cpu,tpu"})
+    assert seen["env"]["JAX_PLATFORMS"] == "cpu,tpu"
