@@ -4,9 +4,12 @@ synchronization in the serve/dispatch/train inner loops.
 These replace dev/run-tests.sh's ``lint_wallclock`` grep and extend it to
 the bug class the Gemma-on-TPU comparison (PAPERS.md) blames for most
 GPU→TPU regressions: a single accidental host round-trip (``.item()``,
-``float(device_val)``, ``np.asarray``, an unguarded ``block_until_ready``)
-inside a dispatch loop serializes the host against the device and erases
-the overlap the pipeline PRs bought.
+``float(device_val)``, ``np.asarray``, a ``block_until_ready``) inside a
+dispatch loop serializes the host against the device and erases the
+overlap the pipeline PRs bought. A fence "only on sampled steps" is one
+too: the fit loop's profiler fenced every tenth step to time it and that
+alone was a third of the device's idle time (PERF.md, PR 27), so no
+sampling predicate excuses one.
 """
 
 from __future__ import annotations
@@ -44,11 +47,6 @@ _LOOPS = (ast.For, ast.While, ast.AsyncFor, ast.ListComp, ast.SetComp,
           ast.DictComp, ast.GeneratorExp)
 _FUNCS = (ast.FunctionDef, ast.AsyncFunctionDef)
 
-#: identifiers in an ``if`` test that mark a deliberate, rate-limited
-#: fence (the profiler's sampled steps) — sampled syncs are the design
-_SAMPLING_MARKERS = ("sample", "prof")
-
-
 def _fn_tokens(name: str) -> set:
     return set(t for t in name.lower().split("_") if t)
 
@@ -62,28 +60,6 @@ def _nearest_function(node: ast.AST):
         if isinstance(a, _FUNCS):
             return a
     return None
-
-
-def _test_identifiers(test: ast.AST) -> Iterable[str]:
-    for n in ast.walk(test):
-        if isinstance(n, ast.Name):
-            yield n.id
-        elif isinstance(n, ast.Attribute):
-            yield n.attr
-
-
-def _sampling_guarded(node: ast.AST, stop_at: ast.AST) -> bool:
-    """True when an ``if`` between ``node`` and its function mentions a
-    sampling/profiling identifier — the fence is intentional and bounded
-    (StepProfiler.should_sample, tracer.should_sample...)."""
-    for a in ancestors(node):
-        if a is stop_at:
-            return False
-        if isinstance(a, ast.If) and any(
-                any(m in ident.lower() for m in _SAMPLING_MARKERS)
-                for ident in _test_identifiers(a.test)):
-            return True
-    return False
 
 
 @register
@@ -119,14 +95,13 @@ class HotpathHostSync(Rule):
     """Implicit host↔device sync inside a dispatch/drain/step loop.
 
     Flags ``.item()``, ``float(x)``, ``np.asarray``/``np.array``,
-    ``device_get`` and un-sampled ``block_until_ready`` calls that sit
-    lexically inside a loop of a hot-named function
+    ``device_get`` and ``block_until_ready`` calls that sit lexically
+    inside a loop of a hot-named function
     (dispatch/drain/serve/produce/finish/fetch/run/predict/fit/...)
     in a hot-path package. Each one forces the host to wait for the
     device per iteration — exactly what the bounded in-flight window
-    exists to avoid. Fence off-loop, fetch via the pipeline's drain, or
-    guard with a sampling predicate (an ``if`` mentioning
-    ``*sample*``/``*prof*`` is recognized)."""
+    exists to avoid. Fence off-loop or fetch via the pipeline's drain;
+    time the device from a profiler trace, not with a fence."""
 
     id = "hotpath-host-sync"
     description = "implicit device sync inside a hot dispatch loop"
@@ -147,13 +122,11 @@ class HotpathHostSync(Rule):
                      if _nearest_function(lp) is fn]
             if not loops:
                 continue
-            if _sampling_guarded(node, fn):
-                continue
             yield Finding(
                 self.id, ctx.path, node.lineno, node.col_offset,
                 f"{label} inside the `{fn.name}` loop forces a host sync "
-                "per iteration — hoist it out of the loop, use the "
-                "pipeline drain, or guard it with a sampling predicate")
+                "per iteration — hoist it out of the loop or use the "
+                "pipeline drain")
 
     @staticmethod
     def _sync_label(ctx: FileContext, node: ast.Call):

@@ -21,7 +21,8 @@ rung's executable built ahead of time, off the hot path:
   construction once the ladder is warm. Every compile is timed into
   ``zoo_compile_seconds`` and recorded as a ``compile`` span under the
   :data:`WARMUP_TRACE_ID` trace, which is how tests prove no serve-thread
-  span ever overlaps a compile.
+  span ever overlaps a compile; its HLO text and FLOP count are kept for
+  ``profiling.scope_index``.
 - **configure_persistent_cache** — wires JAX's on-disk compilation cache
   (``JAX_COMPILATION_CACHE_DIR`` when set, else
   ``<checkout>/zoo_tpu_logs/xla_cache``) so process restarts skip cold
@@ -321,6 +322,8 @@ class ExecutableCache:
         self._inflight: set = set()
         #: dispatches and warm-ups that left the AOT path for plain jit
         self.fallbacks = 0
+        #: XLA's FLOP count of the executable built last (None before)
+        self.flops: Optional[float] = None
         self._fallback_sigs: set = set()
         reg = registry if registry is not None else telemetry.get_registry()
         self._tracer = tracer if tracer is not None else \
@@ -375,8 +378,13 @@ class ExecutableCache:
             t1 = perf_counter()
             self._compile_hist.observe(t1 - t0)
             self._tracer.record(WARMUP_TRACE_ID, "compile", t0, t1)
+            # the process keeps the executable's HLO text and FLOP count
+            # under this cache's name: profiling.scope_index reads them
+            flops = profiling.note_executable(
+                self.name, exe, fn=self._jitted, sig=sig)
             with self._lock:
                 self._execs[sig] = exe
+                self.flops = flops
             return exe
         finally:
             with self._lock:

@@ -218,6 +218,10 @@ def init_orca_context(cluster_mode: str = "local",
         configure_persistent_cache,
     )
     configure_persistent_cache()
+    # likewise before the first compile: set-up's lowerings, compiles and
+    # persistent-cache lookups are counted in the registry from here on
+    from analytics_zoo_tpu.common import telemetry
+    telemetry.install_compile_counters()
 
     if cluster_mode in ("multihost", "tpu_pod"):
         if coordinator_address:

@@ -12,12 +12,18 @@ wedged. This module adds the four missing pieces:
   ``GET /trace``. One track (tid) per trace id, so a serving record's
   dequeue/preprocess/device/postprocess stages and a training step's
   data-wait/dispatch/device/callback phases each render as one row.
-- **StepProfiler** — per-step training decomposition used by
-  ``JaxEstimator.fit``: publishes ``zoo_step_flops`` (XLA
-  ``cost_analysis()`` of the compiled step), ``zoo_mfu`` (flops / fenced
-  step time / chip peak), ``zoo_hbm_bytes`` (``device.memory_stats()``
-  with a live-array-bytes fallback for backends that expose none, e.g.
-  CPU), a ``zoo_train_phase_seconds`` histogram, and sampled step traces.
+- **StepProfiler** — training decomposition used by
+  ``JaxEstimator.fit``, on the host's clock and never with a fence of its
+  own: publishes ``zoo_step_flops`` (XLA ``cost_analysis()`` of the
+  ahead-of-time executable), ``zoo_mfu`` (steps x flops / a flush window's
+  seconds / chip peak), ``zoo_hbm_bytes`` (``device.memory_stats()`` with
+  a live-array-bytes fallback for backends that expose none, e.g. CPU), a
+  ``zoo_train_phase_seconds`` histogram, and sampled step traces.
+- **scope index** — :func:`scope_index`: for an executable the program
+  compiled ahead of time, which named scope (flax module path,
+  ``optimizer``, ``loss`` ...) and which phase each instruction of the
+  optimized HLO belongs to. A device trace names its op events by
+  instruction; the index turns them into time by part of the model.
 - **FlightRecorder** — bounded ring buffer of recent spans + notes that
   dumps a postmortem JSON (spans, metrics snapshot, env, backend state)
   to ``zoo_tpu_logs/`` on SIGTERM or on demand. Arm with
@@ -34,11 +40,15 @@ made-up constant). The peak-FLOPs table lives here (moved from bench.py).
 from __future__ import annotations
 
 import json
+import logging
 import os
+import re
 import signal
 import sys
 import threading
+import weakref
 from collections import deque
+from contextlib import contextmanager
 from time import perf_counter
 from typing import Any, Dict, Iterable, List, Optional, Tuple
 
@@ -46,11 +56,14 @@ from analytics_zoo_tpu.common import telemetry
 from analytics_zoo_tpu.common.telemetry import Span
 
 __all__ = [
-    "PEAK_FLOPS", "device_peak_flops", "compiled_step_flops", "hbm_bytes",
+    "PEAK_FLOPS", "device_peak_flops", "hbm_bytes",
     "chrome_trace", "chrome_trace_events", "dump_trace", "StepProfiler",
     "FlightRecorder", "get_flight_recorder", "maybe_arm_from_env",
     "backend_state", "DUMP_DIR", "reset_for_tests",
+    "note_executable", "scope_index", "parse_scope_index",
 ]
+
+logger = logging.getLogger(__name__)
 
 # ``<checkout>/zoo_tpu_logs``: where flight-recorder postmortems go
 # (``ZOO_FLIGHT_RECORDER_DIR`` overrides), and the anchor for the compile
@@ -87,19 +100,6 @@ def device_peak_flops(device=None) -> Optional[float]:
             import jax
             device = jax.devices()[0]
         return PEAK_FLOPS.get(device.device_kind)
-    except Exception:
-        return None
-
-
-def compiled_step_flops(jitted, *args, **kwargs) -> Optional[float]:
-    """XLA's own FLOP count for one compiled call of ``jitted(*args)``.
-
-    ``lower()`` only reads avals (shape/dtype), so it is safe to pass
-    arrays whose sibling buffers were donated. Returns ``None`` when the
-    backend exposes no cost analysis."""
-    try:
-        ca = jitted.lower(*args, **kwargs).compile().cost_analysis()
-        return float(ca.get("flops", 0.0)) or None
     except Exception:
         return None
 
@@ -185,112 +185,361 @@ def dump_trace(path: str, trace_id: Optional[str] = None,
 # -------------------------------------------------------- step profiler
 
 class StepProfiler:
-    """Per-step training decomposition for ``JaxEstimator.fit``.
+    """Training decomposition for ``JaxEstimator.fit``, one per
+    estimator. Everything is timed on the host around calls the loop
+    makes anyway; nothing here waits for the device.
 
-    The estimator times each phase on the host (iterator wait, dispatch
-    call, fenced device time on sampled steps, callback time) and feeds
-    them to :meth:`observe_step`; the profiler turns them into
+    - ``zoo_train_phase_seconds{phase=...}``: per step ``data_wait``,
+      ``dispatch`` and ``callback`` (:meth:`observe_step`); per epoch-level
+      interval ``prepare``, ``first_batch`` and ``flush``
+      (:meth:`phase`, which also opens the tracer span and with it the
+      ``zoo:`` annotation on the profiler's clock); per flush window
+      ``device``, the window's seconds per optimizer step
+      (:meth:`observe_window`).
+    - ``zoo_step_flops`` — FLOPs of one optimizer step by XLA's
+      ``cost_analysis()`` of the ahead-of-time executable
+      (:meth:`set_flops`); ``zoo_mfu`` — steps x flops / the flush
+      window's seconds / chip peak, refreshed at each flush, which is a
+      host sync already; no peak or no flops → no MFU.
+    - ``zoo_hbm_bytes{source=...}``, refreshed at each flush.
+    - tracer spans under trace id ``{name}/step-{n}`` for every
+      ``sample_every``-th step: ``step`` over contiguous ``data_wait`` /
+      ``dispatch`` / ``callback`` children, chrome-trace exportable like
+      the serving plane's stage traces.
 
-    - a ``zoo_train_phase_seconds{phase=...}`` histogram (every step),
-    - ``zoo_step_flops`` / ``zoo_mfu`` gauges — flops come from the
-      compiled step's ``cost_analysis()`` via :meth:`set_flops`, MFU is
-      flops ÷ fenced device-seconds ÷ chip peak; no peak → no MFU,
-    - a ``zoo_hbm_bytes{source=...}`` gauge refreshed on sampled steps,
-    - tracer spans under trace id ``{name}/step-{n}`` for sampled steps:
-      ``step`` parent over contiguous ``data_wait`` / ``dispatch`` /
-      ``device`` / ``callback`` children — the training analogue of the
-      serving plane's dequeue/preprocess/device/postprocess traces,
-      chrome-trace exportable the same way.
-
-    Sampling (``sample_every``) bounds perturbation: fencing every step
-    would serialize the host against the device and destroy the async
-    dispatch the pipeline PRs bought."""
+    Metric objects are looked up in the current registry at each use, so
+    a profiler outlives ``telemetry.reset_for_tests``."""
 
     def __init__(self, name: str = "train", sample_every: int = 10,
                  peak_flops: Optional[float] = None,
                  registry: Optional[telemetry.MetricsRegistry] = None,
                  tracer: Optional[telemetry.Tracer] = None):
-        reg = registry if registry is not None else telemetry.get_registry()
+        self._registry = registry
         self._tracer = tracer if tracer is not None else \
             telemetry.get_tracer()
         self.name = name
         self.sample_every = max(1, int(sample_every))
         self.peak_flops = (peak_flops if peak_flops is not None
                            else device_peak_flops())
+        # written by the estimator's warm-up thread, read at each flush
+        self._flops_lock = threading.Lock()
         self.flops: Optional[float] = None   # per optimizer step
-        self._flops_attempted = False
-        self._g_flops = reg.gauge(
-            "zoo_step_flops", "FLOPs of one compiled optimizer step "
-            "(XLA cost_analysis)")
-        self._g_mfu = reg.gauge(
-            "zoo_mfu", "Model FLOPs utilization: step flops / fenced "
-            "device time / chip peak")
-        self._g_hbm = reg.gauge(
-            "zoo_hbm_bytes", "Resident device memory", ("source",))
-        self._h_phase = reg.histogram(
-            "zoo_train_phase_seconds", "Per-step training phase wall "
-            "time", ("phase",))
+
+    def _reg(self) -> telemetry.MetricsRegistry:
+        return self._registry if self._registry is not None \
+            else telemetry.get_registry()
+
+    def _phase_hist(self, phase: str):
+        return self._reg().histogram(
+            "zoo_train_phase_seconds", "Training phase wall time on the "
+            "host: per step, per epoch-level interval, per flush window",
+            ("phase",)).labels(phase)
 
     # ------------------------------------------------------------ flops
     def set_flops(self, flops: Optional[float], per_steps: int = 1):
         """Record the compiled step's FLOP count (``per_steps`` optimizer
         steps per compiled call, e.g. a fused scan loop)."""
         if flops:
-            self.flops = float(flops) / max(1, int(per_steps))
-            self._g_flops.set(self.flops)
-
-    def ensure_flops(self, thunk, per_steps: int = 1):
-        """Compute flops once via ``thunk()`` (a ``compiled_step_flops``
-        call — one extra XLA compile, so attempted a single time; the
-        first batch shape wins)."""
-        if self._flops_attempted:
-            return
-        self._flops_attempted = True
-        try:
-            self.set_flops(thunk(), per_steps)
-        except Exception:
-            pass
+            per_step = float(flops) / max(1, int(per_steps))
+            with self._flops_lock:
+                self.flops = per_step
+            self._reg().gauge(
+                "zoo_step_flops", "FLOPs of one compiled optimizer step "
+                "(XLA cost_analysis of the ahead-of-time executable)"
+            ).set(per_step)
 
     def should_sample(self, step: int) -> bool:
-        """Sampled steps are fenced (device time measured) and traced."""
+        """Sampled steps get a ``{name}/step-{n}`` trace."""
         return step % self.sample_every == 0
 
     # ------------------------------------------------------------ steps
     def observe_step(self, step: int, t_start: float, data_wait_s: float,
-                     dispatch_s: float, device_s: Optional[float] = None,
-                     callback_s: float = 0.0, n_steps: int = 1):
-        """One completed step (or fused loop of ``n_steps`` optimizer
-        steps), phase durations measured by the caller. ``device_s`` is
-        the fenced dispatch→ready time, present only on sampled steps;
-        ``t_start`` is the ``perf_counter`` when the data wait began."""
-        self._h_phase.labels("data_wait").observe(data_wait_s)
-        self._h_phase.labels("dispatch").observe(dispatch_s)
+                     dispatch_s: float, callback_s: float = 0.0):
+        """One completed step (or fused loop of optimizer steps), phase
+        durations measured by the caller; ``t_start`` is the
+        ``perf_counter`` when the data wait began."""
+        self._phase_hist("data_wait").observe(data_wait_s)
+        self._phase_hist("dispatch").observe(dispatch_s)
         if callback_s:
-            self._h_phase.labels("callback").observe(callback_s)
-        if device_s is None:
+            self._phase_hist("callback").observe(callback_s)
+        if not self.should_sample(step):
             return
-        self._h_phase.labels("device").observe(device_s)
-        if self.flops and device_s > 0 and self.peak_flops:
-            self._g_mfu.set(
-                self.flops * n_steps / device_s / self.peak_flops)
-        n, src = hbm_bytes()
-        if n is not None:
-            self._g_hbm.labels(src).set(n)
         # contiguous sub-spans reconstructed from the measured durations
         tid = f"{self.name}/step-{step}"
         t_disp = t_start + data_wait_s
-        t_dev_end = t_disp + device_s
-        end = t_dev_end + callback_s
+        t_call = t_disp + dispatch_s
+        end = t_call + callback_s
         self._tracer.record(tid, "step", t_start, end)
         self._tracer.record(tid, "data_wait", t_start, t_disp,
                             parent="step")
-        self._tracer.record(tid, "dispatch", t_disp, t_disp + dispatch_s,
-                            parent="step")
-        self._tracer.record(tid, "device", t_disp, t_dev_end,
-                            parent="step")
+        self._tracer.record(tid, "dispatch", t_disp, t_call, parent="step")
         if callback_s:
-            self._tracer.record(tid, "callback", t_dev_end, end,
+            self._tracer.record(tid, "callback", t_call, end,
                                 parent="step")
+
+    @contextmanager
+    def phase(self, span: str, label: str):
+        """One epoch-level interval on every clock the program keeps: a
+        tracer span called ``span`` (nested under the ambient one, and
+        ``zoo:<span>`` in a profiler session) and one sample of
+        ``zoo_train_phase_seconds{phase=label}``."""
+        trace_id = self._tracer.current_trace_id() or self.name
+        t0 = perf_counter()
+        try:
+            with self._tracer.span(span, trace_id=trace_id):
+                yield
+        finally:
+            self._phase_hist(label).observe(perf_counter() - t0)
+
+    def observe_window(self, n_steps: int, seconds: float):
+        """A flush window that just ended in a host sync: ``n_steps``
+        optimizer steps took ``seconds`` of wall time, data waits and all.
+        Refreshes ``zoo_mfu``, ``zoo_hbm_bytes`` and the ``device`` phase
+        (seconds per step over the window)."""
+        if n_steps <= 0 or seconds <= 0:
+            return
+        self._phase_hist("device").observe(seconds / n_steps)
+        if self.flops and self.peak_flops:
+            self._reg().gauge(
+                "zoo_mfu", "Model FLOPs utilization over the last flush "
+                "window: steps x step flops / seconds / chip peak"
+            ).set(self.flops * n_steps / seconds / self.peak_flops)
+        n, src = hbm_bytes()
+        if n is not None:
+            self._reg().gauge("zoo_hbm_bytes", "Resident device memory",
+                              ("source",)).labels(src).set(n)
+
+
+# --------------------------------------------------------- scope index
+
+class _Executable:
+    """What the process keeps of one executable compiled ahead of time:
+    enough to answer :func:`scope_index` after the executable and the
+    estimator that built it are gone, and nothing that holds device
+    memory."""
+
+    __slots__ = ("fn", "sig", "hlo_text", "flops", "index")
+
+    def __init__(self, fn, sig, hlo_text, flops):
+        self.fn, self.sig = fn, sig
+        self.hlo_text, self.flops = hlo_text, flops
+        self.index: Optional[Dict[str, dict]] = None
+
+
+_executables: Dict[str, _Executable] = {}
+_executables_lock = threading.Lock()
+
+
+def note_executable(name: str, exe, fn=None, sig=None) -> Optional[float]:
+    """Keep the optimized HLO text and XLA's FLOP count of ``exe``, just
+    compiled under ``name`` (the newest executable of a name wins);
+    returns the FLOP count. ``ExecutableCache`` calls this after every
+    build. Reading the text of a whole train step takes most of a second,
+    so a rebuild of the same jitted ``fn`` for the same signature — every
+    ``fit`` call warms its step again — keeps what is held. Never raises:
+    a compile must not fail for tracing's sake."""
+    with _executables_lock:
+        held = _executables.get(name)
+        if held is not None and fn is not None and held.fn is not None \
+                and held.fn() is fn and held.sig == sig:
+            return held.flops
+    text = flops = None
+    try:
+        text = exe.as_text()
+        cost = exe.cost_analysis()
+        if isinstance(cost, (list, tuple)):
+            cost = cost[0]
+        flops = float(cost.get("flops", 0.0)) or None
+    except Exception:
+        logger.debug("no HLO text or cost analysis for %s", name,
+                     exc_info=True)
+    try:
+        ref = weakref.ref(fn) if fn is not None else None
+    except TypeError:        # a callable that takes no weak reference
+        ref = None
+    rec = _Executable(ref, sig, text, flops)
+    with _executables_lock:
+        _executables[name] = rec
+    return flops
+
+
+def scope_index(name: str) -> Optional[Dict[str, dict]]:
+    """``{instruction name: {"scope", "phase", "scopes", "opcode"}}`` for
+    the executable last compiled ahead of time under ``name``
+    (``"estimator_train_step"``, ``"estimator_train_scan"``, an inference
+    model's cache name); ``None`` when nothing was. See
+    :func:`parse_scope_index`. Parsed on first request."""
+    with _executables_lock:
+        rec = _executables.get(name)
+    if rec is None or rec.hlo_text is None:
+        return None
+    if rec.index is None:
+        rec.index = parse_scope_index(rec.hlo_text)
+    return rec.index
+
+
+_COMPUTATION = re.compile(r"^(ENTRY\s+)?%?([\w.\-]+)\s+\(")
+_INSTRUCTION = re.compile(
+    r"^\s+(ROOT\s+)?%?([\w.\-]+)\s+=\s+.*?\s([a-z][a-z0-9\-]*)\(")
+_OP_NAME = re.compile(r'op_name="((?:[^"\\]|\\.)*)"')
+_CALLED = re.compile(r"\b(?:body|condition|to_apply|true_computation|"
+                     r"false_computation|calls)=%?([\w.\-]+)")
+_BRANCHES = re.compile(r"\bbranch_computations=\{([^}]*)\}")
+_OPERAND = re.compile(r"%([\w.\-]+)")
+_TRANSFORM = re.compile(r"^([A-Za-z_][\w.]*)\((.*)\)$")
+#: opcodes whose callee runs as ops of its own on the device
+_CONTAINERS = frozenset({"while", "call", "conditional", "async-start"})
+#: opcodes that take no device time and never show as an op event
+_NO_TIME = frozenset({"parameter", "constant", "get-tuple-element", "tuple",
+                      "bitcast"})
+_PRODUCTS = frozenset({"dot", "convolution"})
+
+
+def _scope_of(op_name: str) -> Tuple[str, str]:
+    """(scope, phase) of one ``metadata.op_name``. The last component is
+    the primitive's name and goes; ``jit(f)`` goes whole (a function's
+    name is no scope); any other transform wrapper is peeled off its
+    scope: ``jit(step_fn)/transpose(jvp(Classifier))/bert/block_3/
+    attention/out/dot_general`` is ``Classifier/bert/block_3/attention/
+    out``, backward."""
+    transforms, scope = set(), []
+    for part in op_name.split("/")[:-1]:
+        while True:
+            m = _TRANSFORM.match(part)
+            if not m:
+                break
+            transforms.add(m.group(1))
+            part = "" if m.group(1) in ("jit", "pjit") else m.group(2)
+        if part:
+            scope.append(part)
+    if "transpose" in transforms:
+        phase = "backward"
+    elif "jvp" in transforms:
+        phase = "forward"
+    elif scope and scope[0] == "optimizer":
+        phase = "optimizer"
+    else:
+        phase = "other"
+    return "/".join(scope), phase
+
+
+def parse_scope_index(hlo_text: str) -> Dict[str, dict]:
+    """The scope index of one optimized HLO module's text: for every
+    instruction the device runs as an op of its own — those of the entry
+    computation and of the bodies of its ``while``/``call``/``conditional``
+    instructions — ``{"scope", "phase", "scopes", "opcode"}``.
+
+    ``scope`` and ``phase`` (``forward`` | ``backward`` | ``optimizer`` |
+    ``other``) come from the instruction's ``metadata.op_name``
+    (:func:`_scope_of`). A fusion takes the scope of the
+    ``dot``/``convolution`` inside its fused computation if it has one,
+    else of that computation's root, else its own; ``scopes`` lists every
+    distinct scope found inside, so a weight-gradient product fused with
+    the optimizer's update counts with its product and shows as mixed.
+    An instruction the compiler left nameless (the copies and slices that
+    bring a weight in ahead of its use, and the waits for them) takes
+    ``scope`` and ``phase`` of the nearest named instruction that
+    consumes its result (or, for a copy out of the step, produced its
+    operand), with ``scopes`` left empty; ``scope`` is ``None`` only
+    where neither exists."""
+    computations: Dict[str, list] = {}
+    entry = current = None
+    for line in hlo_text.splitlines():
+        if not line:
+            continue
+        if not line[0].isspace():
+            current = None
+            m = _COMPUTATION.match(line) if line.endswith("{") else None
+            if m:
+                current = computations.setdefault(m.group(2), [])
+                if m.group(1):
+                    entry = m.group(2)
+            continue
+        m = _INSTRUCTION.match(line) if current is not None else None
+        if not m:
+            continue
+        rest = line[m.end():]
+        named = _OP_NAME.search(rest)
+        opcode = m.group(3)
+        callees = []
+        if opcode == "fusion" or opcode in _CONTAINERS:
+            callees = _CALLED.findall(rest)
+            for group in _BRANCHES.findall(rest):
+                callees += [c.strip().lstrip("%")
+                            for c in group.split(",") if c.strip()]
+        current.append((m.group(2), opcode,
+                        named.group(1) if named else None,
+                        bool(m.group(1)), callees, _OPERAND.findall(rest)))
+
+    index: Dict[str, dict] = {}
+    seen = set()
+
+    def visit(computation: str):
+        if computation in seen:
+            return
+        seen.add(computation)
+        body = computations.get(computation, ())
+        for name, opcode, own, _, callees, _ in body:
+            if opcode in _NO_TIME:
+                continue
+            names = [own] if own else []
+            chosen = own
+            if opcode == "fusion":
+                inner = [i for c in callees for i in computations.get(c, ())]
+                names += [i[2] for i in inner if i[2]]
+                products = [i[2] for i in inner
+                            if i[1] in _PRODUCTS and i[2]]
+                roots = [i[2] for i in inner if i[3] and i[2]]
+                chosen = (products or roots or [own])[0]
+            else:
+                for callee in callees:
+                    visit(callee)
+            scope, phase = _scope_of(chosen) if chosen else (None, "other")
+            index[name] = {
+                "scope": scope, "phase": phase, "opcode": opcode,
+                "scopes": sorted({_scope_of(n)[0] for n in names})}
+        _inherit_from_neighbours(body, index)
+
+    if entry is not None:
+        visit(entry)
+    return index
+
+
+def _inherit_from_neighbours(body, index: Dict[str, dict]) -> None:
+    """For one computation's instructions, already indexed: give each
+    that the compiler left nameless the scope and phase of the nearest
+    instruction with a scope that consumes its result — or, where none
+    does (a copy of a result out of the step), that produced its operand
+    — found breadth-first through instructions without one (program order
+    breaks ties)."""
+    users: Dict[str, list] = {}
+    operands_of: Dict[str, list] = {}
+    for name, _, _, _, _, operands in body:
+        operands_of[name] = operands
+        for operand in operands:
+            users.setdefault(operand, []).append(name)
+
+    def nearest(start: str, edges: Dict[str, list]):
+        seen, frontier = {start}, [start]
+        while frontier:
+            reached = []
+            for at in frontier:
+                for other in edges.get(at, ()):
+                    if index.get(other, {}).get("scopes"):
+                        return index[other]
+                    if other not in seen:
+                        seen.add(other)
+                        reached.append(other)
+            frontier = reached
+        return None
+
+    for name, _, _, _, _, _ in body:
+        entry = index.get(name)
+        if entry is None or entry["scope"] is not None:
+            continue
+        found = nearest(name, users) or nearest(name, operands_of)
+        if found is not None:
+            entry["scope"], entry["phase"] = found["scope"], found["phase"]
 
 
 # ----------------------------------------------------- flight recorder
@@ -529,8 +778,8 @@ def backend_state(timeout_s: float = 2.0) -> dict:
 
 def reset_for_tests():
     """Called from telemetry.reset_for_tests(): drop the flight-recorder
-    singleton (its tracer hook died with the trace clear) and the backend
-    probe cache."""
+    singleton (its tracer hook died with the trace clear), the backend
+    probe cache and what is kept of compiled executables."""
     global _FLIGHT_RECORDER
     with _FR_LOCK:
         if _FLIGHT_RECORDER is not None:
@@ -539,3 +788,5 @@ def reset_for_tests():
             _FLIGHT_RECORDER = None
     with _BACKEND_LOCK:
         _BACKEND_CACHE.clear()
+    with _executables_lock:
+        _executables.clear()

@@ -34,11 +34,13 @@ in docs/observability.md.
 from __future__ import annotations
 
 import contextvars
+import functools
 import math
 import os
+import sys
 import threading
 from collections import OrderedDict
-from contextlib import contextmanager
+from contextlib import contextmanager, nullcontext
 from dataclasses import dataclass
 from time import monotonic, perf_counter
 from typing import Any, Dict, Iterable, List, Optional, Sequence, Tuple
@@ -48,7 +50,8 @@ __all__ = [
     "get_registry", "get_tracer", "prometheus_text", "snapshot",
     "bench_snapshot", "instrument_jit", "traced_device_put",
     "traced_device_get", "observe_device_block", "timed_block_until_ready",
-    "set_trace_sampling", "reset_for_tests", "dump_trace",
+    "set_trace_sampling", "reset_for_tests", "dump_trace", "annotation",
+    "install_compile_counters", "ANNOTATION_PREFIX",
 ]
 
 # latency-shaped default buckets (seconds): 100µs .. 30s
@@ -627,6 +630,21 @@ class Span:
 _current_span: "contextvars.ContextVar[Optional[Tuple[str, str]]]" = \
     contextvars.ContextVar("zoo_current_span", default=None)
 
+#: what the program's host spans are called in a ``jax.profiler`` trace
+ANNOTATION_PREFIX = "zoo:"
+
+
+def annotation(name: str):
+    """A host span on the profiler's own clock: a
+    ``jax.profiler.TraceAnnotation("zoo:" + name)``, which a device trace
+    can be laid against (``perf_counter`` spans cannot). Costs about a
+    microsecond while no profiler session is open; a null context while
+    jax has not been imported, which this module never does itself."""
+    jax = sys.modules.get("jax")
+    if jax is None:
+        return nullcontext()
+    return jax.profiler.TraceAnnotation(ANNOTATION_PREFIX + name)
+
 
 class Tracer:
     """Bounded in-memory span store keyed by trace id.
@@ -718,7 +736,9 @@ class Tracer:
     @contextmanager
     def span(self, name: str, trace_id: Optional[str] = None):
         """Context-propagating span: nested spans inherit the ambient
-        trace id and get the enclosing span's name as ``parent``."""
+        trace id and get the enclosing span's name as ``parent``. The
+        interval is also open as an :func:`annotation`, so a profiler
+        session that is running sees it as ``zoo:<name>``."""
         ambient = _current_span.get()
         if trace_id is None:
             if ambient is None:
@@ -727,12 +747,13 @@ class Tracer:
             trace_id = ambient[0]
         parent = ambient[1] if ambient and ambient[0] == trace_id else None
         token = _current_span.set((trace_id, name))
-        t0 = perf_counter()
-        try:
-            yield
-        finally:
-            _current_span.reset(token)
-            self.record(trace_id, name, t0, perf_counter(), parent)
+        with annotation(name):
+            t0 = perf_counter()
+            try:
+                yield
+            finally:
+                _current_span.reset(token)
+                self.record(trace_id, name, t0, perf_counter(), parent)
 
     def current_trace_id(self) -> Optional[str]:
         cur = _current_span.get()
@@ -851,11 +872,23 @@ class _InstrumentedJit:
     the recompile detector the ROADMAP perf PRs read. Signatures are read
     BEFORE the call, so donated buffers are still valid.
 
-    Delegates everything else (``lower``, ``clear_cache``...) to the
-    underlying jitted callable."""
+    The function is jitted under ``name`` (``jit_<name>`` is the compiled
+    module's name). Delegates everything else (``lower``,
+    ``clear_cache``...) to the underlying jitted callable."""
 
     def __init__(self, fn, name: str, registry: MetricsRegistry, jit_kwargs):
         import jax
+        if getattr(fn, "__name__", None) != name:
+            # jax names the compiled module after the function, and with
+            # it the "XLA Modules" events of a device trace and the
+            # persistent cache's entry: make that the stable name this
+            # wrapper counts under, whatever the closure is called
+            inner = fn
+
+            @functools.wraps(inner)
+            def fn(*args, **kwargs):
+                return inner(*args, **kwargs)
+            fn.__name__ = fn.__qualname__ = name
         self._jitted = jax.jit(fn, **jit_kwargs)
         self.name = name
         self._lock = threading.Lock()
@@ -904,6 +937,62 @@ def instrument_jit(fn=None, *, name: Optional[str] = None,
             jit_kwargs)
 
     return wrap(fn) if fn is not None else wrap
+
+
+#: ``jax.monitoring`` duration events that mean a program was lowered or
+#: handed to the backend (a persistent-cache hit still is), by stage
+_COMPILE_STAGES = {
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+    "/jax/core/compile/backend_compile_duration": "backend_compile",
+}
+#: the persistent compilation cache's own events, by result
+_CACHE_RESULTS = {
+    "/jax/compilation_cache/cache_hits": "hit",
+    "/jax/compilation_cache/cache_misses": "miss",
+}
+_compile_counters_lock = threading.Lock()
+_compile_counters_installed = False
+
+
+def _on_compile_duration(event: str, secs: float, **_):
+    stage = _COMPILE_STAGES.get(event)
+    if stage is None:
+        return
+    reg = get_registry()
+    reg.counter("zoo_compile_events_total",
+                "Programs JAX lowered / handed to the backend compiler "
+                "(a persistent-cache hit counts under both)",
+                ("stage",)).labels(stage).inc()
+    reg.counter("zoo_compile_seconds_total",
+                "Seconds spent lowering / in the backend compiler or "
+                "loading from the persistent cache",
+                ("stage",)).labels(stage).inc(secs)
+
+
+def _on_compile_event(event: str, **_):
+    result = _CACHE_RESULTS.get(event)
+    if result is not None:
+        get_registry().counter(
+            "zoo_compile_cache_total",
+            "Persistent compilation cache lookups", ("result",)
+        ).labels(result).inc()
+
+
+def install_compile_counters() -> None:
+    """Feed JAX's own lowering, compile and persistent-cache events into
+    the registry: what a job that spends minutes starting spent them on.
+    One ``jax.monitoring`` listener per process, registered by
+    ``init_orca_context``; the listeners look the registry up per event
+    (compiles are rare), so they survive ``reset_for_tests``."""
+    global _compile_counters_installed
+    with _compile_counters_lock:
+        if _compile_counters_installed:
+            return
+        import jax.monitoring
+        jax.monitoring.register_event_duration_secs_listener(
+            _on_compile_duration)
+        jax.monitoring.register_event_listener(_on_compile_event)
+        _compile_counters_installed = True
 
 
 def _tree_nbytes(tree) -> int:

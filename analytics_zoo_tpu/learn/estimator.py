@@ -26,6 +26,8 @@ the latest checkpoint and resumes, up to ``failure_retry_times``).
 
 from __future__ import annotations
 
+import glob
+import json
 import logging
 import os
 import time
@@ -69,9 +71,14 @@ class _ProfileWindow:
     traces of long fits are too large to open in TensorBoard/Perfetto, a
     20-step window is not. Thresholds are absolute ``_py_step`` values
     computed at fit start; ``on_step`` is called after every optimizer
-    loop and ``close()`` from fit's ``finally``."""
+    loop and ``close()`` from fit's ``finally``. Beside the trace it
+    wrote, ``close()`` leaves ``scope_index.json``: for ``executable``,
+    the step compiled ahead of time, which named scope every instruction
+    belongs to (``profiling.scope_index``), so the trace's op events can
+    be read by part of the model."""
 
-    def __init__(self, log_dir: str, start_step: int, stop_step: int):
+    def __init__(self, log_dir: str, start_step: int, stop_step: int,
+                 executable: Optional[str] = None):
         if stop_step <= start_step:
             raise ValueError(
                 f"profile_steps window must be non-empty, got "
@@ -79,6 +86,7 @@ class _ProfileWindow:
         self.log_dir = log_dir
         self.start_step = int(start_step)
         self.stop_step = int(stop_step)
+        self.executable = executable
         self.active = False
         self.done = False
 
@@ -99,6 +107,17 @@ class _ProfileWindow:
             jax.profiler.stop_trace()
             self.active = False
             self.done = True
+            self._write_scope_index()
+
+    def _write_scope_index(self):
+        runs = sorted(glob.glob(
+            os.path.join(self.log_dir, "plugins", "profile", "*")))
+        index = self.executable and \
+            profiling_lib.scope_index(self.executable)
+        if not runs or not index:
+            return
+        with open(os.path.join(runs[-1], "scope_index.json"), "w") as fh:
+            json.dump({self.executable: index}, fh)
 
 
 class FlaxModelAdapter:
@@ -336,6 +355,9 @@ class JaxEstimator:
         self._eval_step = None
         self._predict_fn = None
         self._precompile_thread = None
+        # fit's decomposition on the host's clock; its FLOP count is the
+        # ahead-of-time executable's (_start_precompile)
+        self._step_prof = profiling_lib.StepProfiler(name="train")
         self._epoch = 0
         self._py_step = 0  # host-side mirror of state["step"]: no device sync
         self._train_writer = None
@@ -406,8 +428,18 @@ class JaxEstimator:
 
     # ------------- compile machinery -------------------------------------
     def _tx(self):
+        import jax
         import optax
-        tx = self.optimizer.to_optax()
+
+        def scoped(name, inner):
+            # the same transformation, its update's ops named in the
+            # compiled step (profiling.scope_index reads the names)
+            def update(updates, state, params=None):
+                with jax.named_scope(name):
+                    return inner.update(updates, state, params)
+            return optax.GradientTransformation(inner.init, update)
+
+        tx = scoped("optimizer", self.optimizer.to_optax())
         if self._grad_clip:
             if self._grad_clip[0] == "norm":
                 clip = optax.clip_by_global_norm(self._grad_clip[1])
@@ -415,7 +447,7 @@ class JaxEstimator:
                 lo, hi = self._grad_clip[1], self._grad_clip[2]
                 mag = max(abs(lo), abs(hi))
                 clip = optax.clip(mag)
-            tx = optax.chain(clip, tx)
+            tx = optax.chain(scoped("clip", clip), tx)
         return tx
 
     def _ensure_mesh(self):
@@ -515,38 +547,47 @@ class JaxEstimator:
         aux_weight = self.aux_loss_weight
         penalty_fn = self.param_penalty
 
+        # jax.named_scope names the phases no flax module names (loss,
+        # clip and optimizer — the latter two inside tx — and metrics):
+        # the names ride the compiled step's HLO metadata, where
+        # profiling.scope_index finds them; they cost nothing at run time
         def step_fn(state, x, y):
             rng = jax.random.fold_in(base_rng, state["step"])
 
             def compute_loss(params):
                 preds, new_mut = adapter.apply(params, state["model_state"],
                                                x, True, rng)
-                per = loss_fn(y, preds)
-                loss = per.mean()
-                if penalty_fn is not None:
-                    loss = loss + penalty_fn(params)
-                # consume sown per-step losses (MoE load balance): they add
-                # to the objective and are stripped so model_state keeps its
-                # across-step structure
-                if isinstance(new_mut, dict) and "aux_loss" in new_mut:
-                    new_mut = dict(new_mut)
-                    aux = new_mut.pop("aux_loss")
-                    aux_terms = [jnp.sum(jnp.asarray(leaf))
-                                 for leaf in jax.tree_util.tree_leaves(aux)]
-                    if aux_terms:
-                        loss = loss + aux_weight * sum(aux_terms)
+                with jax.named_scope("loss"):
+                    per = loss_fn(y, preds)
+                    loss = per.mean()
+                    if penalty_fn is not None:
+                        loss = loss + penalty_fn(params)
+                    # consume sown per-step losses (MoE load balance):
+                    # they add to the objective and are stripped so
+                    # model_state keeps its across-step structure
+                    if isinstance(new_mut, dict) and "aux_loss" in new_mut:
+                        new_mut = dict(new_mut)
+                        aux = new_mut.pop("aux_loss")
+                        aux_terms = [
+                            jnp.sum(jnp.asarray(leaf))
+                            for leaf in jax.tree_util.tree_leaves(aux)]
+                        if aux_terms:
+                            loss = loss + aux_weight * sum(aux_terms)
                 return loss, new_mut
 
             (loss_val, new_mut), grads = jax.value_and_grad(
                 compute_loss, has_aux=True)(state["params"])
             updates, new_opt = tx.update(grads, state["opt_state"],
                                          state["params"])
-            new_params = optax.apply_updates(state["params"], updates)
-            new_state = {"step": state["step"] + 1,
-                         "params": new_params,
-                         "opt_state": new_opt,
-                         "model_state": new_mut}
-            return new_state, {"loss": loss_val.astype(jnp.float32)}
+            with jax.named_scope("optimizer"):
+                new_params = optax.apply_updates(state["params"], updates)
+            with jax.named_scope("metrics"):
+                new_state = {"step": state["step"] + 1,
+                             "params": new_params,
+                             "opt_state": new_opt,
+                             "model_state": new_mut}
+                logs = {"loss": loss_val.astype(jnp.float32)}
+            return new_state, logs
 
         # instrument_jit = jax.jit + recompile accounting: the
         # zoo_jit_cache_misses_total{fn=...} counter stays flat across
@@ -700,21 +741,23 @@ class JaxEstimator:
                 scan_x = jax.tree_util.tree_map(batched((k,)), ds.x)
                 scan_y = jax.tree_util.tree_map(batched((k,)), ds.y)
                 targets.append(("estimator_train_scan", self._train_scan,
-                                ((scan_x, scan_y),)))
+                                ((scan_x, scan_y),), k))
             else:
                 targets.append(("estimator_train_step", self._train_step,
-                                (x_avals, y_avals)))
+                                (x_avals, y_avals), 1))
             if with_eval and self._eval_step is not None:
                 ms = [m.init_state() for m in self.metrics]
                 ms_avals = jax.tree_util.tree_map(
                     lambda a: jax.ShapeDtypeStruct(
                         np.shape(a), np.asarray(a).dtype), ms)
                 targets.append(("estimator_eval_step", self._eval_step,
-                                (ms_avals, x_avals, y_avals)))
+                                (ms_avals, x_avals, y_avals), 0))
         except Exception:
             logger.debug("step precompile skipped: dataset shapes "
                          "unavailable", exc_info=True)
             return None
+
+        step_prof = self._step_prof
 
         def worker():
             # the eval step takes the state WITHOUT donating it, the train
@@ -723,13 +766,17 @@ class JaxEstimator:
             for sharded in (True, False):
                 sa = state_avals(sharded)
                 ok = True
-                for name, fn, rest in targets:
+                for name, fn, rest, train_steps in targets:
                     if compile_ahead.draining():
                         return          # interpreter exit: stop compiling
                     cache = compile_ahead.ExecutableCache(fn, name=name)
                     if not cache.warm(sa, *rest):
                         ok = False
                         break
+                    if train_steps:
+                        # zoo_step_flops: XLA's count for the executable
+                        # just built, no lowering of its own
+                        step_prof.set_flops(cache.flops, train_steps)
                 if ok:
                     return
 
@@ -777,11 +824,25 @@ class JaxEstimator:
         ``<tensorboard dir>/plugins/profile`` next to the TF-events
         summaries, viewable in TensorBoard's profile tab or Perfetto.
 
-        Independently of ``profile``, every fit publishes the step
-        decomposition through the telemetry registry: ``zoo_step_flops``
-        (XLA ``cost_analysis`` of the compiled step), ``zoo_mfu``,
-        ``zoo_hbm_bytes`` and the ``zoo_train_phase_seconds`` histogram
-        (data_wait/dispatch/device/callback) — see docs/observability.md.
+        The trace directory also gets ``scope_index.json``: which
+        named scope (flax module path, ``loss``, ``optimizer`` ...) each
+        instruction of the compiled step belongs to, so the trace's op
+        events can be summed by part of the model.
+
+        Independently of ``profile``, every fit publishes its
+        decomposition through the telemetry registry, on the host's clock
+        and without a fence of its own: the ``zoo_train_phase_seconds``
+        histogram (per step ``data_wait``/``dispatch``/``callback``; per
+        fit ``prepare``; per epoch ``first_batch``; per summary flush
+        ``flush`` and ``device``, the window's seconds per step) and, at
+        each flush, ``zoo_mfu`` and ``zoo_hbm_bytes``; ``zoo_step_flops``
+        is XLA's ``cost_analysis`` of the ahead-of-time executable. The
+        same intervals are tracer spans (``fit`` > ``fit/prepare``,
+        ``epoch`` > ``epoch/first_batch``, ``epoch/flush``,
+        ``fit/validate``, ``fit/checkpoint``) and, while a
+        ``jax.profiler`` session is open, ``zoo:<name>`` annotations on
+        its clock, with ``zoo:data_wait`` and ``zoo:dispatch`` per step —
+        see docs/observability.md.
 
         ``auto_resume=True`` hardens the retry-from-snapshot boundary for
         backend loss (a wedged/lost accelerator, or an injected
@@ -793,20 +854,40 @@ class JaxEstimator:
         running. Step/epoch counters and data order restore exactly, so a
         resumed run converges to the bitwise-identical loss of an
         unfaulted one."""
-        ds = self._coerce(to_sharded_dataset(data, feature_cols, label_cols))
-        val_ds = (self._coerce(to_sharded_dataset(validation_data, feature_cols,
-                                                  label_cols))
-                  if validation_data is not None else None)
-        mesh = self._ensure_mesh()
-        self._build_train_step()
-        if val_ds is not None:
-            self._build_eval_step()
-        # compile-ahead: AOT-build the train (and eval) step on a daemon
-        # thread WHILE the first batch stages host-side — step 0's jit
-        # call then deserializes from the persistent compile cache instead
-        # of compiling cold (ISSUE 5 tentpole, third hot path)
-        self._start_precompile(ds, batch_size, steps_per_loop,
-                               with_eval=val_ds is not None)
+        tracer = telemetry.get_tracer()
+        with tracer.span("fit", trace_id=f"train/fit-{self._py_step}"):
+            with self._step_prof.phase("fit/prepare", "prepare"):
+                ds = self._coerce(
+                    to_sharded_dataset(data, feature_cols, label_cols))
+                val_ds = (self._coerce(to_sharded_dataset(
+                    validation_data, feature_cols, label_cols))
+                    if validation_data is not None else None)
+                mesh = self._ensure_mesh()
+                self._build_train_step()
+                if val_ds is not None:
+                    self._build_eval_step()
+                # compile-ahead: AOT-build the train (and eval) step on a
+                # daemon thread WHILE the first batch stages host-side —
+                # step 0's jit call then deserializes from the persistent
+                # compile cache instead of compiling cold (ISSUE 5
+                # tentpole, third hot path)
+                self._start_precompile(ds, batch_size, steps_per_loop,
+                                       with_eval=val_ds is not None)
+            return self._fit_epochs(
+                ds, val_ds, mesh, epochs=epochs, batch_size=batch_size,
+                checkpoint_trigger=checkpoint_trigger,
+                summary_interval=summary_interval, shuffle=shuffle,
+                steps_per_loop=steps_per_loop, cache=cache,
+                profile=profile, profile_steps=profile_steps,
+                auto_resume=auto_resume)
+
+    def _fit_epochs(self, ds, val_ds, mesh, *, epochs, batch_size,
+                    checkpoint_trigger, summary_interval, shuffle,
+                    steps_per_loop, cache, profile, profile_steps,
+                    auto_resume) -> Dict[str, List[float]]:
+        """``fit`` after ``fit/prepare``: the epoch loop with its retries,
+        validation and checkpoints."""
+        tracer = telemetry.get_tracer()
         if checkpoint_trigger is None and self.model_dir:
             checkpoint_trigger = EveryEpoch()
         if checkpoint_trigger is not None and \
@@ -826,21 +907,21 @@ class JaxEstimator:
             lo, hi = profile_steps if profile_steps is not None else (0, 20)
             profile_window = _ProfileWindow(
                 self._tb_dirs[0], self._py_step + int(lo),
-                self._py_step + int(hi))
-        # per-step phase decomposition + MFU/FLOPs/HBM gauges — always on
-        # (sampled steps only are fenced, so the async dispatch overlap is
-        # preserved on the other sample_every-1 of steps)
-        step_prof = profiling_lib.StepProfiler(
-            name="train", sample_every=max(2, summary_interval // 2))
+                self._py_step + int(hi),
+                executable=("estimator_train_scan" if steps_per_loop > 1
+                            else "estimator_train_step"))
+        # which steps get a train/step-N trace
+        self._step_prof.sample_every = max(2, summary_interval // 2)
 
         try:
             while self._epoch < target_epoch:
                 try:
-                    epoch_loss = self._run_epoch(
-                        ds, mesh, batch_size, shuffle, summary_interval,
-                        train_writer, checkpoint_trigger,
-                        steps_per_loop=steps_per_loop, cache=cache,
-                        step_prof=step_prof, profile_window=profile_window)
+                    with tracer.span("epoch"):
+                        epoch_loss = self._run_epoch(
+                            ds, mesh, batch_size, shuffle, summary_interval,
+                            train_writer, checkpoint_trigger,
+                            steps_per_loop=steps_per_loop, cache=cache,
+                            profile_window=profile_window)
                 except Exception as e:
                     # elastic retry-from-snapshot (ref Topology.scala:1255-1337)
                     retries += 1
@@ -871,7 +952,8 @@ class JaxEstimator:
                 self._epoch += 1
                 val_score = None
                 if val_ds is not None:
-                    val = self.evaluate(val_ds, batch_size=batch_size)
+                    with tracer.span("fit/validate"):
+                        val = self.evaluate(val_ds, batch_size=batch_size)
                     for k, v in val.items():
                         history.setdefault("val_" + k, []).append(v)
                         self._val_writer.add_scalar(k, v, self._py_step)
@@ -882,7 +964,8 @@ class JaxEstimator:
                 if checkpoint_trigger and self.model_dir and \
                         _fire_trigger(checkpoint_trigger, self._epoch,
                                       self._py_step, epoch_loss, val_score):
-                    self._save_snapshot()
+                    with tracer.span("fit/checkpoint"):
+                        self._save_snapshot()
         finally:
             if profile_window is not None:
                 profile_window.close()
@@ -996,7 +1079,7 @@ class JaxEstimator:
 
     def _run_epoch(self, ds, mesh, batch_size, shuffle, summary_interval,
                    writer, checkpoint_trigger, steps_per_loop: int = 1,
-                   cache: Optional[str] = None, step_prof=None,
+                   cache: Optional[str] = None,
                    profile_window=None) -> float:
         if cache == "device":
             return self._run_epoch_cached(ds, mesh, batch_size, shuffle,
@@ -1004,7 +1087,7 @@ class JaxEstimator:
         if cache is not None:
             raise ValueError(f"unknown cache mode {cache!r} "
                              "(supported: 'device')")
-        import jax
+        step_prof = self._step_prof
         losses: List[Any] = []
         pending: List[Any] = []
         pending_steps = 0
@@ -1017,21 +1100,26 @@ class JaxEstimator:
             nonlocal pending, pending_steps, t_window
             if not pending:
                 return
-            t_fetch = time.perf_counter()
-            vals = list(np.concatenate(
-                [np.atleast_1d(np.asarray(v))
-                 for v in telemetry.traced_device_get(pending)]
-            ).astype(float))
-            telemetry.observe_device_block(
-                time.perf_counter() - t_fetch, "train_flush")
-            losses.extend(vals)
-            step = self._py_step
-            writer.add_scalar("Loss", vals[-1], step)
-            dt = time.perf_counter() - t_window
-            throughput = pending_steps * batch_size / max(dt, 1e-9)
-            writer.add_scalar("Throughput", throughput, step)
-            self._mirror_train_scalars(writer, step, vals[-1], throughput,
-                                       dt / max(pending_steps, 1))
+            with step_prof.phase("epoch/flush", "flush"):
+                t_fetch = time.perf_counter()
+                vals = list(np.concatenate(
+                    [np.atleast_1d(np.asarray(v))
+                     for v in telemetry.traced_device_get(pending)]
+                ).astype(float))
+                telemetry.observe_device_block(
+                    time.perf_counter() - t_fetch, "train_flush")
+                losses.extend(vals)
+                step = self._py_step
+                writer.add_scalar("Loss", vals[-1], step)
+                dt = time.perf_counter() - t_window
+                throughput = pending_steps * batch_size / max(dt, 1e-9)
+                writer.add_scalar("Throughput", throughput, step)
+                self._mirror_train_scalars(writer, step, vals[-1],
+                                           throughput,
+                                           dt / max(pending_steps, 1))
+                # the fetch above was the sync: MFU, HBM and seconds per
+                # step over this window cost no fence of their own
+                step_prof.observe_window(pending_steps, dt)
             t_window = time.perf_counter()
             pending = []
             pending_steps = 0
@@ -1056,81 +1144,52 @@ class JaxEstimator:
                     flush_window()
                     self._save_snapshot()
 
-        # the per-step profiler decomposes each loop into data-wait (the
-        # next() on the device iterator), dispatch (the async jitted
-        # call), device (dispatch→ready, measured by fencing — sampled
-        # steps only, so the dispatch overlap survives) and callback
-        # (summary flush / checkpoint triggers)
-        if steps_per_loop > 1:
+        # each loop is timed on the host as data-wait (the next() on the
+        # device iterator), dispatch (the async jitted call) and callback
+        # (summary flush / checkpoint triggers); nothing waits for the
+        # device between two flushes
+        scan = steps_per_loop > 1
+        if scan:
             it = iter(ds.device_scan_iterator(
                 mesh, self.strategy, batch_size, steps_per_loop,
                 shuffle=shuffle, seed=self.seed, epoch=self._epoch))
-            while True:
-                t0 = time.perf_counter()
-                try:
-                    x, y, k = next(it)
-                except StopIteration:
-                    break
-                t1 = time.perf_counter()
-                sampled = step_prof is not None and \
-                    step_prof.should_sample(self._py_step)
-                # fault-injection step seam: one arrival per compiled
-                # train dispatch (a fused scan counts once)
-                resilience.maybe_fault("step")
-                self._state, loop_losses = self._train_scan(self._state,
-                                                            (x, y))
-                t2 = time.perf_counter()
-                device_s = None
-                if sampled:
-                    step_prof.ensure_flops(
-                        lambda: profiling_lib.compiled_step_flops(
-                            self._train_scan, self._state, (x, y)),
-                        per_steps=k)
-                    jax.block_until_ready(loop_losses)
-                    device_s = time.perf_counter() - t1
-                pending.append(loop_losses)
-                t3 = time.perf_counter()
-                after_steps(k)
-                if step_prof is not None:
-                    step_prof.observe_step(
-                        self._py_step, t0, t1 - t0, t2 - t1, device_s,
-                        time.perf_counter() - t3, n_steps=k)
-                if profile_window is not None:
-                    profile_window.on_step(self._py_step)
         else:
             it = iter(ds.device_iterator(mesh, self.strategy, batch_size,
                                          shuffle=shuffle, seed=self.seed,
                                          epoch=self._epoch,
                                          drop_remainder=True))
-            while True:
-                t0 = time.perf_counter()
-                try:
-                    x, y, _ = next(it)
-                except StopIteration:
-                    break
-                t1 = time.perf_counter()
-                sampled = step_prof is not None and \
-                    step_prof.should_sample(self._py_step)
-                # fault-injection step seam: one arrival per train step
-                resilience.maybe_fault("step")
-                self._state, logs = self._train_step(self._state, x, y)
-                t2 = time.perf_counter()
-                device_s = None
-                if sampled:
-                    step_prof.ensure_flops(
-                        lambda: profiling_lib.compiled_step_flops(
-                            self._train_step, self._state, x, y))
-                    jax.block_until_ready(logs["loss"])
-                    device_s = time.perf_counter() - t1
-                pending.append(logs["loss"])
-                t3 = time.perf_counter()
-                after_steps(1)
-                if step_prof is not None:
-                    step_prof.observe_step(
-                        self._py_step, t0, t1 - t0, t2 - t1, device_s,
-                        time.perf_counter() - t3)
-                if profile_window is not None:
-                    profile_window.on_step(self._py_step)
+
+        def next_batch():
+            with telemetry.annotation("data_wait"):
+                return next(it, None)
+
+        t0 = time.perf_counter()
+        # the epoch's first batch pays for the shuffle and the staging
+        with step_prof.phase("epoch/first_batch", "first_batch"):
+            batch = next_batch()
+        while batch is not None:
+            t1 = time.perf_counter()
+            x, y, k = batch
+            n_steps = k if scan else 1
+            # fault-injection step seam: one arrival per compiled train
+            # dispatch (a fused scan counts once)
+            resilience.maybe_fault("step")
+            with telemetry.annotation("dispatch"):
+                if scan:
+                    self._state, loop_losses = self._train_scan(self._state,
+                                                                (x, y))
+                else:
+                    self._state, logs = self._train_step(self._state, x, y)
+                    loop_losses = logs["loss"]
+            t2 = time.perf_counter()
+            pending.append(loop_losses)
+            after_steps(n_steps)
+            step_prof.observe_step(self._py_step, t0, t1 - t0, t2 - t1,
+                                   time.perf_counter() - t2)
+            if profile_window is not None:
+                profile_window.on_step(self._py_step)
+            t0 = time.perf_counter()
+            batch = next_batch()
         flush_window()
         dt = time.perf_counter() - t_epoch
         logger.info("epoch %d: %d samples in %.2fs (%.0f samples/s)",

@@ -44,8 +44,8 @@ from analytics_zoo_tpu.common.slo import (  # noqa: F401  (re-exports)
 from analytics_zoo_tpu.common.slo import get_monitor as get_slo_monitor  # noqa: F401
 from analytics_zoo_tpu.common.profiling import (  # noqa: F401  (re-exports)
     FlightRecorder, StepProfiler, backend_state, chrome_trace,
-    compiled_step_flops, device_peak_flops, dump_trace, get_flight_recorder,
-    hbm_bytes, maybe_arm_from_env,
+    device_peak_flops, dump_trace, get_flight_recorder, hbm_bytes,
+    maybe_arm_from_env, scope_index,
 )
 from analytics_zoo_tpu.common.telemetry import (  # noqa: F401  (re-exports)
     MetricsRegistry, Span, Tracer, bench_snapshot, get_registry, get_tracer,
@@ -60,7 +60,7 @@ __all__ = [
     "observe_device_block", "timed_block_until_ready",
     "chrome_trace", "dump_trace", "StepProfiler", "FlightRecorder",
     "get_flight_recorder", "maybe_arm_from_env", "backend_state",
-    "compiled_step_flops", "device_peak_flops", "hbm_bytes",
+    "scope_index", "device_peak_flops", "hbm_bytes",
     "BucketLadder", "ExecutableCache", "configure_persistent_cache",
     "WARMUP_TRACE_ID",
     "merge_snapshot", "fleet_registry", "ReplicaRegistry", "ReplicaInfo",

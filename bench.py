@@ -129,10 +129,13 @@ def measure_ncf() -> dict:
 
 
 def _step_flops(train_step, state, x, y):
-    """XLA's own FLOP count for one compiled optimizer step (shared with
-    the estimator's zoo_step_flops/zoo_mfu gauges)."""
-    from analytics_zoo_tpu.common.profiling import compiled_step_flops
-    return compiled_step_flops(train_step, state, x, y)
+    """XLA's own FLOP count for one compiled optimizer step; ``None``
+    when the backend exposes no cost analysis."""
+    try:
+        cost = train_step.lower(state, x, y).compile().cost_analysis()
+        return float(cost.get("flops", 0.0)) or None
+    except Exception:
+        return None
 
 
 def _put_data_sharded(mesh, arr):

@@ -100,13 +100,12 @@ class TestChromeTrace:
 
 class TestStepProfiler:
     def test_mfu_is_exact_for_known_inputs(self):
-        """MFU = flops x n_steps / fenced device seconds / chip peak —
+        """MFU = flops x n_steps / a flush window's seconds / chip peak —
         checked against hand-computed values, no hardware involved."""
         prof = profiling.StepProfiler(name="t", sample_every=1,
                                       peak_flops=1e10)
         prof.set_flops(1e9)
-        prof.observe_step(0, t_start=0.0, data_wait_s=0.01,
-                          dispatch_s=0.001, device_s=0.5)
+        prof.observe_window(n_steps=1, seconds=0.5)
         snap = telemetry.snapshot()
         assert snap["zoo_step_flops"] == 1e9
         assert snap["zoo_mfu"] == pytest.approx(1e9 / 0.5 / 1e10)
@@ -114,21 +113,39 @@ class TestStepProfiler:
         prof2 = profiling.StepProfiler(name="t2", sample_every=1,
                                        peak_flops=1e10)
         prof2.set_flops(4e9, per_steps=4)
-        prof2.observe_step(0, 0.0, 0.01, 0.001, device_s=0.5, n_steps=4)
+        prof2.observe_window(n_steps=4, seconds=0.5)
         assert telemetry.snapshot()["zoo_mfu"] == pytest.approx(
             4 * 1e9 / 0.5 / 1e10)
 
-    def test_compiled_flops_match_hand_computed_matmul(self):
-        """cost_analysis() agrees with the textbook 2mnk FLOPs of a
-        matmul — the MFU numerator is real, not a heuristic."""
+    def test_executable_flops_match_hand_computed_matmul(self):
+        """The FLOP count kept of an ahead-of-time executable is XLA's
+        cost_analysis(), which agrees with the textbook 2mnk of a matmul
+        — the MFU numerator is real, not a heuristic — and building the
+        same jitted function for the same signature again reads nothing
+        anew."""
         import jax
         import jax.numpy as jnp
 
+        from analytics_zoo_tpu.common import compile_ahead
+
         f = jax.jit(lambda a, b: a @ b)
-        a = jnp.zeros((8, 16), jnp.float32)
-        b = jnp.zeros((16, 4), jnp.float32)
-        flops = profiling.compiled_step_flops(f, a, b)
-        assert flops == pytest.approx(2 * 8 * 16 * 4)
+        avals = (jax.ShapeDtypeStruct((8, 16), jnp.float32),
+                 jax.ShapeDtypeStruct((16, 4), jnp.float32))
+        cache = compile_ahead.ExecutableCache(f, name="matmul")
+        assert cache.flops is None
+        assert cache.warm(*avals)
+        assert cache.flops == pytest.approx(2 * 8 * 16 * 4)
+        held = profiling._executables["matmul"]
+        assert "dot(" in held.hlo_text or "dot." in held.hlo_text
+        again = compile_ahead.ExecutableCache(f, name="matmul")
+        assert again.warm(*avals)
+        assert again.flops == cache.flops
+        assert profiling._executables["matmul"] is held
+        # another signature of the same name replaces what is held
+        wider = (avals[0], jax.ShapeDtypeStruct((16, 8), jnp.float32))
+        assert again.warm(*wider)
+        assert again.flops == pytest.approx(2 * 8 * 16 * 8)
+        assert profiling._executables["matmul"] is not held
 
     def test_no_peak_means_no_mfu(self):
         """Unknown chip (CPU): MFU is never published from a made-up
@@ -137,10 +154,12 @@ class TestStepProfiler:
                                       peak_flops=None)
         assert prof.peak_flops is None   # CPU: not in the table, no env
         prof.set_flops(1e9)
-        prof.observe_step(0, 0.0, 0.01, 0.001, device_s=0.5)
+        prof.observe_window(n_steps=2, seconds=0.5)
         snap = telemetry.snapshot()
         assert snap["zoo_step_flops"] == 1e9
         assert "zoo_mfu" not in snap
+        assert snap["zoo_train_phase_seconds"]["phase=device"]["sum"] \
+            == pytest.approx(0.25)
 
     def test_env_peak_override(self, monkeypatch):
         monkeypatch.setenv("BENCH_PEAK_FLOPS", "2.5e12")
@@ -153,35 +172,47 @@ class TestStepProfiler:
         assert [prof.should_sample(s) for s in range(5)] == \
             [True, False, False, False, True]
         for step in range(8):
-            dev = 0.2 if prof.should_sample(step) else None
-            prof.observe_step(step, 0.0, 0.01, 0.001, device_s=dev,
-                              callback_s=0.002)
+            prof.observe_step(step, 0.0, 0.01, 0.001, callback_s=0.002)
+        prof.observe_window(n_steps=8, seconds=1.6)
+        with prof.phase("epoch/flush", "flush"):
+            pass
         snap = telemetry.snapshot()
         h = snap["zoo_train_phase_seconds"]
         assert h["phase=data_wait"]["count"] == 8
         assert h["phase=dispatch"]["count"] == 8
         assert h["phase=callback"]["count"] == 8
-        # device time only exists on fenced (sampled) steps
-        assert h["phase=device"]["count"] == 2
+        # seconds per step over a flush window: one sample per window
+        assert h["phase=device"]["count"] == 1
+        assert h["phase=device"]["sum"] == pytest.approx(0.2)
+        assert h["phase=flush"]["count"] == 1
+        # only the sampled steps left a trace
+        held = [t for t in telemetry.get_tracer().traces()
+                if t.startswith("t/step-")]
+        assert held == ["t/step-0", "t/step-4"]
+        # the phase is a tracer span too, under the profiler's own trace
+        # id when no span is open around it
+        assert [s.name for s in telemetry.get_tracer().get("t")] \
+            == ["epoch/flush"]
 
     def test_sampled_step_trace_decomposition(self):
         """Sampled steps land in the tracer as a step span with
-        contiguous data_wait/dispatch/device/callback children — the
-        training analogue of the serving trace, chrome-exportable."""
+        contiguous data_wait/dispatch/callback children — the training
+        analogue of the serving trace, chrome-exportable. No child
+        claims the device's time: nothing in the loop waits for it."""
         prof = profiling.StepProfiler(name="train", sample_every=1)
         prof.observe_step(7, t_start=50.0, data_wait_s=0.010,
-                          dispatch_s=0.002, device_s=0.100,
-                          callback_s=0.005)
+                          dispatch_s=0.002, callback_s=0.005)
         spans = {s.name: s for s in
                  telemetry.get_tracer().get("train/step-7")}
-        assert set(spans) == {"step", "data_wait", "dispatch", "device",
-                              "callback"}
+        assert set(spans) == {"step", "data_wait", "dispatch", "callback"}
         assert spans["data_wait"].start == pytest.approx(50.0)
         assert spans["data_wait"].end == pytest.approx(50.010)
-        assert spans["device"].start == pytest.approx(50.010)
-        assert spans["device"].end == pytest.approx(50.110)
+        assert spans["dispatch"].start == pytest.approx(50.010)
+        assert spans["dispatch"].end == pytest.approx(50.012)
+        assert spans["callback"].start == pytest.approx(50.012)
         assert spans["callback"].end == spans["step"].end
-        for name in ("data_wait", "dispatch", "device", "callback"):
+        assert spans["step"].end == pytest.approx(50.017)
+        for name in ("data_wait", "dispatch", "callback"):
             assert spans[name].parent == "step"
         xs = [e for e in profiling.chrome_trace("train/step-7")
               ["traceEvents"] if e["ph"] == "X"]
@@ -197,24 +228,26 @@ class TestStepProfiler:
         assert src in ("live_arrays", "memory_stats")
         assert n is not None and n >= keep.nbytes
         prof = profiling.StepProfiler(sample_every=1)
-        prof.observe_step(0, 0.0, 0.01, 0.001, device_s=0.1)
+        prof.observe_window(n_steps=1, seconds=0.1)
         hbm = telemetry.snapshot()["zoo_hbm_bytes"]
         assert hbm[f"source={src}"] >= keep.nbytes
 
+    def test_a_profiler_outlives_a_registry_reset(self):
+        """The estimator keeps one profiler for as long as its step
+        lives; metrics are looked up in the registry of the moment."""
+        prof = profiling.StepProfiler(name="t", peak_flops=1e10)
+        prof.set_flops(1e9)
+        telemetry.reset_for_tests()
+        prof.observe_window(n_steps=1, seconds=0.5)
+        assert telemetry.snapshot()["zoo_mfu"] == pytest.approx(0.2)
+
 
 class TestFitPublishesProfileMetrics:
-    def test_fit_publishes_flops_mfu_hbm(self, orca_ctx, tmp_path,
-                                         monkeypatch):
-        """End to end through the estimator: fit() publishes
-        zoo_step_flops (from the compiled step's cost_analysis), zoo_mfu
-        (peak injected via env — CPU has none), zoo_hbm_bytes, and the
-        phase histogram, all visible in the Prometheus exposition."""
+    def _fit_tiny(self, tmp_path, **fit_args):
         import flax.linen as nn
 
         from analytics_zoo_tpu.learn.estimator import Estimator
         from analytics_zoo_tpu.learn.optimizers import Adam
-
-        monkeypatch.setenv("BENCH_PEAK_FLOPS", "1e12")
 
         class Tiny(nn.Module):
             @nn.compact
@@ -227,23 +260,88 @@ class TestFitPublishesProfileMetrics:
         est = Estimator.from_flax(model=Tiny(), loss="mse",
                                   optimizer=Adam(1e-2), sample_input=x[:2],
                                   model_dir=str(tmp_path / "m"))
-        est.fit((x, y), epochs=2, batch_size=32)
+        est.fit((x, y), epochs=2, batch_size=8, **fit_args)
+        return est
+
+    def test_fit_publishes_flops_mfu_hbm(self, orca_ctx, tmp_path,
+                                         monkeypatch):
+        """End to end through the estimator: fit() publishes
+        zoo_step_flops (from the ahead-of-time executable's
+        cost_analysis), zoo_mfu (peak injected via env — CPU has none),
+        zoo_hbm_bytes, and the phase histogram, all visible in the
+        Prometheus exposition."""
+        monkeypatch.setenv("BENCH_PEAK_FLOPS", "1e12")
+        est = self._fit_tiny(tmp_path, summary_interval=4)
+        est._precompile_thread.join(timeout=60)
+        assert not est._precompile_thread.is_alive()
+        # one more epoch: its flushes find the executable's flops set
+        x = np.zeros((16, 4), np.float32)
+        est.fit((x, x[:, :1]), epochs=1, batch_size=8, summary_interval=2)
         snap = telemetry.snapshot()
         # XLA's optimized-HLO count for one fwd+bwd+adam step of this
         # tiny Dense; exact hand-computed checks are in TestStepProfiler
         assert 0 < snap["zoo_step_flops"] < 1e6
+        assert snap["zoo_step_flops"] == est._step_prof.flops
         assert 0 < snap["zoo_mfu"] < 1.0
-        assert snap["zoo_train_phase_seconds"]["phase=device"]["count"] >= 1
+        phases = snap["zoo_train_phase_seconds"]
+        # 8-step epochs flushed every 4 steps, then one 2-step epoch
+        assert phases["phase=data_wait"]["count"] == 18
+        assert phases["phase=dispatch"]["count"] == 18
+        assert phases["phase=flush"]["count"] == 5
+        assert phases["phase=device"]["count"] == 5
+        assert phases["phase=first_batch"]["count"] == 3
+        assert phases["phase=prepare"]["count"] == 2
         hbm = snap["zoo_hbm_bytes"]
         assert sum(hbm.values()) > 0
         text = telemetry.prometheus_text()
         assert "zoo_mfu " in text and "zoo_step_flops " in text
         assert 'zoo_hbm_bytes{source="' in text
-        # sampled training steps produced chrome-exportable traces
+        # sampled training steps produced chrome-exportable traces, and
+        # none of them pretends to hold the device's time
         xs = [e for e in profiling.chrome_trace()["traceEvents"]
-              if e["ph"] == "X"]
-        assert any(e["args"]["trace_id"].startswith("train/step-")
-                   and e["name"] == "device" for e in xs)
+              if e["ph"] == "X"
+              and e["args"]["trace_id"].startswith("train/step-")]
+        assert any(e["name"] == "dispatch" for e in xs)
+        assert not any(e["name"] == "device" for e in xs)
+        # the fit's own intervals, nested
+        fit = {s.name: s for s in telemetry.get_tracer().get("train/fit-0")}
+        assert {"fit", "fit/prepare", "epoch", "epoch/first_batch",
+                "epoch/flush", "fit/checkpoint"} <= set(fit)
+        assert fit["fit/prepare"].parent == "fit"
+        assert fit["epoch/flush"].parent == "epoch"
+        assert fit["fit"].start <= fit["fit/prepare"].start
+        assert fit["epoch"].end <= fit["fit"].end
+
+    def test_fit_never_fences_between_two_flushes(self, orca_ctx, tmp_path,
+                                                  monkeypatch):
+        """No block_until_ready, lowering or compile exists in the loop
+        only to measure: the device is waited for at a flush and nowhere
+        else, and zoo_mfu and zoo_step_flops are published all the
+        same."""
+        import jax
+
+        monkeypatch.setenv("BENCH_PEAK_FLOPS", "1e12")
+        est = self._fit_tiny(tmp_path, summary_interval=4)
+        est._precompile_thread.join(timeout=60)
+        fenced = []
+        real_fence = jax.block_until_ready
+        monkeypatch.setattr(
+            jax, "block_until_ready",
+            lambda x: fenced.append(1) or real_fence(x))
+        telemetry.reset_for_tests()
+        x = np.zeros((64, 4), np.float32)
+        est.fit((x, x[:, :1]), epochs=1, batch_size=8, summary_interval=4)
+        est._precompile_thread.join(timeout=60)
+        assert fenced == []
+        snap = telemetry.snapshot()
+        assert snap["zoo_train_phase_seconds"]["phase=flush"]["count"] == 2
+        assert "phase=device" in snap["zoo_train_phase_seconds"]
+        assert 0 < snap["zoo_mfu"] < 1.0
+        assert snap["zoo_step_flops"] > 0
+        # nor was the step lowered or compiled again, by the loop or by
+        # the warm-up thread (JAX's in-process caches answer both)
+        assert "zoo_compile_events_total" not in snap
+        assert not hasattr(profiling, "compiled_step_flops")
 
 
 class TestFlightRecorder:

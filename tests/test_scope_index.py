@@ -1,0 +1,315 @@
+"""Tracing from inside the program (ISSUE 27): the scope index of a
+compiled step, fit's spans on the profiler's clock, and the compile
+counters."""
+
+import glob
+import json
+import os
+
+import numpy as np
+import pytest
+
+from analytics_zoo_tpu.common import profiling, telemetry
+
+
+@pytest.fixture(autouse=True)
+def fresh_telemetry():
+    telemetry.reset_for_tests()
+    yield
+    telemetry.reset_for_tests()
+
+
+def _tiny_bert_estimator(clip: bool = False):
+    """The benchmark's tiny BERT classifier behind ``Estimator.from_flax``
+    and 32 rows to feed it."""
+    from analytics_zoo_tpu.learn.estimator import Estimator
+    from benchmarks.harness import tiny
+    from benchmarks.harness.manifest import ROOT
+    from benchmarks.models import bert as model_lib
+
+    cfg = json.loads((ROOT / "benchmarks" / "configs"
+                      / "bert-base.json").read_text())
+    cfg.update(tiny.TINY_CONFIG["bert"], compute_dtype="float32")
+    x, y = model_lib.make_inputs(cfg, {"seq_len": 16},
+                                 np.random.default_rng(0), 32)
+    est = Estimator.from_flax(model=model_lib.build_module(cfg),
+                              loss=model_lib.LOSS, optimizer="adam",
+                              sample_input=x[:2])
+    if clip:
+        est.set_l2_norm_gradient_clipping(1.0)
+    return est, x, y
+
+
+def _fit_and_wait(est, x, y, **fit_args):
+    est.fit((x, y), epochs=1, batch_size=8, **fit_args)
+    est._precompile_thread.join(timeout=300)
+    assert not est._precompile_thread.is_alive()
+
+
+# ------------------------------------------------------- the scope index
+
+@pytest.fixture
+def step_index(orca_ctx):
+    est, x, y = _tiny_bert_estimator(clip=True)
+    _fit_and_wait(est, x, y)
+    index = profiling.scope_index("estimator_train_step")
+    assert index
+    return index
+
+
+def _phases(index, pattern):
+    import re
+    rx = re.compile(pattern)
+    return {e["phase"] for e in index.values()
+            if e["scope"] and rx.search(e["scope"])}
+
+
+def test_scope_index_names_the_parts_of_the_step(step_index):
+    """Flax names the model's parts, fit's step names the rest; forward
+    and backward ops of one module share its scope and differ in phase."""
+    assert {"forward", "backward"} <= _phases(
+        step_index, r"block_\d+/attention(/|$)")
+    assert {"forward", "backward"} <= _phases(
+        step_index, r"block_\d+/intermediate$")
+    assert {"forward", "backward"} <= _phases(
+        step_index, r"block_\d+/output$")
+    assert _phases(step_index, r"^optimizer(/|$)") == {"optimizer"}
+    assert {"forward", "backward"} <= _phases(step_index, r"^loss(/|$)")
+    assert _phases(step_index, r"^clip(/|$)") == {"other"}
+    assert _phases(step_index, r"^metrics$") == {"other"}
+    # no wrapper of a transform is left in a scope, no primitive's name
+    for entry in step_index.values():
+        assert set(entry) == {"scope", "phase", "scopes", "opcode"}
+        for scope in entry["scopes"]:
+            assert "jit(" not in scope and "jvp(" not in scope
+            assert not scope.endswith("dot_general")
+        assert entry["phase"] in ("forward", "backward", "optimizer",
+                                  "other")
+    assert profiling.scope_index("no_such_executable") is None
+
+
+def test_scope_index_outlives_the_estimator_and_serializes(step_index):
+    import gc
+    gc.collect()            # the estimator of the fixture is gone
+    again = profiling.scope_index("estimator_train_step")
+    assert again is step_index          # parsed once
+    assert json.loads(json.dumps(again)) == again
+
+
+PLANTED = """\
+HloModule jit_step_fn, is_scheduled=true
+
+%fused_computation.1 (p0: f32[8,8], p1: f32[8,8], p2: f32[8,8]) -> f32[8,8] {
+  %p0 = f32[8,8]{1,0} parameter(0)
+  %p1 = f32[8,8]{1,0} parameter(1)
+  %p2 = f32[8,8]{1,0:T(8,128)S(1)} parameter(2)
+  %dot.7 = f32[8,8]{1,0} dot(f32[8,8]{1,0} %p0, f32[8,8]{1,0} %p1), lhs_contracting_dims={1}, rhs_contracting_dims={0}, metadata={op_name="jit(step_fn)/transpose(jvp(Classifier))/bert/block_0/output/dot_general" stack_frame_id=7}
+  ROOT %add.9 = f32[8,8]{1,0} add(f32[8,8]{1,0} %dot.7, f32[8,8]{1,0:T(8,128)S(1)} %p2), metadata={op_name="jit(step_fn)/optimizer/add"}
+}
+
+%fused_computation.2 (q0: f32[8,8]) -> f32[8,8] {
+  %q0 = f32[8,8]{1,0} parameter(0)
+  ROOT %exp.3 = f32[8,8]{1,0} exponential(f32[8,8]{1,0} %q0), metadata={op_name="jit(step_fn)/jvp(Classifier)/bert/block_0/attention/exp"}
+}
+
+%body.4 (carry: (s32[], f32[8,8])) -> (s32[], f32[8,8]) {
+  %carry = (s32[], f32[8,8]{1,0}) parameter(0)
+  %gte.1 = f32[8,8]{1,0} get-tuple-element((s32[], f32[8,8]{1,0}) %carry), index=1
+  %negate.5 = f32[8,8]{1,0} negate(f32[8,8]{1,0} %gte.1), metadata={op_name="jit(step_fn)/jvp(Classifier)/bert/Dropout_0/while/body/neg"}
+  ROOT %tuple.6 = (s32[], f32[8,8]{1,0}) tuple(s32[] %c, f32[8,8]{1,0} %negate.5)
+}
+
+ENTRY %main.10 (a: f32[8,8], b: f32[8,8], c: f32[8,8]) -> f32[8,8] {
+  %a = f32[8,8]{1,0} parameter(0), metadata={op_name="state[\\'params\\'][\\'w\\']"}
+  %b = f32[8,8]{1,0} parameter(1)
+  %c = f32[8,8]{1,0} parameter(2)
+  %fusion.12 = f32[8,8]{1,0} fusion(f32[8,8]{1,0} %a, f32[8,8]{1,0} %b, f32[8,8]{1,0} %c), kind=kOutput, calls=%fused_computation.1, metadata={op_name="jit(step_fn)/optimizer/add"}
+  %exp_fusion = (f32[8,8]{1,0:T(8,128)S(1)}, f32[8,8]{1,0}) fusion(f32[8,8]{1,0} %fusion.12), kind=kLoop, calls=%fused_computation.2
+  %copy-start.3 = (f32[8,8]{1,0}, f32[8,8]{1,0}, u32[]) copy-start(f32[8,8]{1,0} %b)
+  %copy-done.3 = f32[8,8]{1,0} copy-done((f32[8,8]{1,0}, f32[8,8]{1,0}, u32[]) %copy-start.3)
+  %bitcast.4 = f32[64]{0} bitcast(f32[8,8]{1,0} %copy-done.3)
+  %fusion.13 = f32[64]{0} fusion(f32[64]{0} %bitcast.4), kind=kLoop, calls=%fused_computation.2
+  %copy-start.5 = (f32[8,8]{1,0}, f32[8,8]{1,0}, u32[]) copy-start(f32[8,8]{1,0} %fusion.12)
+  %copy-done.5 = f32[8,8]{1,0} copy-done((f32[8,8]{1,0}, f32[8,8]{1,0}, u32[]) %copy-start.5)
+  %iota.6 = s32[8]{0} iota(), iota_dimension=0
+  %while.8 = (s32[], f32[8,8]{1,0}) while((s32[], f32[8,8]{1,0}) %t), condition=%cond.5, body=%body.4
+  ROOT %neg.2 = f32[8,8]{1,0} negate(f32[8,8]{1,0} %exp_fusion), metadata={op_name="jit(step_fn)/jit(_where)/neg"}
+}
+"""
+
+
+def test_a_planted_two_scope_fusion_counts_with_its_product_and_is_mixed():
+    index = profiling.parse_scope_index(PLANTED)
+    # the weight-gradient product fused with the optimizer's update
+    fused = index["fusion.12"]
+    assert fused["scope"] == "Classifier/bert/block_0/output"
+    assert fused["phase"] == "backward"
+    assert fused["scopes"] == ["Classifier/bert/block_0/output",
+                               "optimizer"]
+    # no product inside: the fused computation's root names it, though
+    # the fusion instruction itself carries no metadata and a tuple shape
+    assert index["exp_fusion"] == {
+        "scope": "Classifier/bert/block_0/attention", "phase": "forward",
+        "scopes": ["Classifier/bert/block_0/attention"],
+        "opcode": "fusion"}
+    # a weight brought in ahead of its use, and the wait for it: nameless,
+    # so they count with the nearest named consumer of the result ...
+    for name in ("copy-start.3", "copy-done.3"):
+        assert index[name]["scope"] == "Classifier/bert/block_0/attention"
+        assert index[name]["phase"] == "forward"
+        assert index[name]["scopes"] == []
+    # ... a result copied out of the step, with what produced it ...
+    assert index["copy-done.5"]["scope"] == "Classifier/bert/block_0/output"
+    assert index["copy-done.5"]["phase"] == "backward"
+    # ... and what has neither is known, without scope
+    assert index["iota.6"]["scope"] is None
+    assert index["iota.6"]["scopes"] == []
+    # directly under jit(step_fn), inside another jitted helper: no scope
+    assert index["neg.2"]["scope"] == "" and index["neg.2"]["phase"] == "other"
+    # a container's body runs as ops of its own and is indexed ...
+    assert index["while.8"]["opcode"] == "while"
+    assert index["negate.5"]["scope"] \
+        == "Classifier/bert/Dropout_0/while/body"
+    # ... what runs inside a fusion is not, nor what takes no device time
+    assert not {"dot.7", "add.9", "exp.3", "a", "gte.1", "tuple.6",
+                "bitcast.4"} & set(index)
+
+
+def test_profile_window_leaves_the_scope_index_beside_its_trace(
+        orca_ctx, tmp_path):
+    est, x, y = _tiny_bert_estimator()
+    est.set_tensorboard(str(tmp_path), "run")
+    _fit_and_wait(est, x, y)            # builds the step ahead of time
+    est.fit((x, y), epochs=1, batch_size=8, profile_steps=(1, 3))
+    runs = glob.glob(os.path.join(est._tb_dirs[0], "plugins", "profile",
+                                  "*"))
+    assert len(runs) == 1
+    assert glob.glob(os.path.join(runs[0], "*.xplane.pb"))
+    with open(os.path.join(runs[0], "scope_index.json")) as fh:
+        written = json.load(fh)
+    assert list(written) == ["estimator_train_step"]
+    assert written["estimator_train_step"] \
+        == profiling.scope_index("estimator_train_step")
+
+
+def test_a_jitted_step_is_compiled_under_its_stable_name():
+    """``jit_<name>`` names the compiled module, its events in a device
+    trace and its entry in the persistent cache — whose key leaves
+    metadata out, so a step whose named scopes changed must not share a
+    name with the build that compiled the old ones."""
+    import jax.numpy as jnp
+
+    def step_fn(state, x, double):
+        return state + (2 * x if double else x)
+
+    jitted = telemetry.instrument_jit(
+        step_fn, name="estimator_train_step", static_argnums=(2,))
+    a = jnp.ones(3)
+    assert float(jitted(a, a, True)[0]) == 3.0
+    assert "module @jit_estimator_train_step" in \
+        jitted.lower(a, a, True).as_text()
+    assert step_fn.__name__ == "step_fn"        # the caller's is untouched
+    by_keyword = telemetry.instrument_jit(name="other",
+                                          static_argnames=("double",))
+    assert float(by_keyword(step_fn)(a, a, double=False)[0]) == 2.0
+    # without a name of its own the function's is kept
+    bare = telemetry.instrument_jit(step_fn, static_argnums=(2,))
+    assert "module @jit_step_fn" in bare.lower(a, a, True).as_text()
+
+
+# ------------------------------------------- spans on the profiler's clock
+
+def _host_annotations(log_dir, prefixes=("zoo:", "test:")):
+    import jax
+    found = sorted(glob.glob(os.path.join(
+        log_dir, "plugins", "profile", "*", "*.xplane.pb")))
+    data = jax.profiler.ProfileData.from_file(found[-1])
+    spans = []
+    for plane in data.planes:
+        for line in plane.lines:
+            for ev in line.events:
+                if ev.name.startswith(prefixes):
+                    spans.append((ev.name, ev.start_ns,
+                                  ev.start_ns + ev.duration_ns))
+    return spans
+
+
+def test_fit_under_a_profiler_session_leaves_its_spans_in_the_xplane(
+        orca_ctx, tmp_path):
+    import jax
+    est, x, y = _tiny_bert_estimator()
+    _fit_and_wait(est, x, y)
+    with jax.profiler.trace(str(tmp_path)):
+        with jax.profiler.TraceAnnotation("test:traced"):
+            est.fit((x, y), epochs=1, batch_size=8, summary_interval=2)
+    spans = _host_annotations(str(tmp_path))
+    by_name = {}
+    for name, lo, hi in spans:
+        by_name.setdefault(name, []).append((lo, hi))
+    assert len(by_name["test:traced"]) == 1 and len(by_name["zoo:fit"]) == 1
+    assert len(by_name["zoo:epoch"]) == 1
+    assert len(by_name["zoo:epoch/first_batch"]) == 1
+    assert len(by_name["zoo:epoch/flush"]) == 2     # 4 steps, every 2
+    assert len(by_name["zoo:dispatch"]) == 4
+    assert len(by_name["zoo:data_wait"]) == 5       # the last finds no batch
+    assert len(by_name["zoo:fit/prepare"]) == 1
+
+    def inside(inner, outer):
+        return all(any(lo >= a and hi <= b for a, b in by_name[outer])
+                   for lo, hi in by_name[inner])
+
+    assert inside("zoo:fit", "test:traced")
+    assert inside("zoo:fit/prepare", "zoo:fit")
+    assert inside("zoo:epoch", "zoo:fit")
+    for name in ("zoo:epoch/first_batch", "zoo:epoch/flush",
+                 "zoo:dispatch", "zoo:data_wait"):
+        assert inside(name, "zoo:epoch"), name
+    # the first wait for data is the first-batch interval's
+    first_wait = min(by_name["zoo:data_wait"])
+    (fb,) = by_name["zoo:epoch/first_batch"]
+    assert fb[0] <= first_wait[0] and first_wait[1] <= fb[1]
+
+
+def test_tracer_span_annotates_only_once_jax_is_imported(monkeypatch):
+    import contextlib
+    import sys
+    assert type(telemetry.annotation("x")).__name__ == "TraceAnnotation"
+    monkeypatch.delitem(sys.modules, "jax")
+    assert isinstance(telemetry.annotation("x"), contextlib.nullcontext)
+    with telemetry.get_tracer().span("load", "job-1"):
+        pass
+    assert [s.name for s in telemetry.get_tracer().get("job-1")] == ["load"]
+
+
+# ------------------------------------------------------ compile counters
+
+def test_compile_counters_rise_on_a_new_shape_and_not_on_a_repeat(orca_ctx):
+    import jax
+
+    def counts():
+        snap = telemetry.snapshot()
+        events = snap.get("zoo_compile_events_total", {})
+        seconds = snap.get("zoo_compile_seconds_total", {})
+        cache = snap.get("zoo_compile_cache_total", {})
+        return (events.get("stage=lower", 0),
+                events.get("stage=backend_compile", 0),
+                seconds.get("stage=lower", 0.0),
+                sum(cache.values()))
+
+    # numpy inputs: making a jax array would compile a program of its own
+    f = jax.jit(lambda a: (a * 3.0 + 1.0).sum())
+    before = counts()
+    f(np.ones((5, 7), np.float32)).block_until_ready()
+    first = counts()
+    assert first[0] == before[0] + 1 and first[1] == before[1] + 1
+    assert first[2] > before[2]
+    assert first[3] == before[3] + 1        # one hit or one miss
+    f(np.zeros((5, 7), np.float32)).block_until_ready()
+    assert counts() == first                # the same shape: nothing
+    f(np.ones((6, 7), np.float32)).block_until_ready()
+    again = counts()
+    assert again[0] == first[0] + 1 and again[1] == first[1] + 1
+    # one listener per process, however often a context is made
+    telemetry.install_compile_counters()
+    f(np.ones((7, 7), np.float32)).block_until_ready()
+    assert counts()[0] == again[0] + 1

@@ -130,7 +130,9 @@ def test_host_sync_requires_hot_function_and_loop():
     assert _scan(src) == []
 
 
-def test_host_sync_sampling_guard_exempts():
+def test_host_sync_sampling_guard_does_not_exempt():
+    """A fence on sampled steps only is still a fence: the fit loop's
+    every-tenth-step one was a third of the device's idle time (PR 27)."""
     src = """
     import jax
     def run_epoch(steps, profiler):
@@ -138,7 +140,7 @@ def test_host_sync_sampling_guard_exempts():
             if profiler.should_sample():
                 jax.block_until_ready(s)
     """
-    assert _scan(src) == []
+    assert _rules_of(_scan(src)) == ["hotpath-host-sync"]
 
 
 def test_host_sync_float_of_literal_ok():
