@@ -374,14 +374,16 @@ class ExecutableCache:
             self._inflight.add(sig)
         try:
             t0 = perf_counter()
-            exe = self._jitted.lower(*avals).compile()
+            lowered = self._jitted.lower(*avals)
+            exe = lowered.compile()
             t1 = perf_counter()
             self._compile_hist.observe(t1 - t0)
             self._tracer.record(WARMUP_TRACE_ID, "compile", t0, t1)
-            # the process keeps the executable's HLO text and FLOP count
-            # under this cache's name: profiling.scope_index reads them
+            # the process keeps the executable's HLO text, FLOP count
+            # and held-value counts under this cache's name:
+            # profiling.scope_index and step_counts read them
             flops = profiling.note_executable(
-                self.name, exe, fn=self._jitted, sig=sig)
+                self.name, exe, fn=self._jitted, sig=sig, lowered=lowered)
             with self._lock:
                 self._execs[sig] = exe
                 self.flops = flops

@@ -24,6 +24,9 @@ wedged. This module adds the four missing pieces:
   ``optimizer``, ``loss`` ...) and which phase each instruction of the
   optimized HLO belongs to. A device trace names its op events by
   instruction; the index turns them into time by part of the model.
+  Beside it :func:`step_counts`: how many values the program holds behind
+  an optimization barrier (``ops/hold.py``) and how many times the
+  compiled program evaluates an ``erfc`` and generates a dropout mask.
 - **FlightRecorder** — bounded ring buffer of recent spans + notes that
   dumps a postmortem JSON (spans, metrics snapshot, env, backend state)
   to ``zoo_tpu_logs/`` on SIGTERM or on demand. Arm with
@@ -61,6 +64,7 @@ __all__ = [
     "FlightRecorder", "get_flight_recorder", "maybe_arm_from_env",
     "backend_state", "DUMP_DIR", "reset_for_tests",
     "note_executable", "scope_index", "parse_scope_index",
+    "step_counts", "count_elementwise_evals",
 ]
 
 logger = logging.getLogger(__name__)
@@ -318,11 +322,11 @@ class _Executable:
     estimator that built it are gone, and nothing that holds device
     memory."""
 
-    __slots__ = ("fn", "sig", "hlo_text", "flops", "index")
+    __slots__ = ("fn", "sig", "hlo_text", "flops", "counts", "index")
 
-    def __init__(self, fn, sig, hlo_text, flops):
+    def __init__(self, fn, sig, hlo_text, flops, counts):
         self.fn, self.sig = fn, sig
-        self.hlo_text, self.flops = hlo_text, flops
+        self.hlo_text, self.flops, self.counts = hlo_text, flops, counts
         self.index: Optional[Dict[str, dict]] = None
 
 
@@ -330,26 +334,35 @@ _executables: Dict[str, _Executable] = {}
 _executables_lock = threading.Lock()
 
 
-def note_executable(name: str, exe, fn=None, sig=None) -> Optional[float]:
+def note_executable(name: str, exe, fn=None, sig=None,
+                    lowered=None) -> Optional[float]:
     """Keep the optimized HLO text and XLA's FLOP count of ``exe``, just
-    compiled under ``name`` (the newest executable of a name wins);
-    returns the FLOP count. ``ExecutableCache`` calls this after every
-    build. Reading the text of a whole train step takes most of a second,
-    so a rebuild of the same jitted ``fn`` for the same signature — every
-    ``fit`` call warms its step again — keeps what is held. Never raises:
-    a compile must not fail for tracing's sake."""
+    compiled under ``name`` (the newest executable of a name wins), count
+    what :func:`step_counts` answers — the held values in the text of
+    ``lowered``, the ``jax.stages.Lowered`` that ``exe`` was compiled
+    from, the evaluations in the optimized text — and publish the counts
+    as gauges; returns the FLOP count. ``ExecutableCache`` calls this
+    after every build. Reading the text of a whole train step takes most
+    of a second, so a rebuild of the same jitted ``fn`` for the same
+    signature — every ``fit`` call warms its step again — keeps what is
+    held. Never raises: a compile must not fail for tracing's sake."""
     with _executables_lock:
         held = _executables.get(name)
-        if held is not None and fn is not None and held.fn is not None \
-                and held.fn() is fn and held.sig == sig:
-            return held.flops
+    if held is not None and fn is not None and held.fn is not None \
+            and held.fn() is fn and held.sig == sig:
+        _publish_counts(name, held.counts)
+        return held.flops
     text = flops = None
+    counts: Dict[str, int] = {}
     try:
         text = exe.as_text()
         cost = exe.cost_analysis()
         if isinstance(cost, (list, tuple)):
             cost = cost[0]
         flops = float(cost.get("flops", 0.0)) or None
+        counts = count_elementwise_evals(text)
+        if lowered is not None:
+            counts["held_values"] = lowered.as_text().count(_BARRIER)
     except Exception:
         logger.debug("no HLO text or cost analysis for %s", name,
                      exc_info=True)
@@ -357,10 +370,66 @@ def note_executable(name: str, exe, fn=None, sig=None) -> Optional[float]:
         ref = weakref.ref(fn) if fn is not None else None
     except TypeError:        # a callable that takes no weak reference
         ref = None
-    rec = _Executable(ref, sig, text, flops)
+    rec = _Executable(ref, sig, text, flops, counts)
     with _executables_lock:
         _executables[name] = rec
+    _publish_counts(name, counts)
     return flops
+
+
+#: the operation ``jax.lax.optimization_barrier`` lowers to. The compilers
+#: expand it away at the end, so the optimized HLO shows no trace of it:
+#: it is counted in the program as lowered
+_BARRIER = "stablehlo.optimization_barrier"
+#: XLA expands ``erfc`` into two polynomial branches around ONE ``exp``,
+#: which keeps the primitive's ``op_name``
+_ERFC_EVAL = re.compile(r'\sexponential\(.*op_name="[^"]*/erfc"')
+#: ``jax.random.bernoulli`` ends in ONE comparison of the uniform draw
+#: with the keep probability
+_MASK_EVAL = re.compile(
+    r'\scompare\(.*op_name="[^"]*jit\(_bernoulli\)/lt"')
+
+
+def count_elementwise_evals(hlo_text: str) -> Dict[str, int]:
+    """``{"erfc": n, "mask": n}``: how many times one run of the optimized
+    HLO module evaluates the exact gelu's ``erfc`` and generates a dropout
+    keep-mask, fused computations included — a producer the compiler fused
+    into three products is counted three times, which is the point. (A
+    loop's body counts once however often it runs.)"""
+    return {"erfc": len(_ERFC_EVAL.findall(hlo_text)),
+            "mask": len(_MASK_EVAL.findall(hlo_text))}
+
+
+def _publish_counts(name: str, counts: Dict[str, int]) -> None:
+    reg = telemetry.get_registry()
+    if "held_values" in counts:
+        reg.gauge(
+            "zoo_step_held_values", "Values the program as lowered holds "
+            "behind an optimization barrier (ops/hold.py): one per exact "
+            "gelu and per active dropout site of a training step, none in "
+            "an inference program", ("executable",)
+        ).labels(name).set(counts["held_values"])
+    for kind in ("erfc", "mask"):
+        if kind in counts:
+            reg.gauge(
+                "zoo_step_elementwise_evals", "Times the compiled program "
+                "evaluates an erfc / generates a dropout mask; more than "
+                "zoo_step_held_values accounts for means the compiler "
+                "re-derives them inside their consumers",
+                ("executable", "kind")).labels(name, kind).set(counts[kind])
+
+
+def step_counts(name: str) -> Optional[Dict[str, int]]:
+    """``{"held_values", "erfc", "mask"}`` of the executable last compiled
+    ahead of time under ``name``: the optimization barriers of the program
+    as lowered (left out where the lowered program was not at hand) and
+    :func:`count_elementwise_evals` of its optimized HLO; ``None`` when
+    nothing was compiled under that name. The same numbers are the gauges
+    ``zoo_step_held_values{executable}`` and
+    ``zoo_step_elementwise_evals{executable,kind}``."""
+    with _executables_lock:
+        rec = _executables.get(name)
+    return dict(rec.counts) if rec is not None else None
 
 
 def scope_index(name: str) -> Optional[Dict[str, dict]]:

@@ -75,7 +75,8 @@ class _ProfileWindow:
     wrote, ``close()`` leaves ``scope_index.json``: for ``executable``,
     the step compiled ahead of time, which named scope every instruction
     belongs to (``profiling.scope_index``), so the trace's op events can
-    be read by part of the model."""
+    be read by part of the model, and under ``counts`` its held values,
+    erfc evaluations and mask generations (``profiling.step_counts``)."""
 
     def __init__(self, log_dir: str, start_step: int, stop_step: int,
                  executable: Optional[str] = None):
@@ -116,8 +117,10 @@ class _ProfileWindow:
             profiling_lib.scope_index(self.executable)
         if not runs or not index:
             return
+        counts = profiling_lib.step_counts(self.executable)
         with open(os.path.join(runs[-1], "scope_index.json"), "w") as fh:
-            json.dump({self.executable: index}, fh)
+            json.dump({self.executable: index,
+                       "counts": {self.executable: counts}}, fh)
 
 
 class FlaxModelAdapter:

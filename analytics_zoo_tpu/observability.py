@@ -45,7 +45,7 @@ from analytics_zoo_tpu.common.slo import get_monitor as get_slo_monitor  # noqa:
 from analytics_zoo_tpu.common.profiling import (  # noqa: F401  (re-exports)
     FlightRecorder, StepProfiler, backend_state, chrome_trace,
     device_peak_flops, dump_trace, get_flight_recorder, hbm_bytes,
-    maybe_arm_from_env, scope_index,
+    maybe_arm_from_env, scope_index, step_counts,
 )
 from analytics_zoo_tpu.common.telemetry import (  # noqa: F401  (re-exports)
     MetricsRegistry, Span, Tracer, bench_snapshot, get_registry, get_tracer,
@@ -60,7 +60,7 @@ __all__ = [
     "observe_device_block", "timed_block_until_ready",
     "chrome_trace", "dump_trace", "StepProfiler", "FlightRecorder",
     "get_flight_recorder", "maybe_arm_from_env", "backend_state",
-    "scope_index", "device_peak_flops", "hbm_bytes",
+    "scope_index", "step_counts", "device_peak_flops", "hbm_bytes",
     "BucketLadder", "ExecutableCache", "configure_persistent_cache",
     "WARMUP_TRACE_ID",
     "merge_snapshot", "fleet_registry", "ReplicaRegistry", "ReplicaInfo",

@@ -22,6 +22,8 @@ import jax
 import jax.numpy as jnp
 from flax.linen.dtypes import promote_dtype
 
+from analytics_zoo_tpu.ops.hold import Dropout
+
 
 def dot_product_attention(q, k, v, mask=None, causal: bool = False,
                           use_flash: Optional[bool] = None):
@@ -173,5 +175,5 @@ class AttentionModule(nn.Module):
         out = nn.DenseGeneral(q_in.shape[-1], axis=(-2, -1),
                               dtype=self.dtype, name="out")(out)
         if self.dropout > 0:
-            out = nn.Dropout(self.dropout, deterministic=not train)(out)
+            out = Dropout(self.dropout, deterministic=not train)(out)
         return out

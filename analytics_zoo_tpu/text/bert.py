@@ -26,6 +26,7 @@ import jax
 import jax.numpy as jnp
 
 from analytics_zoo_tpu.ops.attention import AttentionModule
+from analytics_zoo_tpu.ops.hold import Dropout, gelu_exact
 
 
 @dataclass(frozen=True)
@@ -101,10 +102,13 @@ class EncoderBlock(nn.Module):
                          name="attn_norm")(x + attn)
         h = nn.Dense(self.intermediate_size, dtype=self.dtype,
                      name="intermediate")(x)
-        h = nn.gelu(h, approximate=not self.gelu_exact)
+        # the erfc of the exact gelu and every dropout mask are held
+        # (ops/hold.py): computed once a step, not inside each product
+        h = gelu_exact(h) if self.gelu_exact \
+            else nn.gelu(h, approximate=True)
         h = nn.Dense(self.hidden_size, dtype=self.dtype, name="output")(h)
         if self.dropout > 0:
-            h = nn.Dropout(self.dropout, deterministic=not train)(h)
+            h = Dropout(self.dropout, deterministic=not train)(h)
         return nn.LayerNorm(epsilon=1e-12, dtype=self.dtype,
                             name="ffn_norm")(x + h)
 
@@ -139,7 +143,7 @@ class BertModule(nn.Module):
         x = nn.LayerNorm(epsilon=1e-12, dtype=cfg.dtype,
                          name="embed_norm")(emb)
         if cfg.hidden_drop > 0:
-            x = nn.Dropout(cfg.hidden_drop, deterministic=not train)(x)
+            x = Dropout(cfg.hidden_drop, deterministic=not train)(x)
 
         mask = None
         if attention_mask is not None:
@@ -192,7 +196,7 @@ class TransformerModule(nn.Module):
         x = x + nn.Embed(self.max_position_len, self.hidden_size,
                          name="wpe")(jnp.arange(L)[None, :])
         if self.hidden_drop > 0:
-            x = nn.Dropout(self.hidden_drop, deterministic=not train)(x)
+            x = Dropout(self.hidden_drop, deterministic=not train)(x)
         inter = self.intermediate_size or 4 * self.hidden_size
         attn_drop = (self.hidden_drop if self.attn_drop is None
                      else self.attn_drop)

@@ -1,0 +1,346 @@
+"""The training step's held residuals (``analytics_zoo_tpu/ops/hold.py``):
+the exact gelu's erfc and every dropout mask sit behind an optimization
+barrier in a differentiated program, and nowhere else; not one bit of a
+value, a gradient or a mask changes."""
+
+import re
+
+import flax.linen as nn
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from analytics_zoo_tpu.ops import attention as attention_lib
+from analytics_zoo_tpu.ops import hold
+from analytics_zoo_tpu.text import bert as bert_lib
+
+BARRIER = re.compile(r"\bstablehlo\.optimization_barrier\b")
+
+
+def barriers(lowered) -> int:
+    return len(BARRIER.findall(lowered.as_text()))
+
+
+def assert_bitwise(got, want):
+    got_leaves, got_def = jax.tree_util.tree_flatten(got)
+    want_leaves, want_def = jax.tree_util.tree_flatten(want)
+    assert got_def == want_def
+    for a, b in zip(got_leaves, want_leaves):
+        a, b = np.asarray(a), np.asarray(b)
+        assert a.dtype == b.dtype and a.shape == b.shape
+        np.testing.assert_array_equal(a, b)
+        assert a.tobytes() == b.tobytes()       # signs of zero, NaN bits
+
+
+# ------------------------------------------------------------------ gelu
+
+@pytest.mark.parametrize("jitted", [False, True], ids=["eager", "jit"])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["fp32", "bf16"])
+def test_gelu_exact_is_jax_gelu_bit_for_bit(dtype, jitted):
+    x = (jax.random.normal(jax.random.PRNGKey(0), (64, 96)) * 3).astype(dtype)
+    g = jax.random.normal(jax.random.PRNGKey(1), x.shape).astype(dtype)
+
+    def both(fn):
+        def run(x, g):
+            y, vjp = jax.vjp(fn, x)
+            return y, vjp(g)[0], fn(x)      # differentiated and plain
+        return jax.jit(run) if jitted else run
+
+    got = both(hold.gelu_exact)(x, g)
+    want = both(lambda x: jax.nn.gelu(x, approximate=False))(x, g)
+    assert got[0].dtype == dtype
+    assert_bitwise(got, want)
+
+
+def test_gelu_exact_promotes_integers_as_jax_gelu_does():
+    x = jnp.arange(-3, 4)
+    assert_bitwise(hold.gelu_exact(x), jax.nn.gelu(x, approximate=False))
+
+
+def test_hold_is_a_barrier_under_grad_and_nothing_otherwise():
+    x = jnp.linspace(-2.0, 2.0, 32)
+    plain = jax.jit(lambda x: hold.hold(jnp.sin(x)) * 2).lower(x)
+    assert barriers(plain) == 0
+    assert "optimization_barrier" not in plain.as_text()
+    # the square keeps the held value as its residual
+    under_grad = jax.jit(jax.grad(
+        lambda x: (hold.hold(jnp.sin(x)) ** 2).sum())).lower(x)
+    assert barriers(under_grad) == 1
+    np.testing.assert_array_equal(
+        jax.grad(lambda x: (hold.hold(jnp.sin(x)) ** 2).sum())(x),
+        jax.grad(lambda x: (jnp.sin(x) ** 2).sum())(x))
+
+
+# --------------------------------------------------------------- dropout
+
+class _Site(nn.Module):
+    """One dropout at module path ``bert/block_0/attention/Dropout_0``,
+    flax's or the held one."""
+    cls: type
+
+    @nn.compact
+    def __call__(self, x):
+        class attention(nn.Module):
+            @nn.compact
+            def __call__(inner, x):
+                return self.cls(0.1, deterministic=False)(x)
+
+        class block(nn.Module):
+            @nn.compact
+            def __call__(inner, x):
+                return attention(name="attention")(x)
+
+        class bert(nn.Module):
+            @nn.compact
+            def __call__(inner, x):
+                return block(name="block_0")(x)
+
+        return bert(name="bert")(x)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["fp32", "bf16"])
+def test_held_dropout_draws_flaxs_mask_at_the_same_path(dtype):
+    from benchmarks.references import bert as reference
+    key = jax.random.PRNGKey(17)
+    x = (jax.random.normal(jax.random.PRNGKey(2), (8, 16, 32)) + 3) \
+        .astype(dtype)
+    g = jnp.ones_like(x)
+
+    def run(cls):
+        def fn(x):
+            return _Site(cls).apply({}, x, rngs={"dropout": key})
+        y, vjp = jax.vjp(fn, x)
+        return y, vjp(g)[0]
+
+    got, want = run(hold.Dropout), run(nn.Dropout)
+    assert_bitwise(got, want)
+    # and the mask the benchmark's reference restates for that site
+    site = ("bert", "block_0", "attention", "Dropout_0")
+    mask = jax.random.bernoulli(
+        reference.fold_static(key, site + (1,)), 0.9, x.shape)
+    np.testing.assert_array_equal(np.asarray(got[0] != 0), np.asarray(mask))
+    assert 0.8 < float(mask.mean()) < 0.97
+
+
+@pytest.mark.parametrize("rate,deterministic,barriers_wanted", [
+    (0.1, False, 1), (0.1, True, 0), (0.0, False, 0), (1.0, False, 0)])
+def test_held_dropout_edges_follow_flax(rate, deterministic, barriers_wanted):
+    x = jnp.ones((4, 8))
+    key = jax.random.PRNGKey(3)
+
+    def fn(cls):
+        return lambda x: cls(rate, deterministic=deterministic).apply(
+            {}, x, rngs={"dropout": key})
+
+    assert_bitwise(fn(hold.Dropout)(x), fn(nn.Dropout)(x))
+    assert barriers(jax.jit(fn(hold.Dropout)).lower(x)) == barriers_wanted
+
+
+# ------------------------------------------------- the whole train step
+
+N_BLOCK = 2
+
+
+def _bert(name=None, **cfg):
+    cfg = dict(dict(vocab=50, hidden_size=32, n_block=N_BLOCK, n_head=4,
+                    intermediate_size=64, max_position_len=16), **cfg)
+    return bert_lib.BertModule(bert_lib.BertConfig(**cfg), name=name)
+
+
+def _gpt(**cfg):
+    return bert_lib.TransformerModule(
+        vocab=50, hidden_size=32, n_block=N_BLOCK, n_head=4,
+        max_position_len=16, **cfg)
+
+
+def _loss_and_grads(module, ids, train=True, key=jax.random.PRNGKey(17)):
+    params = module.init(jax.random.PRNGKey(0), ids)
+
+    def loss(params):
+        out = module.apply(params, ids, train=train, rngs={"dropout": key})
+        out = out[1] if isinstance(out, tuple) else out
+        return (out.astype(jnp.float32) ** 2).mean()
+
+    return jax.jit(jax.value_and_grad(loss)), params
+
+
+@pytest.fixture
+def unheld(monkeypatch):
+    """The same modules built with ``nn.gelu`` and ``nn.Dropout``: the
+    step as it was before the residuals were held."""
+    def apply():
+        monkeypatch.setattr(bert_lib, "Dropout", nn.Dropout)
+        monkeypatch.setattr(attention_lib, "Dropout", nn.Dropout)
+        monkeypatch.setattr(bert_lib, "gelu_exact",
+                            lambda h: nn.gelu(h, approximate=False))
+    return apply
+
+
+IDS = np.random.default_rng(0).integers(0, 50, (4, 16)).astype(np.int32)
+
+
+@pytest.mark.parametrize("build,dtype", [
+    (_bert, None), (_bert, jnp.bfloat16), (_gpt, None), (_gpt, jnp.bfloat16)],
+    ids=["bert-fp32", "bert-bf16", "gpt-fp32", "gpt-bf16"])
+def test_train_step_is_bitwise_the_unheld_step(build, dtype, unheld):
+    step, params = _loss_and_grads(build(dtype=dtype), IDS)
+    got = step(params)
+    held = barriers(step.lower(params))
+    unheld()
+    step, params = _loss_and_grads(build(dtype=dtype), IDS)
+    assert barriers(step.lower(params)) == 0 < held
+    want = step(params)
+    assert np.isfinite(float(want[0]))
+    assert_bitwise(got, want)
+
+
+@pytest.mark.parametrize("build,gelus", [(_bert, N_BLOCK), (_gpt, 0)],
+                         ids=["bert", "gpt-tanh-gelu"])
+def test_lowered_train_step_holds_one_value_per_site(build, gelus):
+    """One barrier per exact gelu and per active dropout site (one after
+    the embeddings, two a block); none where dropout is off, none in a
+    forward pass that is not differentiated."""
+    module = build()
+    step, params = _loss_and_grads(module, IDS)
+    assert barriers(step.lower(params)) == gelus + 1 + 2 * N_BLOCK
+
+    step, params = _loss_and_grads(build(hidden_drop=0.0, attn_drop=0.0), IDS)
+    assert barriers(step.lower(params)) == gelus
+
+    forward = jax.jit(lambda p, ids: module.apply(p, ids, train=False))
+    assert "optimization_barrier" not in forward.lower(params, IDS).as_text()
+    # evaluate-style: not differentiated, dropout off
+    step, params = _loss_and_grads(module, IDS, train=False)
+    assert barriers(step.lower(params)) == gelus
+
+
+def test_inference_model_program_holds_nothing(orca_ctx):
+    from analytics_zoo_tpu.common import profiling, telemetry
+    from analytics_zoo_tpu.inference.inference_model import InferenceModel
+    im = InferenceModel().load_flax(_bert(), IDS)
+    im.warm_up(rungs=(4,), block=True)
+    assert np.asarray(im.predict(IDS)[1]).shape == (4, 32)
+    counts = profiling.step_counts("inference_model")
+    assert counts["held_values"] == 0 and counts["mask"] == 0
+    snap = telemetry.snapshot()
+    assert snap["zoo_step_held_values"]["executable=inference_model"] == 0
+    assert snap["zoo_step_elementwise_evals"][
+        "executable=inference_model,kind=mask"] == 0
+
+
+def test_remat_holds_the_values_inside_the_checkpointed_block(unheld):
+    """The held values are recomputed once in the backward pass, by
+    design: each site of a block shows twice in the lowered step (beside
+    the one barrier ``jax.checkpoint`` itself puts around each block's
+    recomputation), and the numbers are those of the step that keeps
+    everything."""
+    step, params = _loss_and_grads(_bert(), IDS)
+    want = step(params)
+    remat, params_r = _loss_and_grads(_bert(remat=True), IDS)
+    assert_bitwise(params_r, params)
+    in_blocks = N_BLOCK + 2 * N_BLOCK
+    assert barriers(remat.lower(params)) == N_BLOCK + 1 + 2 * in_blocks
+    got = remat(params)
+    unheld()
+    remat, _ = _loss_and_grads(_bert(remat=True), IDS)
+    assert barriers(remat.lower(params)) == N_BLOCK
+    assert_bitwise(got, remat(params))
+    np.testing.assert_allclose(got[0], want[0], rtol=1e-6)
+    for a, b in zip(jax.tree_util.tree_leaves(got[1]),
+                    jax.tree_util.tree_leaves(want[1])):
+        np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-7)
+
+
+def _fit_tiny_bert(steps_per_loop):
+    from analytics_zoo_tpu.learn.estimator import Estimator
+
+    class Classifier(nn.Module):
+        @nn.compact
+        def __call__(self, ids, train: bool = False):
+            _, pooled = _bert(name="bert")(ids, train=train)
+            return nn.Dense(2)(pooled)
+
+    rng = np.random.default_rng(1)
+    x = rng.integers(0, 50, (32, 16)).astype(np.int32)
+    y = rng.integers(0, 2, 32).astype(np.int32)
+    est = Estimator.from_flax(
+        model=Classifier(), loss="sparse_categorical_crossentropy_logits",
+        optimizer="sgd", sample_input=x[:2], seed=0)
+    hist = est.fit((x, y), epochs=1, batch_size=8, shuffle=False,
+                   steps_per_loop=steps_per_loop)
+    est._precompile_thread.join(timeout=300)
+    return est, hist
+
+
+def test_steps_per_loop_runs_the_same_held_step_inside_a_scan(orca_ctx):
+    from analytics_zoo_tpu.common import profiling
+    est1, h1 = _fit_tiny_bert(1)
+    est2, h2 = _fit_tiny_bert(2)
+    np.testing.assert_allclose(h1["loss"], h2["loss"], rtol=1e-5)
+    for a, b in zip(jax.tree_util.tree_leaves(est1._state["params"]),
+                    jax.tree_util.tree_leaves(est2._state["params"])):
+        np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-6)
+    sites = N_BLOCK + 1 + 2 * N_BLOCK
+    assert profiling.step_counts("estimator_train_step")["held_values"] \
+        == sites
+    assert profiling.step_counts("estimator_train_scan")["held_values"] \
+        == sites
+
+
+# ------------------------------ the v5e compiler, described not attached
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def test_v5e_compiles_each_held_value_once_at_bert_base_width(one_chip):
+    """One block of BERT-base at [32,512], bf16, compiled for the chip:
+    the step's optimized HLO evaluates erfc once and generates one mask
+    per dropout site. (Without the barriers the same compile reads 3
+    and 10: each re-derived inside the products that read it.)"""
+    from jax.experimental.compilation_cache import compilation_cache
+    from analytics_zoo_tpu.common import profiling
+    module = _bert(vocab=30522, hidden_size=768, n_block=1, n_head=12,
+                   intermediate_size=3072, max_position_len=512,
+                   dtype=jnp.bfloat16)
+    ids = jax.ShapeDtypeStruct((32, 512), jnp.int32, sharding=one_chip)
+    params = jax.eval_shape(
+        lambda: module.init(jax.random.PRNGKey(0),
+                            jnp.zeros((2, 512), jnp.int32)))
+    params = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        params)
+
+    def step(params, ids):
+        def loss(params):
+            _, pooled = module.apply(
+                params, ids, train=True,
+                rngs={"dropout": jax.random.PRNGKey(17)})
+            return (pooled.astype(jnp.float32) ** 2).mean()
+        loss_val, grads = jax.value_and_grad(loss)(params)
+        return loss_val, jax.tree_util.tree_map(
+            lambda p, g: p - 1e-3 * g, params, grads)
+
+    # a compile for a described chip is written to the persistent cache
+    # and cannot be read back without one: keep it out
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        lowered = jax.jit(step, donate_argnums=0).lower(params, ids)
+        text = lowered.compile().as_text()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", True)
+        compilation_cache.reset_cache()
+    assert barriers(lowered) == 1 + 3
+    assert profiling.count_elementwise_evals(text) == {"erfc": 1, "mask": 3}

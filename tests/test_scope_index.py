@@ -187,9 +187,90 @@ def test_profile_window_leaves_the_scope_index_beside_its_trace(
     assert glob.glob(os.path.join(runs[0], "*.xplane.pb"))
     with open(os.path.join(runs[0], "scope_index.json")) as fh:
         written = json.load(fh)
-    assert list(written) == ["estimator_train_step"]
+    assert list(written) == ["estimator_train_step", "counts"]
     assert written["estimator_train_step"] \
         == profiling.scope_index("estimator_train_step")
+    counts = written["counts"]["estimator_train_step"]
+    assert counts == profiling.step_counts("estimator_train_step")
+    # the tiny BERT has 2 blocks: 2 exact gelus, 1 + 2 x 2 dropout sites
+    assert counts["held_values"] == 7
+
+
+# ----------------------------------------- held values and evaluations
+
+PARENT_FUSIONS = os.path.join(os.path.dirname(__file__), "fixtures",
+                              "hlo_parent_s512_output_fusions.txt")
+
+
+def test_evaluations_counted_in_three_fusions_of_the_unheld_step():
+    """The three fused computations around block 5's ``output`` product —
+    forward, input gradient, weight gradient + Adam — of the s512 step as
+    the v5e compiled it before the residuals were held (PR 27's kept HLO,
+    cut with the computations they call): each re-derives the erfc and
+    the dropout mask inside itself."""
+    with open(PARENT_FUSIONS) as fh:
+        text = fh.read()
+    assert text.count(" convolution(") == 3
+    assert profiling.count_elementwise_evals(text) == {"erfc": 3, "mask": 3}
+    # nothing else of the text reads as either
+    assert profiling.count_elementwise_evals(
+        text.replace("/erfc\"", "/erf_inv\"")
+            .replace("jit(_bernoulli)/lt\"", "jit(_bernoulli)/le\"")) \
+        == {"erfc": 0, "mask": 0}
+
+
+@pytest.mark.parametrize("n_lowered,want", [(0, None), (1, 3)])
+def test_note_executable_counts_and_publishes(n_lowered, want):
+    with open(PARENT_FUSIONS) as fh:
+        text = fh.read()
+
+    class Exe:
+        def as_text(self):
+            return text
+
+        def cost_analysis(self):
+            return {"flops": 2.0}
+
+    class Lowered:
+        def as_text(self):
+            return ("%0 = stablehlo.optimization_barrier %a : tensor<4xi1>\n"
+                    * 3)
+
+    lowered = Lowered() if n_lowered else None
+    assert profiling.note_executable("canned", Exe(), lowered=lowered) == 2.0
+    counts = profiling.step_counts("canned")
+    assert counts.get("held_values") == want
+    assert (counts["erfc"], counts["mask"]) == (3, 3)
+    snap = telemetry.snapshot()
+    assert snap["zoo_step_elementwise_evals"] == {
+        "executable=canned,kind=erfc": 3, "executable=canned,kind=mask": 3}
+    assert snap.get("zoo_step_held_values") == (
+        {"executable=canned": 3} if want else None)
+    assert profiling.step_counts("no_such_executable") is None
+
+
+def test_fit_publishes_the_counts_where_metrics_are_scraped(orca_ctx):
+    """After a CPU ``fit`` the two gauges are in ``GET /metrics``."""
+    import urllib.request
+
+    from analytics_zoo_tpu.serving.broker import Broker
+    from analytics_zoo_tpu.serving.frontend import FrontEnd
+    est, x, y = _tiny_bert_estimator()
+    _fit_and_wait(est, x, y)
+    broker = Broker.launch(backend="python")
+    try:
+        with FrontEnd(broker.port, timeout=5.0).start() as fe:
+            text = urllib.request.urlopen(urllib.request.Request(
+                f"http://127.0.0.1:{fe.port}/metrics",
+                headers={"Accept": "text/plain"}), timeout=10).read().decode()
+    finally:
+        broker.stop()
+    assert 'zoo_step_held_values{executable="estimator_train_step"} 7' \
+        in text
+    assert 'zoo_step_elementwise_evals{executable="estimator_train_step",' \
+        'kind="mask"} ' in text
+    assert 'zoo_step_elementwise_evals{executable="estimator_train_step",' \
+        'kind="erfc"} ' in text
 
 
 def test_a_jitted_step_is_compiled_under_its_stable_name():
