@@ -506,11 +506,16 @@ def parse_scope_index(hlo_text: str) -> Dict[str, dict]:
     distinct scope found inside, so a weight-gradient product fused with
     the optimizer's update counts with its product and shows as mixed.
     An instruction the compiler left nameless (the copies and slices that
-    bring a weight in ahead of its use, and the waits for them) takes
+    bring a weight in ahead of its use, and the waits for them; an
+    instruction whose ``op_name`` is the compiler's own bare name for
+    what it put there, a kernel or a rewritten gather, with no name stack
+    in it) takes
     ``scope`` and ``phase`` of the nearest named instruction that
     consumes its result (or, for a copy out of the step, produced its
     operand), with ``scopes`` left empty; ``scope`` is ``None`` only
-    where neither exists."""
+    where neither exists; inside a called computation (a ``conditional``'s
+    branch, a ``while``'s body) such an instruction falls back to the
+    calling instruction's scope."""
     computations: Dict[str, list] = {}
     entry = current = None
     for line in hlo_text.splitlines():
@@ -560,10 +565,24 @@ def parse_scope_index(hlo_text: str) -> Dict[str, dict]:
                             if i[1] in _PRODUCTS and i[2]]
                 roots = [i[2] for i in inner if i[3] and i[2]]
                 chosen = (products or roots or [own])[0]
-            else:
+            scope, phase = _scope_of(chosen) if chosen else (None, "other")
+            if opcode != "fusion":
                 for callee in callees:
                     visit(callee)
-            scope, phase = _scope_of(chosen) if chosen else (None, "other")
+                    # what a branch or a body runs that neither has a
+                    # name nor a named neighbour inside it (the zeros of
+                    # a branch that computes nothing, the copies around
+                    # it) belongs to the instruction that calls it
+                    for inner, *_ in computations.get(callee, ()):
+                        entry = index.get(inner)
+                        if entry and entry["scope"] is None and scope:
+                            entry["scope"], entry["phase"] = scope, phase
+            if chosen and "/" not in chosen:
+                # what the compiler put in place of an instruction and
+                # named itself (the TPU's grouped matrix product arrives
+                # as "ragged-dot-none", a bit-packed gather as "gather"):
+                # no name stack at all, so nameless like the copies below
+                scope, phase, names = None, "other", []
             index[name] = {
                 "scope": scope, "phase": phase, "opcode": opcode,
                 "scopes": sorted({_scope_of(n)[0] for n in names})}
@@ -580,7 +599,8 @@ def _inherit_from_neighbours(body, index: Dict[str, dict]) -> None:
     instruction with a scope that consumes its result — or, where none
     does (a copy of a result out of the step), that produced its operand
     — found breadth-first through instructions without one (program order
-    breaks ties)."""
+    breaks ties). A nameless ``custom-call`` looks among its operands'
+    producers first."""
     users: Dict[str, list] = {}
     operands_of: Dict[str, list] = {}
     for name, _, _, _, _, operands in body:
@@ -602,11 +622,15 @@ def _inherit_from_neighbours(body, index: Dict[str, dict]) -> None:
             frontier = reached
         return None
 
-    for name, _, _, _, _, _ in body:
+    for name, opcode, _, _, _, _ in body:
         entry = index.get(name)
         if entry is None or entry["scope"] is not None:
             continue
-        found = nearest(name, users) or nearest(name, operands_of)
+        # a kernel belongs with what feeds it (a weight-gradient product's
+        # only user is the optimizer's update); a copy with what reads it
+        first, then = (operands_of, users) if opcode == "custom-call" \
+            else (users, operands_of)
+        found = nearest(name, first) or nearest(name, then)
         if found is not None:
             entry["scope"], entry["phase"] = found["scope"], found["phase"]
 

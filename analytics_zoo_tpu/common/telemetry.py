@@ -37,6 +37,7 @@ import contextvars
 import functools
 import math
 import os
+import re
 import sys
 import threading
 from collections import OrderedDict
@@ -1034,6 +1035,40 @@ def traced_device_get(x):
     _transfer_counter("d2h").inc(n)
     _transfer_gauge("d2h").set(n)
     return out
+
+
+_SERIES = re.compile(r"^(\w+)(?:\{(.*)\})?$")
+
+
+def publish_step_counters(steps) -> None:
+    """What a model's modules counted in each of ``steps`` optimizer steps
+    (the ``counters`` collection, fetched to the host with the losses):
+    ``{module path: {series: value}}`` a step, a series named
+    ``family`` or ``family{label=value,...}``. A family that ends in
+    ``_total`` is a counter and grows by every step's value; any other is
+    a gauge and keeps the last step's. The module's path is the label
+    ``layer``."""
+    import jax
+    for n, step in enumerate(steps):
+        last = n == len(steps) - 1
+        for path, value in jax.tree_util.tree_flatten_with_path(step)[0]:
+            *layer, series = (str(getattr(k, "key", k)) for k in path)
+            m = _SERIES.match(series)
+            if not m:
+                raise ValueError(f"not a series name: {series!r}")
+            labels = dict(kv.split("=", 1)
+                          for kv in (m.group(2) or "").split(",") if kv)
+            labels["layer"] = "/".join(layer)
+            names = tuple(sorted(labels))
+            value = float(value)  # zoolint: disable=hotpath-host-sync (a host array: fetched with the losses)
+            if m.group(1).endswith("_total"):
+                _REGISTRY.counter(m.group(1), "counted by the model's "
+                                  "modules, per optimizer step",
+                                  names).labels(**labels).inc(value)
+            elif last:
+                _REGISTRY.gauge(m.group(1), "read by the model's modules "
+                                "in the last optimizer step fetched",
+                                names).labels(**labels).set(value)
 
 
 def observe_device_block(seconds: float, site: str = ""):

@@ -123,6 +123,10 @@ class _ProfileWindow:
                        "counts": {self.executable: counts}}, fh)
 
 
+#: collections a module sows into anew every step; never model state
+PER_STEP_COLLECTIONS = ("aux_loss", "counters")
+
+
 class FlaxModelAdapter:
     """Uniform call surface over a flax.linen module: handles multi-input
     tuples, the optional ``train`` kwarg, dropout rngs and mutable
@@ -145,8 +149,10 @@ class FlaxModelAdapter:
             # loss), not persistent state — it is consumed by the train step
             # and must not ride model_state across steps (sow appends, so
             # carrying it would grow the collection every iteration)
+            # so is "counters": numbers a module counts each step for
+            # telemetry (the train step hands them out beside the loss)
             model_state = {k: v for k, v in variables.items()
-                           if k != "aux_loss"}
+                           if k not in PER_STEP_COLLECTIONS}
         self.params = params
         self.model_state = model_state or {}
 
@@ -174,7 +180,8 @@ class FlaxModelAdapter:
             # collections before they become the next model_state
             out, mut = self.module.apply(
                 variables, *args, rngs=rngs,
-                mutable=list(model_state.keys()) + ["aux_loss"], **kwargs)
+                mutable=list(model_state.keys())
+                + list(PER_STEP_COLLECTIONS), **kwargs)
             return out, dict(mut)
         out = self.module.apply(variables, *args, rngs=rngs, **kwargs)
         return out, model_state
@@ -576,9 +583,13 @@ class JaxEstimator:
                             for leaf in jax.tree_util.tree_leaves(aux)]
                         if aux_terms:
                             loss = loss + aux_weight * sum(aux_terms)
-                return loss, new_mut
+                counters = {}
+                if isinstance(new_mut, dict) and "counters" in new_mut:
+                    new_mut = dict(new_mut)
+                    counters = new_mut.pop("counters")
+                return loss, (new_mut, counters)
 
-            (loss_val, new_mut), grads = jax.value_and_grad(
+            (loss_val, (new_mut, counters)), grads = jax.value_and_grad(
                 compute_loss, has_aux=True)(state["params"])
             updates, new_opt = tx.update(grads, state["opt_state"],
                                          state["params"])
@@ -590,6 +601,10 @@ class JaxEstimator:
                              "opt_state": new_opt,
                              "model_state": new_mut}
                 logs = {"loss": loss_val.astype(jnp.float32)}
+                if counters:
+                    # what the modules counted this step: outputs of the
+                    # step, fetched where the losses are (flush_window)
+                    logs["counters"] = counters
             return new_state, logs
 
         # instrument_jit = jax.jit + recompile accounting: the
@@ -1093,6 +1108,7 @@ class JaxEstimator:
         step_prof = self._step_prof
         losses: List[Any] = []
         pending: List[Any] = []
+        pending_counters: List[Any] = []
         pending_steps = 0
         t_epoch = time.perf_counter()
         samples = 0
@@ -1100,17 +1116,19 @@ class JaxEstimator:
 
         def flush_window():
             # one host sync per window: fetch the buffered device scalars
-            nonlocal pending, pending_steps, t_window
+            nonlocal pending, pending_counters, pending_steps, t_window
             if not pending:
                 return
             with step_prof.phase("epoch/flush", "flush"):
                 t_fetch = time.perf_counter()
+                fetched, counted = telemetry.traced_device_get(
+                    (pending, pending_counters))
                 vals = list(np.concatenate(
-                    [np.atleast_1d(np.asarray(v))
-                     for v in telemetry.traced_device_get(pending)]
+                    [np.atleast_1d(np.asarray(v)) for v in fetched]
                 ).astype(float))
                 telemetry.observe_device_block(
                     time.perf_counter() - t_fetch, "train_flush")
+                telemetry.publish_step_counters(counted)
                 losses.extend(vals)
                 step = self._py_step
                 writer.add_scalar("Loss", vals[-1], step)
@@ -1125,6 +1143,7 @@ class JaxEstimator:
                 step_prof.observe_window(pending_steps, dt)
             t_window = time.perf_counter()
             pending = []
+            pending_counters = []
             pending_steps = 0
 
         def after_steps(n_steps):
@@ -1184,6 +1203,8 @@ class JaxEstimator:
                 else:
                     self._state, logs = self._train_step(self._state, x, y)
                     loop_losses = logs["loss"]
+                    if "counters" in logs:
+                        pending_counters.append(logs["counters"])
             t2 = time.perf_counter()
             pending.append(loop_losses)
             after_steps(n_steps)

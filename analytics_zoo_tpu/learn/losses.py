@@ -7,6 +7,7 @@ step can apply padding masks before reduction.
 
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 
 _EPS = 1e-7
@@ -81,11 +82,43 @@ def sparse_categorical_crossentropy(y_true, y_pred):
     return -jnp.take_along_axis(logp, idx[..., None], axis=-1)[..., 0]
 
 
+#: float32 bytes of ``[..., vocab]`` logits beyond which their float32
+#: copy is taken a block of positions at a time (a language model's
+#: ``[batch, seq, vocab]``: 1 GB at 16,384 tokens x 16,384 ids)
+LOGITS_BLOCK_BYTES = 256 << 20
+
+
+def _token_nll(logits, idx):
+    logits = _f32(logits)
+    logp = logits - jax_logsumexp(logits)
+    return -jnp.take_along_axis(logp, idx[..., None], axis=-1)[..., 0]
+
+
+def _token_nll_in_blocks(logits, idx):
+    """``_token_nll`` over blocks of positions, each recomputed in the
+    backward pass, so that the float32 logits, their softmax and its
+    gradient exist for one block at a time."""
+    vocab = logits.shape[-1]
+    positions = idx.size
+    blocks = next((n for n in range(1, positions + 1)
+                   if positions % n == 0
+                   and positions // n * vocab * 4 <= LOGITS_BLOCK_BYTES),
+                  None)
+    if blocks in (None, 1):
+        return _token_nll(logits, idx)
+    out = jax.lax.map(
+        jax.checkpoint(lambda block: _token_nll(*block)),
+        (logits.reshape(blocks, -1, vocab), idx.reshape(blocks, -1)))
+    return out.reshape(idx.shape)
+
+
 def sparse_categorical_crossentropy_from_logits(y_true, y_pred):
-    y_pred = _f32(y_pred)
-    logp = y_pred - jax_logsumexp(y_pred)
     idx = jnp.asarray(y_true).astype(jnp.int32)
-    out = -jnp.take_along_axis(logp, idx[..., None], axis=-1)[..., 0]
+    y_pred = jnp.asarray(y_pred)
+    if y_pred.ndim > 2 and y_pred.size * 4 > LOGITS_BLOCK_BYTES:
+        out = _token_nll_in_blocks(y_pred, idx)
+    else:
+        out = _token_nll(y_pred, idx)
     if out.ndim > 1:  # e.g. seq models: mean over time
         out = out.mean(axis=tuple(range(1, out.ndim)))
     return out

@@ -10,7 +10,7 @@ shards it for free).
 
 from __future__ import annotations
 
-from typing import Optional, Union
+from typing import Mapping, Optional, Union
 
 import optax
 
@@ -99,14 +99,23 @@ class Optimizer:
         if isinstance(opt, optax.GradientTransformation):
             return _Raw(opt)
         if isinstance(opt, str):
-            name = opt.lower()
+            opt = {"name": opt}
+        if isinstance(opt, Mapping):
+            # {"name": "adam", "learningrate": 1e-5, ...}: what a
+            # configuration file can say; the rest are the class's own
+            # constructor arguments
+            args = dict(opt)
+            name = str(args.pop("name", "")).lower()
             table = {"sgd": SGD, "adam": Adam, "adamw": AdamWeightDecay,
                      "rmsprop": RMSprop, "adagrad": Adagrad,
                      "adadelta": Adadelta, "adamax": Adamax, "nadam": Nadam,
                      "lars": LARS, "lamb": LAMB, "lbfgs": LBFGS}
             if name not in table:
                 raise ValueError(f"unknown optimizer {opt!r}")
-            return table[name]()
+            try:
+                return table[name](**args)
+            except TypeError as e:
+                raise ValueError(f"optimizer {name!r}: {e}") from None
         raise TypeError(f"cannot build optimizer from {type(opt)}")
 
 

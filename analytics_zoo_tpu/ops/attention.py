@@ -64,8 +64,7 @@ def _flash_ok(q, k, mask) -> bool:
         "flash_attention")
     if rec is not None:
         return bool(rec.get("use_kernel"))
-    scores_bytes = 4 * b * h * sq * sk
-    return scores_bytes > (1 << 31)  # > 2 GiB
+    return 4 * b * h * sq * sk > autotune.SCORES_SWITCH  # > 2 GiB
 
 
 def _reference_attention(q, k, v, mask=None, causal=False,
@@ -177,3 +176,67 @@ class AttentionModule(nn.Module):
         if self.dropout > 0:
             out = Dropout(self.dropout, deterministic=not train)(out)
         return out
+
+
+# ------------------------------------ grouped-query heads, rotary positions
+
+def rotary_embedding(x, theta: float):
+    """Rotary positions 0..seq-1 on ``x`` [batch, seq, heads, head_dim]
+    with the halves rotated (``rotate_half``: element i pairs with
+    i + d/2), the angles in float32: ``x * cos + rotate_half(x) * sin``."""
+    d = x.shape[-1]
+    inv_freq = 1.0 / (theta ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d))
+    angles = jnp.arange(x.shape[1], dtype=jnp.float32)[:, None] \
+        * inv_freq[None, :]
+    cos = jnp.concatenate([jnp.cos(angles)] * 2, -1)[None, :, None, :]
+    sin = jnp.concatenate([jnp.sin(angles)] * 2, -1)[None, :, None, :]
+    xf = x.astype(jnp.float32)
+    rotated = jnp.concatenate([-xf[..., d // 2:], xf[..., :d // 2]], -1)
+    return (xf * cos + rotated * sin).astype(x.dtype)
+
+
+def grouped_query_attention(q, k, v):
+    """Causal attention of ``q`` [batch, seq, heads, d] over ``k``, ``v``
+    [batch, seq, kv_heads, d] with ``heads`` a multiple of ``kv_heads``:
+    each key-value head serves ``heads // kv_heads`` consecutive query
+    heads."""
+    groups = q.shape[2] // k.shape[2]
+    if groups > 1:
+        k, v = (jnp.repeat(t, groups, axis=2) for t in (k, v))
+    return dot_product_attention(q, k, v, causal=True)
+
+
+class GroupedQueryAttention(nn.Module):
+    """Causal self-attention with grouped-query heads, an RMSNorm over
+    each head of q and of k, rotary positions and no bias: projections
+    ``q``, ``k``, ``v``, ``out``; norms ``q_norm``, ``k_norm``."""
+
+    num_heads: int
+    num_kv_heads: int
+    head_dim: int
+    rope_theta: float = 10000.0
+    norm_eps: float = 1e-5
+    dtype: Optional[jnp.dtype] = None
+    kernel_init: nn.initializers.Initializer = nn.initializers.lecun_normal()
+
+    @nn.compact
+    def __call__(self, x):
+        b, s, hidden = x.shape
+        h, g, d = self.num_heads, self.num_kv_heads, self.head_dim
+
+        def proj(name, heads):
+            y = nn.Dense(heads * d, use_bias=False, dtype=self.dtype,
+                         kernel_init=self.kernel_init, name=name)(x)
+            return y.reshape(b, s, heads, d)
+
+        q, k, v = proj("q", h), proj("k", g), proj("v", g)
+        q = nn.RMSNorm(epsilon=self.norm_eps, dtype=self.dtype,
+                       name="q_norm")(q)
+        k = nn.RMSNorm(epsilon=self.norm_eps, dtype=self.dtype,
+                       name="k_norm")(k)
+        q = rotary_embedding(q, self.rope_theta)
+        k = rotary_embedding(k, self.rope_theta)
+        out = grouped_query_attention(q, k, v)
+        return nn.Dense(hidden, use_bias=False, dtype=self.dtype,
+                        kernel_init=self.kernel_init,
+                        name="out")(out.reshape(b, s, h * d))

@@ -62,6 +62,12 @@ DEFAULT_CACHE_PATH = os.path.join(DUMP_DIR, "autotune.json")
 ATTENTION_BLOCKS: Tuple[Tuple[int, int], ...] = (
     (128, 128), (128, 256), (256, 256), (256, 512), (512, 512))
 
+#: float32 scores [b, h, s_q, s_k] beyond which the pallas path is the
+#: only one that fits (``ops/attention._flash_ok``'s switch), and the
+#: block sizes it runs with until a verdict names better ones
+SCORES_SWITCH = 1 << 31
+UNTUNED_BLOCKS = (512, 512)
+
 _lock = threading.RLock()
 _tuner: Optional["Autotuner"] = None
 _pending: "Dict[str, Callable[[], dict]]" = {}
@@ -442,4 +448,11 @@ def auto_flash_attention(q, k, v, causal: bool = False):
         from analytics_zoo_tpu.ops.flash_attention import flash_attention
         bq, bk = (int(t) for t in rec["best"].split("x"))
         return flash_attention(q, k, v, causal, bq, bk)
+    if rec is None and on_tpu() and 4 * b * h * s_q * s_k > SCORES_SWITCH:
+        # no verdict yet and a score matrix the chip cannot hold: the
+        # blockwise scan is O(s) in the forward pass only (its backward
+        # keeps every block's probabilities, [b, h, s, s] in all), the
+        # kernels' backward is not
+        from analytics_zoo_tpu.ops.flash_attention import flash_attention
+        return flash_attention(q, k, v, causal, *UNTUNED_BLOCKS)
     return blockwise_attention(q, k, v, causal=causal)

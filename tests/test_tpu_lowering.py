@@ -35,6 +35,9 @@ def _sq(a):
 FLASH_SHAPES = [
     pytest.param(32, 128, 12, 64, False, id="bert-base-b32s128h12d64"),
     pytest.param(2, 2048, 8, 128, True, id="b2s2048h8d128-causal"),
+    # the hybrid decoder's attention layer at its cell's size (the key and
+    # value heads repeated to the 32 query heads)
+    pytest.param(2, 8192, 32, 64, True, id="gqa-b2s8192h32d64-causal"),
     # ragged sequence, unaligned head dim, block_q clamped below the lane
     pytest.param(1, 200, 2, 64, True, id="ragged-s200"),
     pytest.param(1, 40, 2, 64, False, id="short-s40"),
@@ -66,6 +69,33 @@ def test_flash_with_lse_grad_lowers(b, s, h, d, causal):
         return _sq(out) + lse.sum()
 
     lowers_for_tpu(jax.grad(loss, argnums=(0, 1, 2)), q, q, q)
+
+
+def test_held_experts_grouped_products_lower_at_the_published_widths():
+    """The dropless expert layer's first window and its second under the
+    ``cond``, forward and backward, at 16,384 tokens, 8 of 32 experts
+    held, hidden 2048, expert width 1792: grouped products, no pallas
+    kernel of ours."""
+    from analytics_zoo_tpu.ops import moe
+
+    n, hidden, width, held, experts, k = 16384, 2048, 1792, 8, 32, 4
+
+    def loss(x, w1, w3, w2, logits):
+        ids, weights = moe.sigmoid_top_k_routing(
+            logits, jnp.zeros((experts,)), k)
+        out, _, _ = moe.held_expert_ffn(x, ids, weights, w1, w3, w2,
+                                        tuple(range(held)), experts)
+        return _sq(out)
+
+    exported = export.export(
+        jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4))),
+        platforms=("tpu",))(
+        S((n, hidden), jnp.bfloat16), S((held, hidden, width), jnp.float32),
+        S((held, hidden, width), jnp.float32),
+        S((held, width, hidden), jnp.float32), S((n, experts), jnp.float32))
+    text = exported.mlir_module()
+    assert "ragged_dot" in text
+    assert "stablehlo.case" in text or "stablehlo.if" in text
 
 
 def test_flash_small_block_q_is_widened_to_the_lane():
