@@ -42,6 +42,8 @@ made-up constant). The peak-FLOPs table lives here (moved from bench.py).
 
 from __future__ import annotations
 
+import base64
+import binascii
 import json
 import logging
 import os
@@ -64,7 +66,7 @@ __all__ = [
     "FlightRecorder", "get_flight_recorder", "maybe_arm_from_env",
     "backend_state", "DUMP_DIR", "reset_for_tests",
     "note_executable", "scope_index", "parse_scope_index",
-    "step_counts", "count_elementwise_evals",
+    "step_counts", "count_elementwise_evals", "count_kernel_calls",
 ]
 
 logger = logging.getLogger(__name__)
@@ -360,7 +362,8 @@ def note_executable(name: str, exe, fn=None, sig=None,
         if isinstance(cost, (list, tuple)):
             cost = cost[0]
         flops = float(cost.get("flops", 0.0)) or None
-        counts = count_elementwise_evals(text)
+        counts = {**count_elementwise_evals(text),
+                  **count_kernel_calls(text)}
         if lowered is not None:
             counts["held_values"] = lowered.as_text().count(_BARRIER)
     except Exception:
@@ -400,6 +403,39 @@ def count_elementwise_evals(hlo_text: str) -> Dict[str, int]:
             "mask": len(_MASK_EVAL.findall(hlo_text))}
 
 
+#: a pallas kernel in the TPU compiler's optimized HLO: a custom call whose
+#: ``custom_call_config.body`` is the kernel's MLIR module as base64
+#: bytecode. Neither the instruction's name nor its ``op_name``
+#: (``.../pallas_call``) tells one kernel from another; the bytecode's
+#: string table carries the kernel function's name in the clear
+_TPU_KERNEL_BODY = re.compile(
+    r'custom_call_target="tpu_custom_call".*?"body":"([A-Za-z0-9+/=]*)"')
+#: label of ``zoo_step_kernel_calls`` → the kernel function
+#: (``ops/flash_attention.py``)
+KERNEL_FUNCTIONS = {"flash_fwd": b"_flash_fwd_kernel",
+                    "flash_bwd_dq": b"_flash_bwd_dq_kernel",
+                    "flash_bwd_dkv": b"_flash_bwd_dkv_kernel"}
+
+
+def count_kernel_calls(hlo_text: str) -> Dict[str, int]:
+    """``{"flash_fwd": n, "flash_bwd_dq": n, "flash_bwd_dkv": n}``: the
+    custom calls of each flash attention kernel in the optimized HLO of a
+    TPU executable. A training step reads 1, 1, 1 per attention layer
+    that takes the kernels; 2, 1, 1 where a ``jax.checkpoint`` around the
+    layer does not keep ``flash_attention.RESIDUAL_NAMES`` and the
+    backward pass launches the forward kernel again. All 0 off the TPU
+    (the interpreter inlines a kernel) and where attention is XLA's."""
+    counts = dict.fromkeys(KERNEL_FUNCTIONS, 0)
+    for body in _TPU_KERNEL_BODY.findall(hlo_text):
+        try:
+            module = base64.b64decode(body)
+        except binascii.Error:
+            continue
+        for kernel, function in KERNEL_FUNCTIONS.items():
+            counts[kernel] += function in module
+    return counts
+
+
 def _publish_counts(name: str, counts: Dict[str, int]) -> None:
     reg = telemetry.get_registry()
     if "held_values" in counts:
@@ -417,16 +453,27 @@ def _publish_counts(name: str, counts: Dict[str, int]) -> None:
                 "zoo_step_held_values accounts for means the compiler "
                 "re-derives them inside their consumers",
                 ("executable", "kind")).labels(name, kind).set(counts[kind])
+    for kernel in KERNEL_FUNCTIONS:
+        if kernel in counts:
+            reg.gauge(
+                "zoo_step_kernel_calls", "Custom calls of a pallas kernel "
+                "in the compiled program; flash_fwd twice the backward "
+                "kernels' count means a rematerialised layer launches the "
+                "forward kernel again for its output and logsumexp",
+                ("executable", "kernel")).labels(name, kernel).set(
+                    counts[kernel])
 
 
 def step_counts(name: str) -> Optional[Dict[str, int]]:
-    """``{"held_values", "erfc", "mask"}`` of the executable last compiled
-    ahead of time under ``name``: the optimization barriers of the program
-    as lowered (left out where the lowered program was not at hand) and
-    :func:`count_elementwise_evals` of its optimized HLO; ``None`` when
-    nothing was compiled under that name. The same numbers are the gauges
-    ``zoo_step_held_values{executable}`` and
-    ``zoo_step_elementwise_evals{executable,kind}``."""
+    """``{"held_values", "erfc", "mask", "flash_fwd", "flash_bwd_dq",
+    "flash_bwd_dkv"}`` of the executable last compiled ahead of time under
+    ``name``: the optimization barriers of the program as lowered (left
+    out where the lowered program was not at hand),
+    :func:`count_elementwise_evals` and :func:`count_kernel_calls` of its
+    optimized HLO; ``None`` when nothing was compiled under that name. The
+    same numbers are the gauges ``zoo_step_held_values{executable}``,
+    ``zoo_step_elementwise_evals{executable,kind}`` and
+    ``zoo_step_kernel_calls{executable,kernel}``."""
     with _executables_lock:
         rec = _executables.get(name)
     return dict(rec.counts) if rec is not None else None

@@ -41,8 +41,18 @@ import os
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
 NEG_INF = -1e30
+
+#: the names under which the backward pass's two residuals that only the
+#: forward kernel can produce — its output ``[b, s, h, d]`` and the row
+#: logsumexp ``[b·h, s]`` float32, padding already dropped — go through
+#: ``checkpoint_name``. A ``jax.checkpoint`` whose policy keeps them
+#: (``save_only_these_names(*RESIDUAL_NAMES)``) launches the forward
+#: kernel once a step; any other policy, and no ``jax.checkpoint`` at
+#: all, sees the identity
+RESIDUAL_NAMES = ("flash_attention_out", "flash_attention_lse")
 
 #: TPU vector lane width — the last dim tile the MXU/VPU want
 LANE = 128
@@ -518,9 +528,17 @@ def flash_attention(q, k, v, causal: bool = False, block_q: int = 128,
     return _flash_fwd(q, k, v, causal, block_q, block_k)
 
 
-def _fa_fwd(q, k, v, causal, block_q, block_k):
+def _named_fwd(q, k, v, causal, block_q, block_k):
+    """The forward kernel's two results as both ``custom_vjp`` rules hand
+    them to the backward, under ``RESIDUAL_NAMES``."""
     out, lse = _flash_fwd(q, k, v, causal, block_q, block_k,
                           return_lse=True)
+    return (checkpoint_name(out, RESIDUAL_NAMES[0]),
+            checkpoint_name(lse, RESIDUAL_NAMES[1]))
+
+
+def _fa_fwd(q, k, v, causal, block_q, block_k):
+    out, lse = _named_fwd(q, k, v, causal, block_q, block_k)
     return out, (q, k, v, out, lse)
 
 
@@ -544,8 +562,7 @@ def flash_attention_with_lse(q, k, v, causal: bool = False,
 
 
 def _fal_fwd(q, k, v, causal, block_q, block_k):
-    out, lse = _flash_fwd(q, k, v, causal, block_q, block_k,
-                          return_lse=True)
+    out, lse = _named_fwd(q, k, v, causal, block_q, block_k)
     return (out, lse), (q, k, v, out, lse)
 
 

@@ -3,6 +3,7 @@ the exact gelu's erfc and every dropout mask sit behind an optimization
 barrier in a differentiated program, and nowhere else; not one bit of a
 value, a gradient or a mask changes."""
 
+import contextlib
 import re
 
 import flax.linen as nn
@@ -304,12 +305,25 @@ def one_chip():
     return SingleDeviceSharding(topo.devices[0])
 
 
+@contextlib.contextmanager
+def compiled_outside_the_cache():
+    """A compile for a described chip is written to the persistent cache
+    and cannot be read back without one: keep it out."""
+    from jax.experimental.compilation_cache import compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        yield
+    finally:
+        jax.config.update("jax_enable_compilation_cache", True)
+        compilation_cache.reset_cache()
+
+
 def test_v5e_compiles_each_held_value_once_at_bert_base_width(one_chip):
     """One block of BERT-base at [32,512], bf16, compiled for the chip:
     the step's optimized HLO evaluates erfc once and generates one mask
     per dropout site. (Without the barriers the same compile reads 3
     and 10: each re-derived inside the products that read it.)"""
-    from jax.experimental.compilation_cache import compilation_cache
     from analytics_zoo_tpu.common import profiling
     module = _bert(vocab=30522, hidden_size=768, n_block=1, n_head=12,
                    intermediate_size=3072, max_position_len=512,
@@ -332,15 +346,46 @@ def test_v5e_compiles_each_held_value_once_at_bert_base_width(one_chip):
         return loss_val, jax.tree_util.tree_map(
             lambda p, g: p - 1e-3 * g, params, grads)
 
-    # a compile for a described chip is written to the persistent cache
-    # and cannot be read back without one: keep it out
-    jax.config.update("jax_enable_compilation_cache", False)
-    compilation_cache.reset_cache()
-    try:
+    with compiled_outside_the_cache():
         lowered = jax.jit(step, donate_argnums=0).lower(params, ids)
         text = lowered.compile().as_text()
-    finally:
-        jax.config.update("jax_enable_compilation_cache", True)
-        compilation_cache.reset_cache()
     assert barriers(lowered) == 1 + 3
     assert profiling.count_elementwise_evals(text) == {"erfc": 1, "mask": 3}
+
+
+@pytest.mark.parametrize("policy,forward_launches", [
+    ("no checkpoint", 1), ("products alone", 2), ("the decoder's", 1)],
+    ids=["no_checkpoint", "products_alone", "the_decoders_policy"])
+def test_v5e_launches_the_forward_kernel_once_where_its_residuals_are_kept(
+        one_chip, policy, forward_launches):
+    """``tanh(x @ w) -> flash_attention(causal) -> @ w`` at the decoder's
+    head size and untuned blocks, compiled for the chip: the compiler
+    drops the recomputation's launch of the forward kernel when the
+    checkpoint's policy keeps ``flash_attention.RESIDUAL_NAMES``, and
+    does not merge the two launches when it keeps products alone."""
+    from analytics_zoo_tpu.common import profiling
+    from analytics_zoo_tpu.ops.autotune import UNTUNED_BLOCKS
+    from analytics_zoo_tpu.ops.flash_attention import flash_attention
+    from analytics_zoo_tpu.text import hybrid_decoder
+
+    def layer(x, w):
+        y = jnp.tanh(x @ w)
+        return flash_attention(y, y, y, True, *UNTUNED_BLOCKS) @ w
+
+    if policy != "no checkpoint":
+        layer = jax.checkpoint(layer, policy={
+            "products alone": hybrid_decoder._products_saveable,
+            "the decoder's": hybrid_decoder._BLOCK_POLICY}[policy])
+    x = jax.ShapeDtypeStruct((1, 1024, 2, 64), jnp.bfloat16,
+                             sharding=one_chip)
+    w = jax.ShapeDtypeStruct((64, 64), jnp.bfloat16, sharding=one_chip)
+    # the loss reads the layer's output, so the forward pass cannot be
+    # dropped for the recomputation's sake
+    grad = jax.jit(jax.grad(
+        lambda x, w: (layer(x, w).astype(jnp.float32) ** 2).sum(),
+        argnums=(0, 1)))
+    with compiled_outside_the_cache():
+        text = grad.lower(x, w).compile().as_text()
+    assert profiling.count_kernel_calls(text) == {
+        "flash_fwd": forward_launches, "flash_bwd_dq": 1,
+        "flash_bwd_dkv": 1}

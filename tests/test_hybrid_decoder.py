@@ -16,6 +16,8 @@ from analytics_zoo_tpu.learn import losses
 from analytics_zoo_tpu.ops import attention as attention_lib
 from analytics_zoo_tpu.ops import moe as moe_lib
 from analytics_zoo_tpu.ops import short_conv
+from analytics_zoo_tpu.ops.flash_attention import RESIDUAL_NAMES
+from analytics_zoo_tpu.text import hybrid_decoder
 from benchmarks.harness import program
 from benchmarks.harness.manifest import ROOT
 from benchmarks.models import lfm2_moe as model_lib
@@ -406,6 +408,123 @@ def test_an_untuned_long_sequence_takes_the_kernel_not_the_scan(monkeypatch):
     short = jnp.zeros((1, 1024, 1, 8), jnp.bfloat16)
     autotune.auto_flash_attention(short, short, short, causal=True)
     assert calls[-1] == "scan"
+
+
+# ------------------------- what a rematerialised layer keeps by name
+
+def _eqns(jaxpr):
+    """Every equation of ``jaxpr`` and of the jaxprs in its equations'
+    parameters (``checkpoint``, ``custom_vjp_call``, ``pjit`` ...)."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _eqns(sub)
+
+
+def _count(fn, *args, primitive: str) -> int:
+    return sum(e.primitive.name == primitive
+               for e in _eqns(jax.make_jaxpr(fn)(*args).jaxpr))
+
+
+def _attention_layer(dtype):
+    """``tanh(x @ w) -> flash_attention(causal) -> @ w``: the kernel
+    between two products, as in a block of the decoder."""
+    from analytics_zoo_tpu.ops.flash_attention import flash_attention
+    kx, kw = jax.random.split(jax.random.PRNGKey(3))
+    x = jax.random.normal(kx, (1, 256, 2, 64), jnp.float32).astype(dtype)
+    w = (jax.random.normal(kw, (64, 64), jnp.float32) / 8).astype(dtype)
+
+    def layer(x, w):
+        y = jnp.tanh(x @ w)
+        return flash_attention(y, y, y, True, 128, 128) @ w
+
+    return layer, x, w
+
+
+def _grad_of(layer, policy):
+    if policy != "no checkpoint":
+        layer = jax.checkpoint(layer, policy=policy)
+    return jax.grad(
+        lambda x, w: (layer(x, w).astype(jnp.float32) ** 2).sum(),
+        argnums=(0, 1))
+
+
+@pytest.mark.parametrize("policy,launches", [
+    ("no checkpoint", 3), (hybrid_decoder._products_saveable, 4),
+    (hybrid_decoder._BLOCK_POLICY, 3)],
+    ids=["no_checkpoint", "products_alone", "the_decoders_policy"])
+def test_the_forward_kernel_is_launched_once_where_its_residuals_are_kept(
+        monkeypatch, policy, launches):
+    """Forward, dq and dk/dv kernels: three launches in a gradient. A
+    checkpoint that keeps products alone launches the forward kernel a
+    second time for the backward's ``out`` and ``lse``."""
+    monkeypatch.setenv("ZOO_PALLAS_INTERPRET", "1")
+    layer, x, w = _attention_layer(jnp.float32)
+    assert _count(_grad_of(layer, policy), x, w,
+                  primitive="pallas_call") == launches
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bfloat16"])
+def test_kept_residuals_give_the_unrematerialised_gradient_exactly(
+        monkeypatch, dtype):
+    monkeypatch.setenv("ZOO_PALLAS_INTERPRET", "1")
+    layer, x, w = _attention_layer(dtype)
+    want = jax.jit(_grad_of(layer, "no checkpoint"))(x, w)
+    got = jax.jit(_grad_of(layer, hybrid_decoder._BLOCK_POLICY))(x, w)
+    for g, r in zip(got, want):
+        assert np.asarray(r, np.float32).any()
+        assert np.array_equal(np.asarray(g), np.asarray(r))
+
+
+@pytest.mark.parametrize("primitive,params,kept", [
+    ("name", {"name": RESIDUAL_NAMES[0]}, True),
+    ("name", {"name": RESIDUAL_NAMES[1]}, True),
+    ("name", {"name": "some_other_value"}, False),
+    ("dot_general", {}, True), ("ragged_dot_general", {}, True),
+    ("exp", {}, False), ("pallas_call", {}, False)],
+    ids=["out", "lse", "another_name", "dot_general", "ragged_dot_general",
+         "exp", "pallas_call"])
+def test_a_block_keeps_its_products_and_the_kernels_residuals_only(
+        primitive, params, kept):
+    """The decoder's policy, asked about one equation."""
+    from jax._src.lax import lax as lax_internal
+    from jax._src.pallas.pallas_call import pallas_call_p
+    from jax.extend.core import primitives
+    prim = {"name": primitives.name_p,
+            "dot_general": primitives.dot_general_p,
+            "ragged_dot_general": lax_internal.ragged_dot_general_p,
+            "exp": primitives.exp_p, "pallas_call": pallas_call_p}[primitive]
+    assert prim.name == primitive
+    assert hybrid_decoder._BLOCK_POLICY(prim, **params) is kept
+
+
+def test_every_block_is_rematerialised_under_that_policy(monkeypatch):
+    import flax.linen as nn
+    handed = []
+    monkeypatch.setattr(
+        nn, "remat", lambda cls, policy: handed.append(policy) or cls)
+    module = model_lib.build_module(tiny_cfg())
+    jax.eval_shape(lambda: module.init(
+        jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32)))
+    assert handed == [hybrid_decoder._BLOCK_POLICY]
+
+
+def test_outside_differentiation_nothing_is_named(monkeypatch):
+    """``checkpoint_name`` sits in the ``custom_vjp``'s forward rule: the
+    primal function (``predict``, the tuner's timing) traces to the
+    kernel alone."""
+    from analytics_zoo_tpu.ops.flash_attention import flash_attention
+    monkeypatch.setenv("ZOO_PALLAS_INTERPRET", "1")
+    _, x, _ = _attention_layer(jnp.bfloat16)
+
+    def primal(x):
+        return flash_attention(x, x, x, True, 128, 128)
+
+    assert _count(primal, x, primitive="name") == 0
+    assert _count(primal, x, primitive="pallas_call") == 1
+    assert _count(jax.grad(lambda x: primal(x).astype(jnp.float32).sum()),
+                  x, primitive="name") == 2
 
 
 # ------------------------------------------------ tracing and counters

@@ -219,17 +219,23 @@ def test_evaluations_counted_in_three_fusions_of_the_unheld_step():
         == {"erfc": 0, "mask": 0}
 
 
+class _CannedExe:
+    """What ``note_executable`` reads of a compiled executable."""
+
+    def __init__(self, text):
+        self.text = text
+
+    def as_text(self):
+        return self.text
+
+    def cost_analysis(self):
+        return {"flops": 2.0}
+
+
 @pytest.mark.parametrize("n_lowered,want", [(0, None), (1, 3)])
 def test_note_executable_counts_and_publishes(n_lowered, want):
     with open(PARENT_FUSIONS) as fh:
         text = fh.read()
-
-    class Exe:
-        def as_text(self):
-            return text
-
-        def cost_analysis(self):
-            return {"flops": 2.0}
 
     class Lowered:
         def as_text(self):
@@ -237,7 +243,8 @@ def test_note_executable_counts_and_publishes(n_lowered, want):
                     * 3)
 
     lowered = Lowered() if n_lowered else None
-    assert profiling.note_executable("canned", Exe(), lowered=lowered) == 2.0
+    assert profiling.note_executable("canned", _CannedExe(text),
+                                     lowered=lowered) == 2.0
     counts = profiling.step_counts("canned")
     assert counts.get("held_values") == want
     assert (counts["erfc"], counts["mask"]) == (3, 3)
@@ -247,6 +254,41 @@ def test_note_executable_counts_and_publishes(n_lowered, want):
     assert snap.get("zoo_step_held_values") == (
         {"executable=canned": 3} if want else None)
     assert profiling.step_counts("no_such_executable") is None
+
+
+REMAT_LAYER = os.path.join(os.path.dirname(__file__), "fixtures",
+                           "hlo_v5e_remat_attention_layer_entry.txt")
+
+
+@pytest.mark.parametrize("fixture,want", [
+    (REMAT_LAYER, {"flash_fwd": 2, "flash_bwd_dq": 1, "flash_bwd_dkv": 1}),
+    (PARENT_FUSIONS, {"flash_fwd": 0, "flash_bwd_dq": 0,
+                      "flash_bwd_dkv": 0})],
+    ids=["a_layer_that_keeps_products_alone", "no_kernel"])
+def test_kernel_calls_counted_and_published(fixture, want):
+    """The entry computation of ``tanh(x @ w) -> flash_attention -> @ w``
+    under ``jax.checkpoint`` keeping products alone, as the v5e compiler
+    left it: the forward kernel once for the forward pass and once more
+    for the backward's ``out`` and ``lse``. BERT's fusions call no
+    kernel, and publish zeros as they do for the evaluations."""
+    with open(fixture) as fh:
+        text = fh.read()
+    assert profiling.count_kernel_calls(text) == want
+    profiling.note_executable("canned", _CannedExe(text))
+    counts = profiling.step_counts("canned")
+    assert {k: counts[k] for k in want} == want
+    assert telemetry.snapshot()["zoo_step_kernel_calls"] == {
+        f"executable=canned,kernel={k}": n for k, n in want.items()}
+    # a kernel is told by its function's name inside the call's body and
+    # by nothing else of the line
+    assert sum(profiling.count_kernel_calls(
+        text.replace('"body":"', '"body":"A')).values()) == 0
+
+
+def test_the_counted_kernel_functions_are_flash_attentions():
+    from analytics_zoo_tpu.ops import flash_attention
+    for function in profiling.KERNEL_FUNCTIONS.values():
+        assert callable(getattr(flash_attention, function.decode()))
 
 
 def test_fit_publishes_the_counts_where_metrics_are_scraped(orca_ctx):
