@@ -19,8 +19,7 @@ waits. This module packages that into a reusable **bounded in-flight window**:
 Consumers: ``serving/engine.py`` (produce → staged-dispatch → drain serve
 loop), ``inference/inference_model.py`` (chunked/streaming predict), and
 ``learn/estimator.py`` (predict keeps K batches in flight, ``device_get``
-moved out of the batch loop). ``bench.py`` measures the win as
-``serving_sync_records_per_sec`` vs ``serving_pipelined_records_per_sec``.
+moved out of the batch loop).
 """
 
 from __future__ import annotations
@@ -43,8 +42,8 @@ class StageTimer:
     ``record`` also lands in the ``zoo_stage_seconds`` histogram (labelled
     by stage) and every ``record_value`` sets the ``zoo_stage_value``
     gauge, so StageTimer consumers show up in ``GET /metrics`` Prometheus
-    exposition and BENCH snapshots for free. The local lists stay — the
-    exact-percentile ``summary()`` API is unchanged."""
+    exposition and ``telemetry.snapshot()`` for free. The local lists
+    stay — the exact-percentile ``summary()`` API is unchanged."""
 
     def __init__(self, registry: Optional[telemetry.MetricsRegistry] = None):
         self._lock = threading.Lock()

@@ -37,7 +37,7 @@ wedged. This module adds the four missing pieces:
 
 Everything degrades gracefully: no jax → ``jax-not-imported``; no
 ``memory_stats`` → live-array bytes; unknown chip → no MFU (never a
-made-up constant). The peak-FLOPs table lives here (moved from bench.py).
+made-up constant). The peak-FLOPs table ``zoo_mfu`` divides by lives here.
 """
 
 from __future__ import annotations
@@ -80,8 +80,10 @@ DUMP_DIR = os.path.join(
     os.path.dirname(os.path.dirname(os.path.dirname(
         os.path.abspath(__file__)))), "zoo_tpu_logs")
 
-# peak dense-matmul FLOP/s per chip (bf16), keyed by device_kind; override
-# with BENCH_PEAK_FLOPS / ZOO_PEAK_FLOPS. bench.py re-exports this table.
+# peak dense-matmul FLOP/s per chip (bf16), keyed by device_kind: what
+# ``zoo_mfu`` divides by. A new chip is a row here; the environment cannot
+# replace one. tests/test_profiling.py holds the rows the benchmark's own
+# table (benchmarks/peaks.json) names equal to it.
 PEAK_FLOPS = {
     "TPU v4": 275e12,
     "TPU v5 lite": 197e12,
@@ -94,13 +96,10 @@ PEAK_FLOPS = {
 
 
 def device_peak_flops(device=None) -> Optional[float]:
-    """Peak FLOP/s for ``device`` (default: first visible device), from the
-    env override (``BENCH_PEAK_FLOPS``/``ZOO_PEAK_FLOPS``) or the table.
-    ``None`` for unknown chips (CPU backend): MFU is then not published —
-    never derived from a made-up constant."""
-    for var in ("BENCH_PEAK_FLOPS", "ZOO_PEAK_FLOPS"):
-        if os.environ.get(var):
-            return float(os.environ[var])
+    """Peak FLOP/s for ``device`` (default: first visible device), from
+    ``PEAK_FLOPS`` by its ``device_kind``. ``None`` for unknown chips (CPU
+    backend): MFU is then not published — never derived from a made-up
+    constant."""
     try:
         if device is None:
             import jax
@@ -695,7 +694,7 @@ class FlightRecorder:
     last N spans, a full metrics snapshot, selected env, and the backend
     probe state to ``zoo_tpu_logs/flightrec_*.json``."""
 
-    _ENV_PREFIXES = ("ZOO_", "JAX_", "XLA_", "BENCH_", "TPU_")
+    _ENV_PREFIXES = ("ZOO_", "JAX_", "XLA_", "TPU_")
 
     def __init__(self, capacity: int = 256,
                  dump_dir: Optional[str] = None,
@@ -858,7 +857,7 @@ def get_flight_recorder(capacity: int = 256) -> FlightRecorder:
 
 def maybe_arm_from_env() -> Optional[FlightRecorder]:
     """``ZOO_FLIGHT_RECORDER=1`` → attach + arm(SIGTERM) the singleton.
-    Called from long-running entrypoints (serving engine start, bench)."""
+    Called from the serving engine's ``start()``."""
     if os.environ.get("ZOO_FLIGHT_RECORDER", "").lower() not in (
             "1", "true", "yes", "on"):
         return None

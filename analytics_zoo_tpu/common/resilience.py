@@ -8,7 +8,7 @@ supervised, tested behavior here rather than a hang:
   (``ZOO_FAULT_PLAN``) hooked into the dispatch/probe seams of
   ``compile_ahead.ExecutableCache``, ``pipeline_io.DevicePipeline`` and
   ``profiling.backend_state``, plus the estimator's step loop, so tests
-  and bench can wedge the backend on demand **without a TPU**. A plan is
+  can wedge the backend on demand **without a TPU**. A plan is
   a comma-separated list of ``kind@site[:start[+more]]`` specs:
 
   - ``wedge@step:12``     — the 12th training-step dispatch raises
@@ -190,7 +190,7 @@ def get_injector() -> Optional[FaultInjector]:
 
 
 def install_plan(plan: Optional[str]) -> Optional[FaultInjector]:
-    """Install a fault plan programmatically (tests, bench drills) —
+    """Install a fault plan programmatically (tests) —
     fresh counters; ``None``/empty clears."""
     global _INJECTOR, _INJ_LOADED
     with _INJ_LOCK:
@@ -252,7 +252,7 @@ def probe_fault() -> Optional[str]:
 
 @contextmanager
 def fault_drill(plan: str, cpu_fallback: bool = True):
-    """Scoped wedge drill for tests and bench: install ``plan`` with
+    """Scoped wedge drill for tests: install ``plan`` with
     fresh counters (and force the CPU-fallback gate on), restore
     everything — injector, env, supervisor singleton — on exit."""
     prev_env = os.environ.get("ZOO_CPU_FALLBACK")

@@ -49,8 +49,8 @@ from typing import Any, Dict, Iterable, List, Optional, Sequence, Tuple
 __all__ = [
     "Counter", "Gauge", "Histogram", "MetricsRegistry", "Span", "Tracer",
     "get_registry", "get_tracer", "prometheus_text", "snapshot",
-    "bench_snapshot", "instrument_jit", "traced_device_put",
-    "traced_device_get", "observe_device_block", "timed_block_until_ready",
+    "instrument_jit", "traced_device_put", "traced_device_get",
+    "observe_device_block", "timed_block_until_ready",
     "set_trace_sampling", "reset_for_tests", "dump_trace", "annotation",
     "install_compile_counters", "ANNOTATION_PREFIX",
 ]
@@ -61,8 +61,7 @@ DEFAULT_BUCKETS = (1e-4, 5e-4, 1e-3, 5e-3, 0.01, 0.025, 0.05, 0.1, 0.25,
 RESERVOIR_SIZE = 1024
 #: how many reservoir samples ride a JSON snapshot per histogram series —
 #: enough for stable p50/p99 on the merged side, small enough that a
-#: snapshot stays a one-line payload (fleet scrapes and BENCH records
-#: both carry it)
+#: snapshot stays a one-line payload (fleet scrapes carry it)
 SNAPSHOT_RESERVOIR = 256
 
 _NAME_OK = frozenset(
@@ -509,7 +508,7 @@ class MetricsRegistry:
     def snapshot(self) -> Dict[str, Any]:
         """JSON-able view: counters/gauges as values, histograms as
         {count, sum, mean, p50, p99, le, bucket_counts, reservoir} — what
-        rides BENCH records and the JSON ``/metrics`` response. ``le`` is
+        rides the JSON ``/metrics`` response. ``le`` is
         the bucket upper-edge list and ``bucket_counts`` the per-bucket
         (NOT cumulative) counts with the +Inf bucket last, so two
         snapshots of the same series are mergeable by addition
@@ -581,7 +580,7 @@ class MetricsRegistry:
         keys round-trip through the snapshot's ``k=v,k2=v2`` encoding
         (label VALUES therefore must not contain ``,`` or ``=`` — true
         for every catalog metric). Entries that are not valid metric
-        families (e.g. ``trace_ids_held``) are skipped."""
+        families are skipped."""
         reg = cls()
         for name, val in snap.items():
             try:
@@ -838,16 +837,6 @@ def reset_for_tests():
     ts = sys.modules.get("analytics_zoo_tpu.common.timeseries")
     if ts is not None:
         ts.reset_for_tests()
-
-
-def bench_snapshot() -> Dict[str, Any]:
-    """Trimmed snapshot for the one-line BENCH JSON: every counter/gauge,
-    histograms as compact stats, plus the trace-store size — small enough
-    to ride the record, complete enough to reconstruct the perf story."""
-    snap = snapshot()
-    with _TRACER._lock:
-        snap["trace_ids_held"] = len(_TRACER._traces)
-    return snap
 
 
 # ------------------------------------------------------------- JAX hooks

@@ -39,7 +39,7 @@ def _as_list(x) -> List[str]:
 
 def _fast_enabled() -> bool:
     """``ZOO_DATA_VECTORIZE=0`` restores every legacy body — row-wise
-    kernels *and* gather-style aggregations — as one parity/bench toggle."""
+    kernels *and* gather-style aggregations — as one parity toggle."""
     return os.environ.get("ZOO_DATA_VECTORIZE", "1").strip().lower() \
         not in ("0", "false", "off")
 

@@ -793,8 +793,8 @@ class DecodeScheduler:
                    enc_shape=None) -> Optional[dict]:
         """Synchronously measure gather-vs-paged for one step shape and
         persist the verdict ``paged="auto"`` dispatch consults (what
-        bench.py and tests call; the serve path tunes in the background
-        instead). Shape arguments default to the live sequences'.
+        tests call; the serve path tunes in the background instead).
+        Shape arguments default to the live sequences'.
         Returns None when no paged seam or allocator exists yet."""
         if self._paged_step_fn is None or self._alloc is None:
             return None

@@ -1026,7 +1026,7 @@ class JaxEstimator:
                               throughput: float, step_seconds: float):
         """One window's training scalars go BOTH ways: TF-events (the
         existing TensorBoard surface) and the telemetry registry (the
-        Prometheus/BENCH surface) — same numbers, one call site."""
+        Prometheus surface) — same numbers, one call site."""
         reg = telemetry.get_registry()
         reg.gauge("zoo_training_loss",
                   "Last flushed training loss").set(loss)

@@ -48,14 +48,14 @@ from analytics_zoo_tpu.common.profiling import (  # noqa: F401  (re-exports)
     maybe_arm_from_env, scope_index, step_counts,
 )
 from analytics_zoo_tpu.common.telemetry import (  # noqa: F401  (re-exports)
-    MetricsRegistry, Span, Tracer, bench_snapshot, get_registry, get_tracer,
+    MetricsRegistry, Span, Tracer, get_registry, get_tracer,
     instrument_jit, observe_device_block, prometheus_text, set_trace_sampling,
     snapshot, timed_block_until_ready, traced_device_get, traced_device_put,
 )
 
 __all__ = [
     "scrape", "metrics", "trace", "trace_table", "get_registry",
-    "get_tracer", "instrument_jit", "set_trace_sampling", "bench_snapshot",
+    "get_tracer", "instrument_jit", "set_trace_sampling",
     "prometheus_text", "snapshot", "traced_device_put", "traced_device_get",
     "observe_device_block", "timed_block_until_ready",
     "chrome_trace", "dump_trace", "StepProfiler", "FlightRecorder",

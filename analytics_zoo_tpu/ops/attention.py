@@ -35,7 +35,7 @@ def dot_product_attention(q, k, v, mask=None, causal: bool = False,
     ``ops.autotune.auto_flash_attention`` — the tuned block config when
     the measurement says the kernel beats blockwise, the blockwise
     reference otherwise — so forcing flash can never be slower than the
-    fallback (the 0.676× regression class from BENCH r5).
+    fallback as measured.
     """
     if use_flash is None:
         use_flash = _flash_ok(q, k, mask)

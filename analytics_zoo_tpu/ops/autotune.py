@@ -1,18 +1,18 @@
 """Kernel block-size autotuner: measure, cache, fall back by construction.
 
-BENCH_builder_r5_onchip.json is the motivation: the pallas flash kernel at
-its default (128, 128) blocks ran at 0.676× its own blockwise-jax fallback
-— a hand-picked config lost to XLA and *nothing noticed*. This module makes
-block-size choice empirical and the fallback automatic — for a kernel that
-*loses*, never for one that *breaks*: on a TPU backend a candidate that
-raises is a bug, logged and re-raised, and a verdict that carries an error
-is never written to disk.
+A hand-picked block size can lose to XLA's own code for the same
+computation without anything noticing. This module makes block-size
+choice empirical and the fallback automatic — for a kernel that *loses*,
+never for one that *breaks*: on a TPU backend a candidate that raises is
+a bug, logged and re-raised, and a verdict that carries an error is never
+written to disk.
 
 - ``Autotuner.tune`` times every candidate config against the
-  numerics-reference implementation on the same chained-dependency harness
-  bench.py uses (each iteration's input folds in the previous output, so
-  the final fence covers the whole chain — unordered dispatches would let
-  XLA overlap all iterations and under-report per-call latency).
+  numerics-reference implementation on one chained-dependency harness
+  (``_time_candidate``: each iteration's input folds in the previous
+  output, so the final fence covers the whole chain — unordered
+  dispatches would let XLA overlap all iterations and under-report
+  per-call latency).
 - The verdict (winning config + whether it actually beats the reference)
   persists to a JSON cache next to the compile cache directory, so a
   serving process pays the measurement once per (shape, dtype, backend)
@@ -20,8 +20,7 @@ is never written to disk.
 - Dispatchers (``auto_flash_attention`` here, the fused embedding-bag in
   ops/embedding_bag.py) consult the cached verdict: no verdict or a losing
   kernel means the reference path runs. A tuned kernel can therefore never
-  be slower than the fallback — the 0.676× regression class is structurally
-  impossible.
+  be slower than the fallback as measured.
 - Misses during tracing (model build under jit) enqueue the shape; the
   compile-ahead warmup worker (common/compile_ahead.py) calls
   ``tune_pending()`` off the serve thread, so tuning never blocks a
@@ -30,7 +29,7 @@ is never written to disk.
 Env knobs (documented in docs/kernels.md and docs/observability.md):
 
 - ``ZOO_AUTOTUNE``: ``on`` (default: cached verdicts + background tuning),
-  ``sync`` (tune at first miss, blocking — what bench.py wants), ``off``
+  ``sync`` (tune at the first concrete-argument miss, blocking), ``off``
   (no tuning; auto dispatchers always take the reference path).
 - ``ZOO_AUTOTUNE_CACHE``: verdict cache path (default
   ``<checkout>/zoo_tpu_logs/autotune.json``, beside the compile cache).
@@ -57,8 +56,7 @@ logger = logging.getLogger(__name__)
 
 DEFAULT_CACHE_PATH = os.path.join(DUMP_DIR, "autotune.json")
 
-#: candidate (block_q, block_k) grid for the flash kernels — the same grid
-#: bench.py swept by hand before the tuner existed
+#: candidate (block_q, block_k) grid for the flash kernels
 ATTENTION_BLOCKS: Tuple[Tuple[int, int], ...] = (
     (128, 128), (128, 256), (256, 256), (256, 512), (512, 512))
 
@@ -192,9 +190,9 @@ class Autotuner:
     # ----------------------------------------------------------- timing
     @staticmethod
     def _time_candidate(fn, args, iters: int, chain=None) -> float:
-        """Mean per-call seconds with honest fencing (bench.py `timed`
-        idiom): ``chain(out, args)`` folds each result into the next
-        call's arguments so the closing fence covers every iteration."""
+        """Mean per-call seconds with honest fencing: ``chain(out, args)``
+        folds each result into the next call's arguments so the closing
+        fence covers every iteration."""
         if chain is None:
             chain = lambda out, a: a
         f = jax.jit(fn)

@@ -1,9 +1,8 @@
 """Fused embedding-bag pallas kernels for the recsys path.
 
-BENCH_builder_r5_onchip.json shows NCF gather-bound: 20.0M staged
-samples/s vs 92.3M with the dataset HBM-resident — the per-step cost is
-dominated by N separate XLA gathers (one per embedding table) each making
-its own pass over HBM. The kernels here do the whole lookup in one pass:
+A recommender's embedding lookup compiles to N separate XLA gathers (one
+per embedding table), each making its own pass over HBM. The kernels here
+do the whole lookup in one pass:
 
 - ``fused_embedding_lookup`` — N tables, one id column per table
   (``ids[b, t]`` indexes table ``t``), combined row-wise

@@ -415,7 +415,8 @@ def gather_decision(pool, table) -> bool:
     not even an enqueue: this runs at trace time, possibly while the
     model lock is held, so the whole path must stay measurement-free.
     The kernel engages only where a persisted verdict already says it
-    wins (bench/tests/warmup call ``tune_paged_gather`` explicitly);
+    wins (chip_smoke.py, tests and warmup call ``tune_paged_gather``
+    explicitly);
     until then the pure-jax reference serves."""
     from analytics_zoo_tpu.ops import autotune
     if autotune._mode() == "off" or not autotune.kernels_available():

@@ -126,8 +126,8 @@ class ClusterServing:
 
     ``pipeline_window``: how many dispatched batches may be in flight on
     the device while the loop dequeues/preprocesses the next ones (0 =
-    fully synchronous dispatch, the pre-pipeline behavior — kept as the
-    measured baseline for bench.py's sync-vs-pipelined comparison).
+    fully synchronous dispatch — the reference tests/test_pipeline_io.py
+    compares the windowed results with, bit for bit).
 
     ``max_batch_size``: cap for adaptive batch growth. Under sustained
     backlog (every dequeue returns a full batch) the engine steps its
@@ -462,7 +462,7 @@ class ClusterServing:
         # error drains the window onto pre-built CPU executables and keeps
         # serving degraded until the supervisor reports recovered. The
         # flag/t0/seconds are written on the serve thread and read from
-        # frontend/bench threads — all under _state_lock.
+        # frontend/test threads — all under _state_lock.
         self._cpu_fallback = resilience.cpu_fallback_enabled()
         self._supervisor: Optional[resilience.BackendSupervisor] = None
         self._failover = False
@@ -1025,8 +1025,9 @@ class ClusterServing:
 
     def wait_warm(self, timeout: Optional[float] = None
                   ) -> "ClusterServing":
-        """Block until the background ladder compiles finish (tests and
-        bench cold-start timing; no-op for duck-typed models)."""
+        """Block until the background ladder compiles finish (tests,
+        chip_smoke.py and the benchmark's serve drivers; no-op for
+        duck-typed models)."""
         fn = getattr(self.model, "wait_warm", None)
         if fn is not None:
             fn(timeout=timeout)
@@ -1322,7 +1323,7 @@ class ClusterServing:
         with self._state_lock:
             t0, self._failover_t0 = self._failover_t0, None
         if t0 is not None:
-            # drain → first CPU result: serving_failover_seconds in bench
+            # drain → first CPU result
             dt = time.perf_counter() - t0
             with self._state_lock:
                 self.failover_seconds.append(dt)

@@ -3,8 +3,8 @@
 
 Drives the two main paths once, through the entry points a user calls, on
 one TPU process, at the full width of BERT-base (12 blocks, hidden 768,
-12 heads, vocab 30522, bf16 compute, batch 32, seq 128 — the shape
-``bench.measure_bert`` uses) with random weights made from a seed:
+12 heads, vocab 30522, bf16 compute, batch 32, seq 128 — the shape of
+the cell ``bert-base-train-b32-s128``) with random weights made from a seed:
 
 - trainer: ``init_orca_context`` → ``Estimator.from_flax`` → ``fit`` on the
   default path and on the ``steps_per_loop`` scan path, then ``NeuralCF``
@@ -36,9 +36,8 @@ import sys
 import time
 
 #: switches that would let something other than the compiled chip path
-#: pass for it (interpreter, CPU failover, injected faults, a made-up peak)
-REFUSED_ENV = ("ZOO_PALLAS_INTERPRET", "ZOO_CPU_FALLBACK", "ZOO_FAULT_PLAN",
-               "ZOO_PEAK_FLOPS", "BENCH_PEAK_FLOPS")
+#: pass for it (interpreter, CPU failover, injected faults)
+REFUSED_ENV = ("ZOO_PALLAS_INTERPRET", "ZOO_CPU_FALLBACK", "ZOO_FAULT_PLAN")
 
 BERT_BATCH, BERT_SEQ = 32, 128
 BERT_STEPS = 8                  # optimizer steps per epoch, both paths
@@ -149,8 +148,7 @@ class Phase:
 
 
 def _bert_classifier():
-    """BERT-base encoder + 2-way head, the module ``bench.measure_bert``
-    trains (one input: token ids)."""
+    """BERT-base encoder + 2-way head (one input: token ids)."""
     import flax.linen as nn
     import jax.numpy as jnp
     from analytics_zoo_tpu.text.bert import BertConfig, BertModule

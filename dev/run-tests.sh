@@ -85,28 +85,13 @@ case "$lane" in
                 tests/test_attention.py tests/test_pipeline.py tests/test_moe.py ;;
   # data plane (ISSUE 12): pooled shard executor, vectorized Friesian
   # kernels with bitwise legacy parity, tiered bounded-residency
-  # pipeline, streaming prefetch — then a tiny recsys pipeline measure
-  # gating the never-slower transform dispatch (docs/data_plane.md)
+  # pipeline, streaming prefetch, the whole recsys chain into a fit
+  # (docs/data_plane.md)
   data)     run tests/test_data.py tests/test_native_store.py \
                 tests/test_feature.py tests/test_friesian.py \
                 tests/test_friesian_parity.py tests/test_data_plane.py \
                 tests/test_image3d_parquet.py tests/test_elastic_search.py \
-                tests/test_tfrecord.py
-            echo "== recsys pipeline smoke (never-slower transform dispatch)"
-            JAX_PLATFORMS=cpu python - <<'PY'
-import bench
-bench.RECSYS_ROWS, bench.RECSYS_SHARDS = 1500, 4
-bench.RECSYS_USERS, bench.RECSYS_ITEMS = 60, 40
-bench.RECSYS_BATCH = 128
-out = bench.measure_recsys_pipeline()
-assert out["recsys_pipeline_samples_per_sec"] > 0, out
-assert out["friesian_transform_speedup"] >= 1.0, out
-print(f"recsys OK: {out['recsys_pipeline_samples_per_sec']} samples/s "
-      f"(data included), transform speedup "
-      f"{out['friesian_transform_speedup']}x "
-      f"[{out['recsys_transform_mode']}]")
-PY
-            ;;
+                tests/test_tfrecord.py ;;
   keras)    run tests/test_keras.py tests/test_keras_layers_golden.py \
                 tests/test_keras2_multihost.py tests/test_nnframes_autograd.py ;;
   models)   run tests/test_model_zoo.py tests/test_recommendation.py \
@@ -120,41 +105,10 @@ PY
   interop)  run tests/test_inference_net.py tests/test_onnx.py \
                 tests/test_openvino.py ;;
   examples) run tests/test_examples.py ;;
-  # observability: unit tests, then an armed bench smoke that must leave
-  # a flight-recorder postmortem (the dump path CI would rely on after a
-  # wedged TPU round is exercised on every lane run, not just on wedges)
+  # observability: registry, tracer, step profiler, and the armed
+  # flight recorder's postmortem (the dump path after a wedged run)
   telemetry) lint_zoolint
-            run -m "not slow" tests/test_telemetry.py tests/test_profiling.py
-            echo "== bench --smoke telemetry (flight recorder armed)"
-            frdir="$(mktemp -d)"
-            ZOO_FLIGHT_RECORDER=1 ZOO_FLIGHT_RECORDER_DIR="$frdir" \
-              JAX_PLATFORMS=cpu python bench.py --smoke telemetry \
-              > "$frdir/smoke.json"
-            python - "$frdir" <<'PY'
-import glob, json, sys
-frdir = sys.argv[1]
-rec = json.load(open(frdir + "/smoke.json"))
-assert rec["mode"] == "smoke" and "telemetry" in rec, rec.keys()
-assert "bench_regression" in rec, "regression gate missing from record"
-dumps = glob.glob(frdir + "/flightrec_*.json")
-assert dumps, "armed smoke left no flight-recorder dump"
-d = json.load(open(dumps[0]))
-assert d["kind"] == "zoo_flight_recorder" and d["spans"], d.get("kind")
-assert rec.get("flight_recorder") in dumps, "record does not point at dump"
-# compile-ahead serve path (ISSUE 5): after the ladder warmup the burst
-# must cross at least one bucket-growth boundary with ZERO recompiles —
-# a stall-free swap onto an already-AOT-compiled rung
-assert rec.get("serving_post_warmup_recompiles") == 0, \
-    f"serve path recompiled after warmup: {rec.get('serving_post_warmup_recompiles')}"
-assert rec.get("serving_bucket_growth", 0) >= 1, \
-    f"burst never crossed a bucket boundary: {rec.get('serving_bucket_growth')}"
-assert rec.get("serving_cold_start_seconds", -1) >= 0, \
-    "cold-start metric missing from smoke record"
-print(f"flight recorder OK: {len(d['spans'])} spans in {dumps[0]}")
-print(f"compile-ahead OK: growth={rec['serving_bucket_growth']} "
-      f"recompiles=0 cold_start={rec['serving_cold_start_seconds']}s")
-PY
-            ;;
+            run -m "not slow" tests/test_telemetry.py tests/test_profiling.py ;;
   # pallas kernels + autotuner (ISSUE 8): flash/embedding-bag parity on
   # the CPU interpreter, then a smoke proving the autotune dispatch NEVER
   # picks a config slower than the numerics-reference fallback — the
@@ -202,39 +156,13 @@ PY
               exit 1
             fi
             ;;
-  # wedge resilience (ISSUE 7): fault injector, backend supervisor,
-  # checkpoint fallback, fit auto-resume, serving failover — then an
-  # armed bench smoke whose built-in wedge drill must leave a
-  # backend-wedged postmortem AND a completed CPU failover on the record
-  resilience) run -m "not slow" tests/test_resilience.py
-            echo "== bench --smoke resilience (wedge drill armed)"
-            frdir="$(mktemp -d)"
-            ZOO_FLIGHT_RECORDER=1 ZOO_FLIGHT_RECORDER_DIR="$frdir" \
-              JAX_PLATFORMS=cpu python bench.py --smoke resilience \
-              > "$frdir/smoke.json"
-            python - "$frdir" <<'PY'
-import glob, json, sys
-frdir = sys.argv[1]
-rec = json.load(open(frdir + "/smoke.json"))
-assert rec["mode"] == "smoke", rec.keys()
-# the drill's wedge completed a measured failover: every record served,
-# drain->first-CPU-result latency on the (lower-better-gated) record
-assert rec.get("serving_failover_seconds", -1) >= 0, \
-    f"no completed failover on record: {rec.get('serving_failover_seconds')}"
-assert rec.get("serving_failover_episodes", 0) >= 1, \
-    "supervisor never entered wedged during the drill"
-# the supervisor wedge verdict left exactly one latched postmortem
-dumps = [p for p in glob.glob(frdir + "/flightrec_*.json")
-         if json.load(open(p)).get("reason") == "backend-wedged"]
-assert len(dumps) == 1, f"expected 1 backend-wedged dump, got {len(dumps)}"
-print(f"failover OK: {rec['serving_failover_seconds']}s "
-      f"episodes={rec['serving_failover_episodes']} dump={dumps[0]}")
-PY
-            ;;
+  # wedge resilience (ISSUE 7): fault injector, backend supervisor
+  # (one backend-wedged postmortem an episode), checkpoint fallback, fit
+  # auto-resume, serving failover onto the CPU rungs and back
+  resilience) run -m "not slow" tests/test_resilience.py ;;
   # multi-replica delivery contract (ISSUE 9): lease/XCLAIM semantics on
   # both broker backends, client reconnect retry, orphan detection, and
-  # the 2-replica SIGKILL chaos drill (slow-marked, runs here) — then a
-  # bench smoke gating the scaling floor and replica-kill failover. The
+  # the 2-replica SIGKILL chaos drill (slow-marked, runs here). The
   # seeded zoolint fixture must flag an undeclared zoo_serving_* family:
   # a quiet drift check on the new delivery metrics means the linter
   # regressed, not that the tree is clean.
@@ -253,41 +181,13 @@ PY
               echo "zoolint missed the seeded non-daemon thread leak" >&2
               exit 1
             fi
-            echo "== bench --smoke chaos (replica-kill drill + scaling floor)"
-            outdir="$(mktemp -d)"
-            ZOO_FLIGHT_RECORDER_DIR="$outdir" \
-              JAX_PLATFORMS=cpu python bench.py --smoke chaos \
-              > "$outdir/smoke.json"
-            python - "$outdir" <<'PY'
-import json, sys
-rec = json.load(open(sys.argv[1] + "/smoke.json"))
-assert rec["mode"] == "smoke", rec.keys()
-# consumer-group fan-out really scales: 2 replicas on one stream must
-# beat one by the acceptance floor (sleep-dominated duck model, so the
-# ratio is host-independent)
-scaling = rec.get("serving_replica_scaling", 0.0)
-assert scaling >= 1.5, f"2-replica scaling below floor: {scaling}"
-# the SIGKILL drill completed: zero loss is asserted inside the measure;
-# the record must carry the (lower-better-gated) failover latency and a
-# visible redelivery in exactly one reclaim sweep
-fo = rec.get("serving_replica_failover_seconds", -1)
-assert fo >= 0, f"no completed replica-kill failover on record: {fo}"
-assert rec.get("serving_replica_kill_redelivered", 0) >= 1, \
-    "kill drill recorded no redelivery"
-assert rec.get("serving_replica_lease_reclaims", 0) == 1, \
-    f"expected one reclaim sweep: {rec.get('serving_replica_lease_reclaims')}"
-print(f"chaos OK: scaling={scaling} failover={fo}s "
-      f"redelivered={rec['serving_replica_kill_redelivered']} "
-      f"sweeps={rec['serving_replica_lease_reclaims']}")
-PY
             ;;
   # SLO-aware continuous batching (ISSUE 10): priority lanes on both
   # broker backends, weighted-deficit scheduling, deadline expiry,
   # admission control, the lane/lease SIGKILL drill (slow-marked, runs
-  # here) — then a mixed-traffic bench smoke gating interactive p99
-  # under a batch-lane flood. The seeded zoolint fixture must flag an
-  # undeclared per-lane metric: a quiet drift check on the scheduling
-  # metrics means the linter regressed, not that the tree is clean.
+  # here). The seeded zoolint fixture must flag an undeclared per-lane
+  # metric: a quiet drift check on the scheduling metrics means the
+  # linter regressed, not that the tree is clean.
   scheduling) run tests/test_priority.py
             echo "== zoolint: drift must flag undeclared lane metrics/knobs"
             drift="$(python -m analytics_zoo_tpu.analysis --no-baseline \
@@ -313,33 +213,11 @@ PY
               echo "zoolint missed the seeded cross-file lock inversion" >&2
               exit 1
             fi
-            echo "== bench --smoke scheduling (batch-lane flood drill)"
-            outdir="$(mktemp -d)"
-            ZOO_FLIGHT_RECORDER_DIR="$outdir" \
-              JAX_PLATFORMS=cpu python bench.py --smoke scheduling \
-              > "$outdir/smoke.json"
-            python - "$outdir" <<'PY'
-import json, sys
-rec = json.load(open(sys.argv[1] + "/smoke.json"))
-assert rec["mode"] == "smoke", rec.keys()
-# interactive p99 stayed within budget while the batch lane was flooded
-# (zero loss + zero expiries are asserted inside the measure)
-p99 = rec.get("serving_p99_interactive_ms", -1)
-budget = rec.get("serving_interactive_budget_ms", 0)
-assert 0 <= p99 <= budget, \
-    f"interactive p99 {p99}ms blew the {budget}ms budget under flood"
-rps = rec.get("serving_priority_records_per_sec", 0)
-assert rps > 0, "mixed-traffic drill recorded no throughput"
-assert rec.get("serving_priority_flood_records", 0) > 0, \
-    "drill ran without a batch-lane flood"
-print(f"scheduling OK: interactive p99={p99}ms (budget {budget}ms) "
-      f"mixed throughput={rps} rec/s")
-PY
             ;;
   # sharded executor seam + bucketed decode (ISSUE 14): dispatch
   # equivalence and recompile-flat warm rungs on the forced 8-device
-  # mesh, bitwise rung-padding parity, the end-to-end generate flow —
-  # then the sharded/decode bench measures at smoke size. The seeded
+  # mesh, bitwise rung-padding parity, the end-to-end generate flow, a
+  # sharded model behind the engine across a bucket boundary. The seeded
   # zoolint fixture must flag undeclared zoo_shard_* / zoo_decode_*
   # names: a quiet drift check on the new families means the linter
   # regressed, not that the tree is clean.
@@ -357,38 +235,12 @@ PY
                 exit 1
               fi
             done
-            echo "== bench sharded/decode smoke (8 forced host devices)"
-            JAX_PLATFORMS=cpu \
-              XLA_FLAGS="--xla_force_host_platform_device_count=8" \
-              python - <<'PY'
-import bench
-bench.SERVE_BATCH, bench.SERVE_HIDDEN = 8, 32
-bench.DECODE_BATCH, bench.DECODE_STEPS, bench.DECODE_HIDDEN = 4, 8, 16
-sh = bench.measure_serving_sharded()
-# the tentpole's proof obligations: every device carries a strict
-# fraction of the model, and a post-warmup burst crossing a bucket
-# growth boundary never recompiles
-assert sh.get("serving_sharded_n_shards") == 8, sh
-assert 0 < sh["serving_sharded_max_shard_fraction"] < 1.0, sh
-assert sh["serving_sharded_post_warmup_recompiles"] == 0, sh
-assert sh["serving_sharded_bucket_growth"] >= 1, sh
-assert sh["serving_sharded_records_per_sec"] > 0, sh
-dec = bench.measure_decode()
-assert dec["decode_tokens_per_sec"] > 0, dec
-assert dec["decode_post_warmup_recompiles"] == 0, dec
-print(f"sharded OK: {sh['serving_sharded_records_per_sec']} rec/s "
-      f"max_shard_fraction={sh['serving_sharded_max_shard_fraction']} "
-      f"growth={sh['serving_sharded_bucket_growth']} recompiles=0")
-print(f"decode OK: {dec['decode_tokens_per_sec']} tok/s "
-      f"p99={dec['decode_p99_ms']}ms recompiles=0")
-PY
             ;;
   # step-level continuous batching + paged KV + speculative decode
   # (ISSUE 16): scheduler parity/spec units, the sampling contract, the
-  # kv-page-leak dataflow rule — the seeded allocator leaks must fire by
-  # file — then a bench smoke gating the interleaved-streams speedup,
-  # the self-draft accept ratio at exactly 1.0, and interactive p99
-  # under a live decode flood.
+  # kv-page-leak dataflow rule, the warmed decode grid, the self-draft
+  # accept ratio at exactly 1.0, a generate flood under interactive
+  # probes — the seeded allocator leaks must fire by file.
   decode)   run -m "not slow" tests/test_decode_scheduler.py \
                 tests/test_generation.py tests/test_zoolint_dataflow.py
             echo "== zoolint: seeded kv page leaks must fire"
@@ -414,43 +266,13 @@ PY
                 exit 1
               fi
             done
-            echo "== bench decode smoke (continuous batching + spec + mixed)"
-            JAX_PLATFORMS=cpu python - <<'PY'
-import bench
-bench.DECODE_BATCH, bench.DECODE_STEPS, bench.DECODE_HIDDEN = 4, 8, 16
-bench.MIXED_FLOOD, bench.MIXED_INT, bench.MIXED_STEPS = 6, 6, 8
-dec = bench.measure_decode()
-# interleaving N streams through one scheduler must beat draining them
-# serially (both run the same warmed executables — the delta is pure
-# step-sharing), and the self-drafted speculative pass accepts every
-# token (bitwise identity vs plain greedy is asserted inside)
-assert dec["decode_concurrent_speedup"] >= 1.0, dec
-assert dec["decode_spec_accept_ratio"] == 1.0, dec
-assert dec["decode_post_warmup_recompiles"] == 0, dec
-# the paged-attention verdict is never-slower by construction (a losing
-# measurement dispatches the gather fallback and reports 1.0), and the
-# paged run's outputs are asserted bitwise against plain decode inside
-assert dec["decode_paged_attn_speedup"] >= 1.0, dec
-assert dec["decode_kv_bytes_per_seq"] > 0, dec
-mix = bench.measure_decode_mixed()
-p99, budget = (mix["decode_mixed_interactive_p99_ms"],
-               mix["decode_mixed_interactive_budget_ms"])
-assert 0 <= p99 <= budget, mix
-print(f"decode OK: concurrent speedup "
-      f"{dec['decode_concurrent_speedup']}x "
-      f"accept_ratio={dec['decode_spec_accept_ratio']} "
-      f"paged={dec['decode_paged_attn_speedup']}x "
-      f"kv_bytes/seq={dec['decode_kv_bytes_per_seq']}")
-print(f"mixed OK: interactive p99={p99}ms (budget {budget}ms) "
-      f"preemptions={mix['decode_mixed_preemptions_total']}")
-PY
             ;;
   # metric history + cost attribution (ISSUE 17): the windowed store's
   # quantile/rate algebra, exemplar->/trace links, fleet window merge,
-  # the end-to-end cost drill (slow-marked, runs here) — then the bench
-  # history drill scraping /metrics/history mid-flood. The seeded
-  # zoolint fixture must flag an undeclared zoo_ts_* name: a quiet
-  # drift check on the new families means the linter regressed.
+  # the end-to-end cost drill (slow-marked, runs here), the lane-depth
+  # ring scraped from /metrics/history mid-flood. The seeded zoolint
+  # fixture must flag an undeclared zoo_ts_* name: a quiet drift check
+  # on the new families means the linter regressed.
   observability) run tests/test_timeseries.py
             echo "== zoolint: drift must flag undeclared history names"
             drift="$(python -m analytics_zoo_tpu.analysis --no-baseline \
@@ -461,23 +283,6 @@ PY
                 exit 1
               fi
             done
-            echo "== bench metric-history smoke (flood + mid-drill scrape)"
-            JAX_PLATFORMS=cpu python - <<'PY'
-import bench
-bench.HIST_FLOOD, bench.HIST_GEN = 48, 2
-# the measure itself asserts ramp -> sustain -> recover on the lane
-# depth ring, a mid-drill non-empty scrape, >= 1 exemplar resolving on
-# /trace, and encode+generate request-cost settlement
-h = bench.measure_metric_history()
-assert h["history_lane_depth_peak"] > 0, h
-assert h["history_ring_points"] >= 3, h
-assert h["history_exemplar_links"] >= 1, h
-assert h["history_records_per_sec"] > 0, h
-print(f"history OK: peak={h['history_lane_depth_peak']} "
-      f"points={h['history_ring_points']} "
-      f"p99(60s)={h['history_p99_60s_ms']}ms "
-      f"exemplars={h['history_exemplar_links']}")
-PY
             ;;
   release)  bash "$(dirname "$0")/release.sh" ;;
   all)      lint_zoolint

@@ -278,7 +278,7 @@ def _attn_args(s_q=64, s_k=64, d=64, dtype=jnp.float32):
 
 
 def test_attention_candidates_filter():
-    # full grid survives at bench shapes; tiny shapes get one clamped cfg
+    # full grid survives at long sequences; tiny shapes get one clamped cfg
     big = autotune._attention_candidates(2048, 2048)
     assert set(big) == {"128x128", "128x256", "256x256", "256x512",
                         "512x512"}

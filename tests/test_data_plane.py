@@ -260,3 +260,51 @@ def test_prefetch_env_default(monkeypatch):
         HostXShards([pd.DataFrame({"f": [1.0], "label": [0]})]),
         feature_cols=["f"], label_cols="label")
     assert ds.prefetch_depth == 4
+
+
+# ------------------------------------------- the whole chain into a fit
+
+def test_recsys_chain_streams_into_an_ncf_fit(parallel_env, orca_ctx):
+    """Raw interactions with string codes through the whole Friesian chain
+    (index fit and encode, history sequences, negative samples, crossed
+    columns, pad and mask, merged features), then
+    ``to_streaming_dataset`` straight into ``NeuralCF``'s ``fit``: every
+    row the chain made reaches the estimator, and every epoch's loss is
+    a number."""
+    from analytics_zoo_tpu.learn.optimizers import Adam
+    from analytics_zoo_tpu.models.recommendation import NeuralCF
+
+    rows, users, items, seq = 1200, 50, 40, 8
+    rng = np.random.default_rng(11)
+    u = rng.integers(0, users, rows)
+    i = rng.integers(0, items, rows)
+    df = pd.DataFrame({
+        "user_code": np.char.add("u", u.astype(str)),
+        "item_code": np.char.add("i", i.astype(str)),
+        "time": rng.integers(0, 100_000, rows),
+    })
+    t = FeatureTable.from_pandas(df, 4)
+    indices = t.gen_string_idx(["user_code", "item_code"])
+    t = t.encode_string(["user_code", "item_code"], indices)
+    t = t.rename({"user_code": "user", "item_code": "item"})
+    t = t.add_hist_seq("user", ["item"], sort_col="time",
+                       min_len=1, max_len=seq)
+    t = t.add_negative_samples(item_size=items, item_col="item", neg_num=1)
+    t = t.cross_columns([["user", "item"]], [100])
+    t = t.mask_pad(padding_cols=["item_hist_seq"],
+                   mask_cols=["item_hist_seq"], seq_len=seq)
+    t = t.add_length("item_hist_seq")
+    t = t.merge_cols(["user", "item"], "features")
+    assert {"features", "label", "item_hist_seq",
+            "item_hist_seq_length"} <= set(t.col_names())
+
+    ds = t.to_streaming_dataset(["features"], "label", prefetch_depth=2)
+    assert ds.n == t.size() > 0
+    ncf = NeuralCF(user_count=users, item_count=items, class_num=2,
+                   user_embed=16, item_embed=16, hidden_layers=(32, 16),
+                   include_mf=True, mf_embed=16)
+    ncf.compile(optimizer=Adam(1e-3),
+                loss="sparse_categorical_crossentropy")
+    est = ncf.model._ensure_estimator(for_training=True)
+    history = est.fit(ds, epochs=2, batch_size=128)
+    assert len(history["loss"]) == 2 and np.all(np.isfinite(history["loss"]))

@@ -313,6 +313,49 @@ def test_engine_defers_decode_to_hotter_lane_with_starvation_floor():
     sched.abort_all()
 
 
+def test_engine_answers_a_generate_flood_and_the_probes_between_its_steps():
+    """Several generate records live in the engine's step scheduler at
+    once while interactive predicts arrive on the same stream: every
+    record of both lanes ends in a result of its own shape, none
+    expires, and each generation is what the model generates alone."""
+    from analytics_zoo_tpu.inference import InferenceModel
+    from analytics_zoo_tpu.models import Seq2Seq
+    from analytics_zoo_tpu.serving import (
+        Broker, ClusterServing, InputQueue, OutputQueue,
+    )
+
+    n_gen, n_probe, steps = 6, 6, 8
+    m = Seq2Seq(input_dim=8, output_dim=8, hidden_size=16, rnn_type="gru",
+                encoder_seq_len=8, decoder_seq_len=4)
+    im = InferenceModel().load_zoo(m)
+    rng = np.random.default_rng(29)
+    encs = rng.standard_normal((n_gen, 8, 8)).astype(np.float32)
+    start = np.zeros(8, np.float32)
+    probe_dec = np.zeros((4, 8), np.float32)
+    with Broker.launch(backend="python") as broker, \
+            ClusterServing(im, broker.port, batch_size=4, max_batch_size=4,
+                           block_ms=10, warmup=False) as eng:
+        in_q = InputQueue(port=broker.port)
+        out_q = OutputQueue(port=broker.port)
+        flood = list(in_q.enqueue_batch(
+            ((f"fg{i}", {"x": encs[i], "start": start})
+             for i in range(n_gen)),
+            priority="batch", generate={"max_new_tokens": steps}))
+        for i in range(n_probe):
+            u = in_q.enqueue(f"fp{i}", priority="interactive",
+                             deadline_ms=120_000.0,
+                             a_enc=encs[i % n_gen], b_dec=probe_dec)
+            r = out_q.query(u, timeout=120.0)
+            assert r is not None and r.shape == (4, 8), u
+        res = out_q.query_many(flood, timeout=120.0)
+        assert eng.metrics()["records_expired"] == 0
+    assert all(v is not None for v in res.values())
+    alone = im.generate(encs, np.tile(start, (n_gen, 1)), steps)
+    for i, u in enumerate(flood):
+        assert res[u].shape == (steps, 8)
+        np.testing.assert_allclose(res[u], alone[i], rtol=1e-5, atol=1e-5)
+
+
 # ------------------------------------------- paged step seam (ISSUE 20)
 
 def _paged_fn(fn):

@@ -8,6 +8,7 @@ directly above the fence are skipped (e.g. TPU-pod-only or
 network-dependent snippets).
 """
 
+import functools
 import os
 import re
 import subprocess
@@ -41,9 +42,7 @@ _SLOW_DOCS = {"model-zoo.md", "zouwu.md"}
 
 
 def _doc_files():
-    docs = sorted(f for f in os.listdir(DOCS)
-                  if f.endswith(".md") and f not in ("BERT_MFU.md",
-                                                     "INT8_CEILING.md"))
+    docs = sorted(f for f in os.listdir(DOCS) if f.endswith(".md"))
     return [pytest.param(d, marks=pytest.mark.slow) if d in _SLOW_DOCS
             else d for d in docs]
 
@@ -83,3 +82,136 @@ def test_observability_catalog_matches_code():
     from analytics_zoo_tpu.analysis import catalog_drift
     findings = catalog_drift(root=REPO)
     assert findings == [], "\n".join(f.format() for f in findings)
+
+
+# ------------------------------------------------- names that must resolve
+
+_CODE_SPAN = re.compile(r"`([^`\n]+)`")
+_LINK = re.compile(r"\]\(([^)\s]+)\)")
+#: what a name may carry behind the file: ``::test``, ``:118``, ``:72-85``
+_SUFFIX = re.compile(r"(::[\w\[\]-]+|:\d+(-\d+)?(,\d+(-\d+)?)*)+$")
+_CHECKED_EXT = (".py", ".sh", ".md")
+_CHECKED_DIRS = ("tests/", "dev/", "docs/", "benchmarks/", "examples/",
+                 "analytics_zoo_tpu/")
+#: written at run time into directories .gitignore lists
+_RUNTIME_DIRS = ("zoo_tpu_logs/", "build/", "native/build/", "chiprun_out/")
+#: what a sentence says before a name it gives as the reference's own
+_REFERENCE_LEAD = re.compile(r"\b(ref|reference|upstream)\b", re.I)
+_SENTENCE_END = re.compile(r"\.\s|\n\s*\n|\n\s*[-*] |\|")
+_WALKED = ("analytics_zoo_tpu", "tests", "benchmarks", "examples", "dev",
+           "docs", "docker")
+
+
+@functools.lru_cache(maxsize=None)
+def _basenames():
+    names = set(os.listdir(REPO))
+    for top in _WALKED:
+        for _, _, files in os.walk(os.path.join(REPO, top)):
+            names.update(files)
+    return names
+
+
+def _resolves(name, doc_dir, basenames):
+    if "/" not in name:
+        return name in basenames
+    name = name.rstrip("/")
+    return any(os.path.exists(os.path.join(root, name))
+               for root in (REPO, doc_dir,
+                            os.path.join(REPO, "analytics_zoo_tpu")))
+
+
+def _defines(name, members):
+    """``tests/test_x.py::TestY::test_z``: the file defines each member."""
+    if not members:
+        return True
+    with open(os.path.join(REPO, name)) as fh:
+        source = fh.read()
+    return all(re.search(rf"^\s*(def|class) {m}\b", source, re.M)
+               for m in members)
+
+
+def unresolved_names(path):
+    """The names in one document that point at nothing in the checkout."""
+    text = open(path).read()
+    text = re.sub(r"```.*?```", "", text, flags=re.S)
+    doc_dir = os.path.dirname(path)
+    basenames = _basenames()
+    found = []
+    for m in list(_CODE_SPAN.finditer(text)) + list(_LINK.finditer(text)):
+        lead = _SENTENCE_END.split(text[max(0, m.start() - 400):m.start()])
+        if _REFERENCE_LEAD.search(lead[-1]):
+            continue
+        for word in m.group(1).split():
+            word = word.strip("()[],;\"'").split("#")[0]
+            name = _SUFFIX.sub("", word)
+            if not (name.endswith(_CHECKED_EXT)
+                    or name.startswith(_CHECKED_DIRS)):
+                continue
+            if re.search(r"[*<>{}$]|\.\.\.|…|^[/~]|^\w+://", name) \
+                    or name.startswith(_RUNTIME_DIRS):
+                continue
+            if not _resolves(name, doc_dir, basenames) \
+                    or not _defines(name, re.findall(r"::(\w+)", word)):
+                found.append(word)
+    return found
+
+
+def _named_documents():
+    """The documents that describe the tree as it is (BASELINE.md and
+    MIGRATION.md are tables of the reference's own paths; ROADMAP.md,
+    PERF.md and CHANGES.md are history)."""
+    return ["README.md", "PARITY.md", ".claude/skills/verify/SKILL.md"] \
+        + sorted(os.path.join("docs", f) for f in os.listdir(DOCS)
+                 if f.endswith(".md"))
+
+
+@pytest.mark.parametrize("doc", _named_documents())
+def test_names_of_scripts_and_documents_resolve(doc):
+    """Every back-ticked word and every markdown link of a document that
+    names a script or a document of this repository points at something
+    in the checkout: a ``*.py``, ``*.sh`` or ``*.md`` (bare, it resolves
+    against the root and every file name of the tree; with a directory,
+    against the root, the document's own directory and
+    ``analytics_zoo_tpu/``), or a path under ``tests/``, ``dev/``,
+    ``docs/``, ``benchmarks/``, ``examples/``, ``analytics_zoo_tpu/``,
+    with or without ``::test`` or ``:line`` behind it. A document that
+    tells its reader to run a file that is gone fails here.
+
+    Skipped by shape, never by name: patterns (``BENCH_r*.json``,
+    ``<family>.py``, ``${VAR}``, ``...``), absolute paths and URLs, files
+    written at run time under the git-ignored ``zoo_tpu_logs/``,
+    ``build/`` and ``chiprun_out/``, fenced code blocks (the doc-test lane
+    runs those), and names the sentence gives as the reference
+    repository's (``ref``, ``reference`` or ``upstream`` earlier in the
+    same sentence, list item or table cell)."""
+    assert unresolved_names(os.path.join(REPO, doc)) == []
+
+
+# ----------------------------------------------------- dev/run-tests.sh
+
+RUN_TESTS = os.path.join(REPO, "dev", "run-tests.sh")
+
+
+@functools.lru_cache(maxsize=None)
+def _lanes():
+    """{lane: the ``tests/...`` paths its branch of the ``case`` names}."""
+    with open(RUN_TESTS) as fh:
+        body = fh.read().split('case "$lane" in', 1)[1]
+    lanes, lane = {}, None
+    for line in body.splitlines():
+        label = re.match(r"  ([\w-]+)\)", line)
+        if label:
+            lane = label.group(1)
+        if lane and not line.lstrip().startswith("#"):
+            lanes.setdefault(lane, []).extend(
+                re.findall(r"\btests/[\w./-]*", line))
+    return {k: v for k, v in lanes.items() if v}
+
+
+@pytest.mark.parametrize("lane", sorted(_lanes()))
+def test_lane_names_tests_that_exist(lane):
+    """A lane of ``dev/run-tests.sh`` runs zoolint and pytest over files
+    that are there, and the script parses."""
+    for path in _lanes()[lane]:
+        assert os.path.exists(os.path.join(REPO, path)), path
+    subprocess.run(["bash", "-n", RUN_TESTS], check=True)

@@ -209,3 +209,61 @@ def test_decode_spans_land_on_the_trace(s2s, s2s_inputs):
     assert {"decode_step_1", "decode_step_2", "decode_step_3"} <= names
     assert all(s.parent == "device" for s in spans
                if s.name.startswith("decode_step_"))
+
+
+# ------------------------------------- InferenceModel: warmed grid, draft
+
+def _compiles_on_the_hot_path() -> float:
+    """Dispatches of the model's forward that found no executable built
+    ahead of time: compiled in band, or left to plain jit."""
+    snap = telemetry.snapshot()
+    return sum(float(snap.get(name, {}).get("fn=inference_model", 0.0))
+               for name in ("zoo_compile_cache_misses_total",
+                            "zoo_jit_cache_misses_total"))
+
+
+def _counter(name) -> float:
+    val = telemetry.snapshot().get(name, 0.0)
+    return float(val if isinstance(val, (int, float)) else 0.0)
+
+
+def test_warmed_decode_grid_never_recompiles_and_self_draft_accepts_all():
+    """``warm_decode`` builds the (batch rung x seq rung) grid ahead of
+    time, so a decode loop that grows through the seq rungs compiles
+    nothing; the same model drafting for itself proposes tokens that are
+    all accepted, and the speculative result is the plain greedy loop's
+    bit for bit."""
+    from analytics_zoo_tpu.inference import InferenceModel
+    from analytics_zoo_tpu.models import Seq2Seq
+
+    batch, steps = 4, 8
+    m = Seq2Seq(input_dim=8, output_dim=8, hidden_size=16, rnn_type="gru",
+                encoder_seq_len=8, decoder_seq_len=4)
+    im = InferenceModel().load_zoo(m)
+    rng = np.random.default_rng(7)
+    enc = rng.standard_normal((batch, 8, 8)).astype(np.float32)
+    start = np.zeros((batch, 8), np.float32)
+    # one predict registers the two-input spec the grid is built from
+    im.predict((enc, np.zeros((batch, 1, 8), np.float32)))
+    im.set_ladder(compile_ahead.BucketLadder(batch, batch))
+    im.warm_decode(steps + 1, block=True)
+
+    def step(e, d):
+        return np.asarray(im.predict_fetch(im.predict_async((e, d))))
+
+    base = _compiles_on_the_hot_path()
+    plain = generation.decode_loop(step, enc, start, steps,
+                                   ladder=generation.seq_ladder(steps + 1),
+                                   mode="greedy")
+    assert plain.shape == (batch, steps, 8)
+    assert _compiles_on_the_hot_path() == base, \
+        "the decode loop met a shape warm_decode had not built"
+
+    im.warm_decode(steps + 1, verify_k=4, block=True)
+    proposed0 = _counter("zoo_spec_proposed_total")
+    accepted0 = _counter("zoo_spec_accepted_total")
+    spec = im.generate(enc, start, steps, mode="greedy", draft=im, spec_k=4)
+    assert np.array_equal(spec, plain)
+    proposed = _counter("zoo_spec_proposed_total") - proposed0
+    assert proposed > 0
+    assert _counter("zoo_spec_accepted_total") - accepted0 == proposed

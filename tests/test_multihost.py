@@ -192,3 +192,15 @@ def test_non_process_major_batch_layout_refused():
         env=dict(os.environ))
     assert proc.returncode != 0
     assert "do not span the processes" in proc.stdout + proc.stderr
+
+
+def test_entry_is_jittable(orca_ctx):
+    """``__graft_entry__.entry()`` hands the driver a forward step it can
+    put under ``jax.jit`` as it is."""
+    import jax
+    sys.path.insert(0, REPO)
+    import __graft_entry__
+
+    fn, args = __graft_entry__.entry()
+    out = jax.jit(fn)(*args)
+    assert jax.tree_util.tree_leaves(out)[0].shape[0] == 8

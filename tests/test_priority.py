@@ -266,8 +266,9 @@ def test_starvation_protection_batch_drains_under_interactive_load():
             priority="batch")
         with ClusterServing(model, b.port, batch_size=n_batch,
                             max_batch_size=n_batch, pipeline_window=1,
-                            warmup=False):
+                            warmup=False) as eng:
             res = out_q.query_many(uris, timeout=30.0)
+            assert eng.metrics()["records_expired"] == 0
         assert all(v is not None for v in res.values())
         batch_markers = {float(100 + i) for i in range(n_batch)}
         hit = [i for i, call in enumerate(model.calls)

@@ -347,10 +347,13 @@ def _tiny_inference_model():
     return InferenceModel().load_flax(Net(), np.zeros((4, 5), np.float32))
 
 
-def test_serving_wedge_failover_recover_swap_back(orca_ctx):
+def test_serving_wedge_failover_recover_swap_back(orca_ctx, tmp_path,
+                                                  monkeypatch):
     """Acceptance (ISSUE 7): full in-process cycle — wedge mid-stream,
     drain to the pre-built CPU rungs with ZERO dropped records, recover
-    when probes heal, swap dispatch back to the device."""
+    when probes heal, swap dispatch back to the device; the episode
+    leaves exactly one ``backend-wedged`` postmortem."""
+    monkeypatch.setenv("ZOO_FLIGHT_RECORDER_DIR", str(tmp_path))
     from analytics_zoo_tpu.common import resilience
     from analytics_zoo_tpu.serving import (
         Broker, ClusterServing, InputQueue, OutputQueue,
@@ -385,6 +388,12 @@ def test_serving_wedge_failover_recover_swap_back(orca_ctx):
                 time.sleep(0.1)
             assert sup.state == "ok"
             assert not eng.failover_active
+    reasons = []
+    for name in os.listdir(tmp_path):
+        if name.startswith("flightrec_"):
+            with open(tmp_path / name) as fh:
+                reasons.append(json.load(fh)["reason"])
+    assert reasons == ["backend-wedged"]
 
 
 _REPLICA_SCRIPT = """

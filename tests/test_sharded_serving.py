@@ -44,6 +44,12 @@ def _jit_misses() -> float:
     return float(fam.get("fn=inference_model", 0.0))
 
 
+def _in_band_compiles() -> float:
+    """Dispatches that found no executable built ahead of time."""
+    fam = telemetry.snapshot().get("zoo_compile_cache_misses_total", {})
+    return float(fam.get("fn=inference_model", 0.0))
+
+
 def _net_and_params():
     net = _Net()
     params = net.init(jax.random.PRNGKey(0),
@@ -89,7 +95,7 @@ def test_replicated_params_without_rules():
     ex = ShardedExecutable(lambda p, x: net.apply(p, x), params, "tp8")
     hbm = ex.shard_hbm_bytes(publish=False)
     # no rules matched → every shard holds the full model (the failure
-    # mode the max_shard_fraction bench gate exists to catch)
+    # mode the strict-fraction assertions of this file exist to catch)
     assert max(hbm.values()) == ex.total_param_bytes()
 
 
@@ -138,6 +144,45 @@ def test_sharded_warm_ladder_dispatches_recompile_flat():
         out = im.predict(rng.randn(n, 16).astype(np.float32))
         assert np.asarray(out).shape == (n, 8)
     assert _jit_misses() == base
+
+
+def test_sharded_engine_burst_crosses_a_rung_without_recompiling():
+    """A tensor-parallel model behind the engine: every device holds a
+    strict fraction of it, warm-up walks the ladder with sharded avals,
+    and a burst deep enough to step the bucket up is answered whole
+    through executables built ahead of time."""
+    from analytics_zoo_tpu.serving import (
+        Broker, ClusterServing, InputQueue, OutputQueue,
+    )
+
+    net, params = _net_and_params()
+    im = InferenceModel().load_flax(net, np.zeros((1, 16), np.float32),
+                                    params=params)
+    im.shard("tp8", param_rules=RULES)
+    info = im.shard_info()
+    assert info["n_shards"] == 8
+    assert 0 < max(info["shard_hbm_bytes"].values()) \
+        < info["total_param_bytes"]
+    rng = np.random.RandomState(21)
+    # dequeues at the bottom rung come back full well past the engine's
+    # grow-after streak
+    xs = {f"sh{i}": rng.randn(16).astype(np.float32) for i in range(48)}
+    with Broker.launch() as broker, \
+            ClusterServing(im, broker.port, batch_size=2, min_batch_size=2,
+                           max_batch_size=8, pipeline_window=2) as eng:
+        eng.wait_warm(timeout=240.0)
+        base = _jit_misses() + _in_band_compiles()
+        uris = InputQueue(port=broker.port).enqueue_batch(
+            (u, {"x": v}) for u, v in xs.items())
+        res = OutputQueue(port=broker.port).query_many(uris, timeout=120.0)
+        peak = eng.batch_size
+    assert all(v is not None for v in res.values())
+    assert peak > 2, "burst never crossed a bucket-growth boundary"
+    assert _jit_misses() + _in_band_compiles() == base, \
+        "the burst met a rung warm-up had not built"
+    ref = np.asarray(net.apply(params, np.stack(list(xs.values()))))
+    np.testing.assert_allclose(np.stack([res[u] for u in xs]), ref,
+                               rtol=1e-5, atol=1e-5)
 
 
 # ------------------------------------------------------ fleet merge

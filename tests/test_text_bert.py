@@ -187,7 +187,7 @@ class TestEstimators:
 
 def test_remat_forward_and_grad_equivalence(orca_ctx):
     """BertConfig(remat=True) recomputes activations in backward without
-    changing forward outputs or gradients (docs/BERT_MFU.md)."""
+    changing forward outputs or gradients."""
     import jax
     import jax.numpy as jnp
     from analytics_zoo_tpu.text.bert import BertConfig, BertModule

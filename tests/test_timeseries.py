@@ -290,6 +290,76 @@ def test_query_exemplar_rides_trace_sampling_decision():
 # --------------------------------------------- HTTP surface, end-to-end
 
 
+def test_lane_depth_ring_shows_a_flood_ramp_and_recover(monkeypatch):
+    """A batch-lane flood behind a live ``FrontEnd``, the store sampling
+    on a fast tick: ``/metrics/history`` shows the lane's depth at zero
+    before the flood, readable above zero while the flood still drains,
+    at a batch or more at its peak, and back at zero once every record
+    is answered."""
+    import time
+
+    from analytics_zoo_tpu.serving import (
+        Broker, ClusterServing, FrontEnd, InputQueue, OutputQueue,
+    )
+
+    tick_s, batch, n = 0.05, 4, 96
+
+    class SleepDuck:
+        def predict(self, x):
+            time.sleep(0.025)
+            return np.asarray(x) * 2.0
+
+    def batch_depths(base):
+        hist = _get_json(base + "/metrics/history"
+                                "?name=zoo_serving_lane_depth")
+        return [p["value"] for s in hist["series"]
+                if s["name"] == "zoo_serving_lane_depth"
+                and s["labels"].get("priority") == "batch"
+                for p in s["points"]]
+
+    def wait_for(base, what, cond, timeout=30.0):
+        deadline = time.monotonic() + timeout
+        while time.monotonic() < deadline:
+            depths = batch_depths(base)
+            if cond(depths):
+                return depths
+            time.sleep(tick_s)
+        raise AssertionError(f"{what}: {depths[-8:]}")
+
+    # the lane gauges refresh on the engine's admission tick: at its
+    # default second the whole flood drains between two refreshes
+    monkeypatch.setenv("ZOO_SERVING_ADMISSION_S", str(tick_s))
+    timeseries.set_store(timeseries.TimeSeriesStore(tick_s=tick_s))
+    rng = np.random.default_rng(31)
+    payloads = rng.standard_normal((n, 6)).astype(np.float32)
+    with Broker.launch(backend="python") as broker:
+        eng = ClusterServing(SleepDuck(), broker.port, batch_size=batch,
+                             max_batch_size=batch, pipeline_window=2,
+                             block_ms=10, warmup=False)
+        fe = FrontEnd(broker.port, engine=eng)
+        try:
+            with eng.start():
+                fe.start()
+                base = f"http://127.0.0.1:{fe.port}"
+                wait_for(base, "no zero point before the flood",
+                         lambda d: d and d[-1] == 0)
+                flood = InputQueue(port=broker.port).enqueue_batch(
+                    ((f"hb{i}", {"x": payloads[i]}) for i in range(n)),
+                    priority="batch")
+                wait_for(base, "no ramp readable while the flood drains",
+                         lambda d: d and max(d) > 0, timeout=10.0)
+                res = OutputQueue(port=broker.port).query_many(
+                    flood, timeout=90.0)
+                assert all(v is not None for v in res.values())
+                depths = wait_for(
+                    base, "the lane's depth never came back to 0",
+                    lambda d: d and d[-1] == 0)
+        finally:
+            fe.stop()
+    assert min(depths) == 0 and max(depths) >= batch
+    assert len(depths) >= 3
+
+
 @pytest.mark.slow
 def test_history_query_cost_and_healthz_decode_end_to_end():
     """Acceptance drill (ISSUE 17): encode + generate records flow
