@@ -67,6 +67,7 @@ __all__ = [
     "backend_state", "DUMP_DIR", "reset_for_tests",
     "note_executable", "scope_index", "parse_scope_index",
     "step_counts", "count_elementwise_evals", "count_kernel_calls",
+    "count_flash_grid_steps",
 ]
 
 logger = logging.getLogger(__name__)
@@ -362,7 +363,8 @@ def note_executable(name: str, exe, fn=None, sig=None,
             cost = cost[0]
         flops = float(cost.get("flops", 0.0)) or None
         counts = {**count_elementwise_evals(text),
-                  **count_kernel_calls(text)}
+                  **count_kernel_calls(text),
+                  **count_flash_grid_steps(text)}
         if lowered is not None:
             counts["held_values"] = lowered.as_text().count(_BARRIER)
     except Exception:
@@ -406,14 +408,46 @@ def count_elementwise_evals(hlo_text: str) -> Dict[str, int]:
 #: ``custom_call_config.body`` is the kernel's MLIR module as base64
 #: bytecode. Neither the instruction's name nor its ``op_name``
 #: (``.../pallas_call``) tells one kernel from another; the bytecode's
-#: string table carries the kernel function's name in the clear
+#: string table carries the kernel function's name in the clear. Ahead of
+#: the body, among the call's frontend attributes, rides what the launch
+#: gave ``pl.pallas_call`` as ``metadata`` (group 1; empty for a launch
+#: that gave none)
 _TPU_KERNEL_BODY = re.compile(
-    r'custom_call_target="tpu_custom_call".*?"body":"([A-Za-z0-9+/=]*)"')
+    r'custom_call_target="tpu_custom_call"'
+    r'(?:.*?kernel_metadata=\{([^{}]*)\})?.*?"body":"([A-Za-z0-9+/=]*)"')
+#: XLA prints a call's ``kernel_metadata``, where it is not empty, one key
+#: a line: the one place where an instruction of the optimized text does
+#: not end on the line it began
+_KERNEL_METADATA = re.compile(r"kernel_metadata=\{[^{}]*\}")
 #: label of ``zoo_step_kernel_calls`` → the kernel function
 #: (``ops/flash_attention.py``)
 KERNEL_FUNCTIONS = {"flash_fwd": b"_flash_fwd_kernel",
                     "flash_bwd_dq": b"_flash_bwd_dq_kernel",
                     "flash_bwd_dkv": b"_flash_bwd_dkv_kernel"}
+#: label ``kind`` of ``zoo_flash_grid_steps``: the kinds of tile a flash
+#: kernel's launch lists (``flash_attention.TILE_KINDS``)
+TILE_KINDS = ("interior", "diagonal", "dead")
+
+
+def _one_line_each(hlo_text: str) -> str:
+    """``hlo_text`` with every instruction on one line."""
+    return _KERNEL_METADATA.sub(
+        lambda m: m.group(0).replace("\n", ""), hlo_text)
+
+
+def _flash_launches(hlo_text: str):
+    """``(label, metadata)`` of every custom call of a flash attention
+    kernel in the optimized HLO of a TPU executable: the kernel's label
+    in ``KERNEL_FUNCTIONS`` and the launch's ``kernel_metadata`` as a
+    dict of strings."""
+    for metadata, body in _TPU_KERNEL_BODY.findall(_one_line_each(hlo_text)):
+        try:
+            module = base64.b64decode(body)
+        except binascii.Error:
+            continue
+        for kernel, function in KERNEL_FUNCTIONS.items():
+            if function in module:
+                yield kernel, dict(re.findall(r'"(\w+)":"(\w*)"', metadata))
 
 
 def count_kernel_calls(hlo_text: str) -> Dict[str, int]:
@@ -425,13 +459,26 @@ def count_kernel_calls(hlo_text: str) -> Dict[str, int]:
     backward pass launches the forward kernel again. All 0 off the TPU
     (the interpreter inlines a kernel) and where attention is XLA's."""
     counts = dict.fromkeys(KERNEL_FUNCTIONS, 0)
-    for body in _TPU_KERNEL_BODY.findall(hlo_text):
-        try:
-            module = base64.b64decode(body)
-        except binascii.Error:
-            continue
-        for kernel, function in KERNEL_FUNCTIONS.items():
-            counts[kernel] += function in module
+    for kernel, _ in _flash_launches(hlo_text):
+        counts[kernel] += 1
+    return counts
+
+
+def count_flash_grid_steps(hlo_text: str) -> Dict[str, int]:
+    """``{"<kernel>/<kind>": n}``: the grid steps the launches of each
+    flash attention kernel in the optimized HLO of a TPU executable take,
+    by kind of tile (``TILE_KINDS``), as the launch listed them ahead of
+    time (``flash_attention.tile_table``) and wrote them into its call's
+    metadata; summed over the kernel's launches, all heads. A causal
+    launch reads ``dead`` 0 unless it has query blocks that see no key at
+    all. No key for a kernel the program does not launch: an executable
+    without the kernels gives ``{}``."""
+    counts: Dict[str, int] = {}
+    for kernel, metadata in _flash_launches(hlo_text):
+        for kind in TILE_KINDS:
+            if metadata.get(kind, "").isdigit():
+                key = f"{kernel}/{kind}"
+                counts[key] = counts.get(key, 0) + int(metadata[kind])
     return counts
 
 
@@ -461,18 +508,30 @@ def _publish_counts(name: str, counts: Dict[str, int]) -> None:
                 "forward kernel again for its output and logsumexp",
                 ("executable", "kernel")).labels(name, kernel).set(
                     counts[kernel])
+    for key in counts:
+        kernel, _, kind = key.partition("/")
+        if kind:
+            reg.gauge(
+                "zoo_flash_grid_steps", "Grid steps the launches of a "
+                "flash attention kernel in the compiled program take, by "
+                "kind of tile: interior (no mask), diagonal (masked), "
+                "dead (no work: kept only where a block has no live tile)",
+                ("executable", "kernel", "kind")).labels(
+                    name, kernel, kind).set(counts[key])
 
 
 def step_counts(name: str) -> Optional[Dict[str, int]]:
     """``{"held_values", "erfc", "mask", "flash_fwd", "flash_bwd_dq",
     "flash_bwd_dkv"}`` of the executable last compiled ahead of time under
-    ``name``: the optimization barriers of the program as lowered (left
-    out where the lowered program was not at hand),
-    :func:`count_elementwise_evals` and :func:`count_kernel_calls` of its
+    ``name``, and ``"<kernel>/<kind>"`` for each flash kernel it launches:
+    the optimization barriers of the program as lowered (left out where
+    the lowered program was not at hand), :func:`count_elementwise_evals`,
+    :func:`count_kernel_calls` and :func:`count_flash_grid_steps` of its
     optimized HLO; ``None`` when nothing was compiled under that name. The
     same numbers are the gauges ``zoo_step_held_values{executable}``,
-    ``zoo_step_elementwise_evals{executable,kind}`` and
-    ``zoo_step_kernel_calls{executable,kernel}``."""
+    ``zoo_step_elementwise_evals{executable,kind}``,
+    ``zoo_step_kernel_calls{executable,kernel}`` and
+    ``zoo_flash_grid_steps{executable,kernel,kind}``."""
     with _executables_lock:
         rec = _executables.get(name)
     return dict(rec.counts) if rec is not None else None
@@ -564,7 +623,7 @@ def parse_scope_index(hlo_text: str) -> Dict[str, dict]:
     calling instruction's scope."""
     computations: Dict[str, list] = {}
     entry = current = None
-    for line in hlo_text.splitlines():
+    for line in _one_line_each(hlo_text).splitlines():
         if not line:
             continue
         if not line[0].isspace():

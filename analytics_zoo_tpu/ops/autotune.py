@@ -62,9 +62,16 @@ ATTENTION_BLOCKS: Tuple[Tuple[int, int], ...] = (
 
 #: float32 scores [b, h, s_q, s_k] beyond which the pallas path is the
 #: only one that fits (``ops/attention._flash_ok``'s switch), and the
-#: block sizes it runs with until a verdict names better ones
+#: block sizes it runs with until a verdict names better ones: the fastest
+#: of eleven pairs timed forward AND backward at [2, 8192, 32, 64]
+#: bfloat16 causal on a v5e (PERF.md, PR 34; ``tune_attention`` times the
+#: forward alone), and the largest whose float32 score tile, 4 MB, the
+#: kernels' VMEM holds beside the operands' blocks. That room is there
+#: while a head's lane-padded row is within ``UNTUNED_HEAD_ROW`` bytes
+#: (bfloat16 to 256 wide, float32 to 128); a wider head takes 512 x 512
 SCORES_SWITCH = 1 << 31
-UNTUNED_BLOCKS = (512, 512)
+UNTUNED_BLOCKS = (1024, 1024)
+UNTUNED_HEAD_ROW = 512
 
 _lock = threading.RLock()
 _tuner: Optional["Autotuner"] = None
@@ -433,6 +440,14 @@ def attention_decision(b: int, s_q: int, s_k: int, h: int, d: int, dtype,
     return None
 
 
+def untuned_blocks(head_dim: int, dtype) -> Tuple[int, int]:
+    """The flash kernels' ``(block_q, block_k)`` for a shape no verdict
+    names: ``UNTUNED_BLOCKS`` where the head's row leaves VMEM the room."""
+    from analytics_zoo_tpu.ops.flash_attention import LANE, ceil_to
+    row = ceil_to(head_dim, LANE) * jnp.dtype(dtype).itemsize
+    return UNTUNED_BLOCKS if row <= UNTUNED_HEAD_ROW else (512, 512)
+
+
 def auto_flash_attention(q, k, v, causal: bool = False):
     """Verdict-driven attention dispatch: the tuned flash config when the
     measurement says it wins, the blockwise reference otherwise. This is
@@ -452,5 +467,6 @@ def auto_flash_attention(q, k, v, causal: bool = False):
         # keeps every block's probabilities, [b, h, s, s] in all), the
         # kernels' backward is not
         from analytics_zoo_tpu.ops.flash_attention import flash_attention
-        return flash_attention(q, k, v, causal, *UNTUNED_BLOCKS)
+        return flash_attention(q, k, v, causal,
+                               *untuned_blocks(d, q.dtype))
     return blockwise_attention(q, k, v, causal=causal)

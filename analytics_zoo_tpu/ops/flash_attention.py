@@ -8,7 +8,8 @@ ref pyzoo/zoo/pipeline/api/keras/layers/self_attention.py). Two tiers:
   (``lax.scan`` over key blocks): O(seq·block) memory, differentiable,
   runs on any backend. This is the numerics reference for the kernel.
 - ``flash_attention`` — pallas TPU kernels for forward AND backward: the
-  forward grid (batch·heads, q-blocks, k-blocks) runs online softmax in
+  forward grid (batch·heads, the head's live tiles: ``tile_table``) runs
+  online softmax in
   VMEM with fp32 accumulators and saves the per-row logsumexp; the
   backward is the FlashAttention-2 two-kernel split (dq over key blocks,
   dk/dv over query blocks) reconstructing p = exp(s − lse) — no O(s²)
@@ -41,6 +42,7 @@ import os
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.ad_checkpoint import checkpoint_name
 
 NEG_INF = -1e30
@@ -174,11 +176,141 @@ def _pad_axis(a, axis: int, to: int):
     return jnp.pad(a, widths)
 
 
+# ---------------------------------------------------------------- tile table
+#
+# Every quantity that decides which tiles of the score matrix hold work is
+# static at trace time, so a launch lists its grid steps ahead of time and
+# visits nothing else. A tile (qi, ki) is one of three kinds:
+
+#: every score of the tile is wanted: computed with no mask at all
+INTERIOR = 0
+#: the causal diagonal or the padded key tail crosses the tile: computed
+#: under the mask
+DIAGONAL = 1
+#: the causal triangle holds nothing of the tile: no grid step — but for a
+#: resident block with no live tile at all (``sk < sq``), which keeps one
+#: step that computes nothing, so that its zeros are still written
+DEAD = 2
+#: the kinds by name, in the order of their codes (the labels of
+#: ``zoo_flash_grid_steps``, common/profiling.py)
+TILE_KINDS = ("interior", "diagonal", "dead")
+
+
+@functools.lru_cache(maxsize=None)
+def tile_table(nq: int, nk: int, block_q: int, block_k: int, causal: bool,
+               causal_off: int, kv_len, key_major: bool = False):
+    """The grid steps ONE head of a launch takes: an int32 ``[steps, 3]``
+    array of ``(qi, ki, kind)`` rows in visiting order — for each query
+    block its live key blocks ascending (forward, ``dq``), or with
+    ``key_major`` for each key block its live query blocks ascending
+    (``dk/dv``). The resident block's accumulator is opened on the first
+    step of its run of rows and flushed on the last, so every sum takes
+    the terms a rectangular grid would give it, in the same order.
+    ``kv_len`` is the true key length where the last key block is padded,
+    else ``None``. ``causal=False`` lists every tile once."""
+
+    def kind(qi, ki):
+        limit = qi * block_q + causal_off      # the first query's last key
+        if causal and ki * block_k > limit + block_q - 1:
+            return DEAD
+        if causal and ki * block_k + block_k - 1 > limit:
+            return DIAGONAL
+        if kv_len is not None and ki * block_k + block_k > kv_len:
+            return DIAGONAL
+        return INTERIOR
+
+    rows = []
+    for outer in range(nk if key_major else nq):
+        run = [(qi, ki, kind(qi, ki)) for qi, ki in (
+            (inner, outer) if key_major else (outer, inner)
+            for inner in range(nq if key_major else nk))]
+        rows += [t for t in run if t[2] != DEAD] or run[:1]
+    table = np.asarray(rows, np.int32)
+    table.setflags(write=False)
+    return table
+
+
+def _step(qi_ref, ki_ref, kind_ref, key_major: bool = False):
+    """This grid step's row of the table, and whether it is the first and
+    the last of its resident block's run (``qi``'s, or with ``key_major``
+    ``ki``'s): ``(qi, ki, kind, first, last)``, scalars."""
+    import jax.experimental.pallas as pl
+
+    step, last_step = pl.program_id(1), pl.num_programs(1) - 1
+    row_ref = ki_ref if key_major else qi_ref
+    row = row_ref[step]
+    first = (step == 0) | (row_ref[jnp.maximum(step - 1, 0)] != row)
+    last = (step == last_step) | (
+        row_ref[jnp.minimum(step + 1, last_step)] != row)
+    return qi_ref[step], ki_ref[step], kind_ref[step], first, last
+
+
+def _by_kind(kind, kinds, compute) -> None:
+    """Run ``compute(masked)`` as the step's kind says: bare on an
+    interior tile, masked on a diagonal one, not at all on a dead one.
+    ``kinds`` are the kinds the launch's table holds; one that holds a
+    single kind computes unconditionally."""
+    import jax.experimental.pallas as pl
+
+    if len(kinds) == 1:
+        if DEAD not in kinds:
+            compute(DIAGONAL in kinds)
+        return
+    for k in sorted(kinds - {DEAD}):
+        pl.when(kind == k)(functools.partial(compute, k == DIAGONAL))
+
+
+def _masked_scores(s, qi, ki, *, block_q, block_k, causal, causal_off,
+                   kv_len):
+    """A diagonal tile's scores with what the causal triangle and the
+    padded key tail exclude set to ``NEG_INF`` — the kernel-side mirror of
+    ``blockwise_attention``'s ``k_pos <= q_pos + off`` and ``k_pos <
+    sk``."""
+    k_pos = ki * block_k + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+    masked = None
+    if causal:
+        q_pos = qi * block_q + jax.lax.broadcasted_iota(
+            jnp.int32, s.shape, 0)
+        masked = k_pos > q_pos + causal_off
+    if kv_len is not None:
+        over = k_pos >= kv_len
+        masked = over if masked is None else (masked | over)
+    return s if masked is None else jnp.where(masked, NEG_INF, s)
+
+
+def _tile_call(kernel, table, heads: int, *, out_shape, in_specs, out_specs,
+               scratch_shapes):
+    """``pl.pallas_call`` of ``kernel`` over ``(heads, the table's
+    steps)``: the table's three columns go ahead of the operands by scalar
+    prefetch, to the kernel and to every index map, which take ``(head,
+    step, qi_ref, ki_ref, kind_ref)``. One-dimensional columns: SMEM pads
+    an array's last dim to 128 words, so ``[steps, 3]`` as it stands
+    would take 512 bytes a step. The steps the launch takes, by kind, ride
+    in the custom call as its kernel metadata
+    (``profiling.count_flash_grid_steps`` reads them from the compiled
+    program)."""
+    import jax.experimental.pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    kinds = table[:, 2]
+    counts = np.bincount(kinds, minlength=len(TILE_KINDS)) * heads
+    call = pl.pallas_call(
+        functools.partial(kernel, kinds=frozenset(kinds.tolist())),
+        out_shape=out_shape,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3, grid=(heads, len(table)),
+            in_specs=in_specs, out_specs=out_specs,
+            scratch_shapes=scratch_shapes),
+        metadata={name: str(n) for name, n in zip(TILE_KINDS, counts)},
+        **_interp_kw())
+    columns = [jnp.asarray(table[:, c]) for c in range(3)]
+    return lambda *operands: call(*columns, *operands)
+
+
 # ---------------------------------------------------------------- pallas fwd
 
-def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, *rest,
-                      block_k: int, causal: bool, block_q: int, nk: int,
-                      causal_off: int, sm_scale: float, kv_len):
+def _flash_fwd_kernel(qi_ref, ki_ref, kind_ref, q_ref, k_ref, v_ref, o_ref,
+                      *rest, kinds, sm_scale: float, **mask):
     import jax.experimental.pallas as pl
 
     # rest = (lse_ref?, o_scr, m_scr, l_scr): the lse output only exists
@@ -187,21 +319,15 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, *rest,
     lse_ref = rest[0] if len(rest) == 4 else None
     o_scr, m_scr, l_scr = rest[-3:]
 
-    qi = pl.program_id(1)
-    ki = pl.program_id(2)
+    qi, ki, kind, first, last = _step(qi_ref, ki_ref, kind_ref)
 
-    @pl.when(ki == 0)
+    @pl.when(first)
     def _init():
         o_scr[...] = jnp.zeros_like(o_scr)
         m_scr[...] = jnp.full_like(m_scr, NEG_INF)
         l_scr[...] = jnp.zeros_like(l_scr)
 
-    # causal: a key block strictly in the future contributes nothing
-    live = (ki * block_k <= qi * block_q + block_q - 1 + causal_off) \
-        if causal else True
-
-    @pl.when(live)
-    def _compute():
+    def _compute(masked: bool):
         # MXU matmuls stay in the input dtype (bf16 doubles throughput on
         # v5e); softmax state and the output accumulator are fp32 — the
         # standard flash mixed-precision split. preferred_element_type
@@ -214,22 +340,8 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, *rest,
         s = jax.lax.dot_general(                 # [block_q, block_k] fp32
             q, k_blk, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * sm_scale
-        masked = None
-        if causal:
-            q_pos = qi * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, s.shape, 0)
-            k_pos = ki * block_k + jax.lax.broadcasted_iota(
-                jnp.int32, s.shape, 1)
-            masked = k_pos > q_pos + causal_off
-        if kv_len is not None:
-            # ragged tail: padded key positions contribute nothing — the
-            # kernel-side mirror of blockwise_attention's `k_pos < sk`
-            k_pos = ki * block_k + jax.lax.broadcasted_iota(
-                jnp.int32, s.shape, 1)
-            over = k_pos >= kv_len
-            masked = over if masked is None else (masked | over)
-        if masked is not None:
-            s = jnp.where(masked, NEG_INF, s)
+        if masked:
+            s = _masked_scores(s, qi, ki, **mask)
         # softmax state stays 2-D ([block_q, 1] columns) end to end:
         # Mosaic works in sublane × lane tiles, and a column broadcasts
         # along the lanes as it is
@@ -244,7 +356,9 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, *rest,
         l_scr[...] = l_scr[...] * corr + p.sum(-1, keepdims=True)
         m_scr[...] = m_new
 
-    @pl.when(ki == nk - 1)
+    _by_kind(kind, kinds, _compute)
+
+    @pl.when(last)
     def _flush():
         l_fin = jnp.maximum(l_scr[...], 1e-37)
         o_ref[0] = (o_scr[...] / l_fin).astype(o_ref.dtype)
@@ -277,6 +391,16 @@ def _pad_blocks(q, k, v, block_q: int, block_k: int):
     return q, k, v, block_q, block_k, sq_p, sk_p, d_p
 
 
+def _q_block(i, step, qi_ref, ki_ref, kind_ref):
+    """Index map of a ``[heads, seq, d]`` operand's query block."""
+    return i, qi_ref[step], 0
+
+
+def _k_block(i, step, qi_ref, ki_ref, kind_ref):
+    """Index map of a ``[heads, seq, d]`` operand's key block."""
+    return i, ki_ref[step], 0
+
+
 def _flash_fwd(q, k, v, causal: bool, block_q: int, block_k: int,
                return_lse: bool = False):
     import jax.experimental.pallas as pl
@@ -290,32 +414,34 @@ def _flash_fwd(q, k, v, causal: bool, block_q: int, block_k: int,
     sm_scale = 1.0 / math.sqrt(d)
     q, k, v, block_q, block_k, sq_p, sk_p, d_p = _pad_blocks(
         q, k, v, block_q, block_k)
-    # fold (batch, heads) into the leading grid dim; k/v stream through VMEM
-    # one block per innermost grid step (pallas double-buffers the HBM loads),
-    # accumulators persist in VMEM scratch across the k dimension.
+    # fold (batch, heads) into the leading grid dim; the second is the
+    # head's live tiles in the table's order: k/v stream through VMEM one
+    # block per step (pallas double-buffers the HBM loads, and a block the
+    # next step names again is not loaded again), accumulators persist in
+    # VMEM scratch across a query block's steps.
     qt = q.transpose(0, 2, 1, 3).reshape(b * h, sq_p, d_p)
     kt = k.transpose(0, 2, 1, 3).reshape(b * h, sk_p, d_p)
     vt = v.transpose(0, 2, 1, 3).reshape(b * h, sk_p, d_p)
-    nk = sk_p // block_k
-    grid = (b * h, sq_p // block_q, nk)
+    kv_len = sk if sk_p != sk else None
+    table = tile_table(sq_p // block_q, sk_p // block_k, block_q, block_k,
+                       causal, causal_off, kv_len)
     out_shape = [jax.ShapeDtypeStruct((b * h, sq_p, d_p), q.dtype)]
-    out_specs = [pl.BlockSpec((1, block_q, d_p), lambda i, qi, ki: (i, qi, 0))]
+    out_specs = [pl.BlockSpec((1, block_q, d_p), _q_block)]
     if return_lse:
         out_shape.append(
             jax.ShapeDtypeStruct((b * h, sq_p, LANE), jnp.float32))
-        out_specs.append(pl.BlockSpec((1, block_q, LANE),
-                                      lambda i, qi, ki: (i, qi, 0)))
-    res = pl.pallas_call(
+        out_specs.append(pl.BlockSpec((1, block_q, LANE), _q_block))
+    res = _tile_call(
         functools.partial(_flash_fwd_kernel, block_k=block_k,
-                          causal=causal, block_q=block_q, nk=nk,
+                          causal=causal, block_q=block_q,
                           causal_off=causal_off, sm_scale=sm_scale,
-                          kv_len=sk if sk_p != sk else None),
+                          kv_len=kv_len),
+        table, b * h,
         out_shape=tuple(out_shape),
-        grid=grid,
         in_specs=[
-            pl.BlockSpec((1, block_q, d_p), lambda i, qi, ki: (i, qi, 0)),
-            pl.BlockSpec((1, block_k, d_p), lambda i, qi, ki: (i, ki, 0)),
-            pl.BlockSpec((1, block_k, d_p), lambda i, qi, ki: (i, ki, 0)),
+            pl.BlockSpec((1, block_q, d_p), _q_block),
+            pl.BlockSpec((1, block_k, d_p), _k_block),
+            pl.BlockSpec((1, block_k, d_p), _k_block),
         ],
         out_specs=tuple(out_specs),
         scratch_shapes=[
@@ -323,7 +449,6 @@ def _flash_fwd(q, k, v, causal: bool, block_q: int, block_k: int,
             pltpu.VMEM((block_q, 1), jnp.float32),
             pltpu.VMEM((block_q, 1), jnp.float32),
         ],
-        **_interp_kw(),
     )(qt, kt, vt)
     out, lse = res if return_lse else (res[0], None)
     out = out.reshape(b, h, sq_p, d_p).transpose(0, 2, 1, 3)
@@ -342,8 +467,8 @@ def _flash_fwd(q, k, v, causal: bool, block_q: int, block_k: int,
 # use Δ = rowsum(dO ⊙ O) for the softmax Jacobian. MXU matmuls run in the
 # input dtype with fp32 accumulation; accumulators live in VMEM scratch.
 
-def _bwd_block(q, k_blk, v_blk, do, lse, delta, qi, ki, *,
-               block_q, block_k, causal, causal_off, sm_scale, kv_len):
+def _bwd_block(q, k_blk, v_blk, do, lse, delta, qi, ki, masked: bool, *,
+               sm_scale, **mask):
     """Shared per-tile math: returns (p, ds) as fp32 [block_q, block_k].
     ``lse`` and ``delta`` are [block_q] rows; ``delta`` already has the
     cotangent of the row logsumexp subtracted (see ``_flash_bwd``).
@@ -352,20 +477,8 @@ def _bwd_block(q, k_blk, v_blk, do, lse, delta, qi, ki, *,
     s = jax.lax.dot_general(
         q, k_blk, (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32) * sm_scale
-    masked = None
-    if causal:
-        q_pos = qi * block_q + jax.lax.broadcasted_iota(
-            jnp.int32, s.shape, 0)
-        k_pos = ki * block_k + jax.lax.broadcasted_iota(
-            jnp.int32, s.shape, 1)
-        masked = k_pos > q_pos + causal_off
-    if kv_len is not None:
-        k_pos = ki * block_k + jax.lax.broadcasted_iota(
-            jnp.int32, s.shape, 1)
-        over = k_pos >= kv_len
-        masked = over if masked is None else (masked | over)
-    if masked is not None:
-        s = jnp.where(masked, NEG_INF, s)
+    if masked:
+        s = _masked_scores(s, qi, ki, **mask)
     p = jnp.exp(s - lse[:, None])                     # [bq, bk] fp32
     dp = jax.lax.dot_general(                         # dO · Vᵀ
         do, v_blk, (((1,), (1,)), ((), ())),
@@ -374,61 +487,49 @@ def _bwd_block(q, k_blk, v_blk, do, lse, delta, qi, ki, *,
     return p, ds
 
 
-def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                         dq_ref, dq_scr, *, block_q, block_k,
-                         nk, causal, causal_off, sm_scale, kv_len):
+def _flash_bwd_dq_kernel(qi_ref, ki_ref, kind_ref, q_ref, k_ref, v_ref,
+                         do_ref, lse_ref, delta_ref, dq_ref, dq_scr, *,
+                         kinds, **tile):
     import jax.experimental.pallas as pl
 
-    qi, ki = pl.program_id(1), pl.program_id(2)
+    qi, ki, kind, first, last = _step(qi_ref, ki_ref, kind_ref)
 
-    @pl.when(ki == 0)
+    @pl.when(first)
     def _init():
         dq_scr[...] = jnp.zeros_like(dq_scr)
 
-    live = (ki * block_k <= qi * block_q + block_q - 1 + causal_off) \
-        if causal else True
-
-    @pl.when(live)
-    def _compute():
+    def _compute(masked: bool):
         q, k_blk, v_blk = q_ref[0], k_ref[0], v_ref[0]
         _, ds = _bwd_block(q, k_blk, v_blk, do_ref[0], lse_ref[0, 0],
-                           delta_ref[0, 0], qi, ki,
-                           block_q=block_q, block_k=block_k, causal=causal,
-                           causal_off=causal_off, sm_scale=sm_scale,
-                           kv_len=kv_len)
+                           delta_ref[0, 0], qi, ki, masked, **tile)
         dq_scr[...] += jax.lax.dot_general(           # dS · K
             ds.astype(q.dtype), k_blk, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
 
-    @pl.when(ki == nk - 1)
+    _by_kind(kind, kinds, _compute)
+
+    @pl.when(last)
     def _flush():
         dq_ref[0] = dq_scr[...].astype(dq_ref.dtype)
 
 
-def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                          dk_ref, dv_ref, dk_scr, dv_scr, *,
-                          block_q, block_k, nq, causal, causal_off,
-                          sm_scale, kv_len):
+def _flash_bwd_dkv_kernel(qi_ref, ki_ref, kind_ref, q_ref, k_ref, v_ref,
+                          do_ref, lse_ref, delta_ref, dk_ref, dv_ref,
+                          dk_scr, dv_scr, *, kinds, **tile):
     import jax.experimental.pallas as pl
 
-    ki, qi = pl.program_id(1), pl.program_id(2)
+    qi, ki, kind, first, last = _step(qi_ref, ki_ref, kind_ref,
+                                      key_major=True)
 
-    @pl.when(qi == 0)
+    @pl.when(first)
     def _init():
         dk_scr[...] = jnp.zeros_like(dk_scr)
         dv_scr[...] = jnp.zeros_like(dv_scr)
 
-    live = (ki * block_k <= qi * block_q + block_q - 1 + causal_off) \
-        if causal else True
-
-    @pl.when(live)
-    def _compute():
+    def _compute(masked: bool):
         q, k_blk, v_blk, do = q_ref[0], k_ref[0], v_ref[0], do_ref[0]
         p, ds = _bwd_block(q, k_blk, v_blk, do, lse_ref[0, 0],
-                           delta_ref[0, 0], qi, ki, block_q=block_q,
-                           block_k=block_k, causal=causal,
-                           causal_off=causal_off, sm_scale=sm_scale,
-                           kv_len=kv_len)
+                           delta_ref[0, 0], qi, ki, masked, **tile)
         dv_scr[...] += jax.lax.dot_general(           # Pᵀ · dO
             p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
@@ -436,7 +537,9 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
 
-    @pl.when(qi == nq - 1)
+    _by_kind(kind, kinds, _compute)
+
+    @pl.when(last)
     def _flush():
         dk_ref[0] = dk_scr[...].astype(dk_ref.dtype)
         dv_ref[0] = dv_scr[...].astype(dv_ref.dtype)
@@ -477,36 +580,33 @@ def _flash_bwd(q, k, v, o, lse, g, causal: bool, block_q: int,
     # lane-dense [1, block_q] row (a [1, block_q] block of a 2-D array is
     # not a legal TPU tile)
     lse, delta = lse[:, None, :], delta[:, None, :]
-    nq, nk = sq_p // block_q, sk_p // block_k
-    common = dict(block_q=block_q, block_k=block_k, causal=causal,
-                  causal_off=causal_off, sm_scale=sm_scale,
-                  kv_len=sk if sk_p != sk else None)
-    q_spec = pl.BlockSpec((1, block_q, d_p), lambda i, qi, ki: (i, qi, 0))
-    k_spec = pl.BlockSpec((1, block_k, d_p), lambda i, qi, ki: (i, ki, 0))
-    r_spec = pl.BlockSpec((1, 1, block_q), lambda i, qi, ki: (i, 0, qi))
-    dq = pl.pallas_call(
-        functools.partial(_flash_bwd_dq_kernel, nk=nk, **common),
+    kv_len = sk if sk_p != sk else None
+    tile = dict(block_q=block_q, block_k=block_k, causal=causal,
+                causal_off=causal_off, sm_scale=sm_scale, kv_len=kv_len)
+    tiles = functools.partial(tile_table, sq_p // block_q, sk_p // block_k,
+                              block_q, block_k, causal, causal_off, kv_len)
+    q_spec = pl.BlockSpec((1, block_q, d_p), _q_block)
+    k_spec = pl.BlockSpec((1, block_k, d_p), _k_block)
+    r_spec = pl.BlockSpec(
+        (1, 1, block_q), lambda i, step, qi_ref, *_: (i, 0, qi_ref[step]))
+    in_specs = [q_spec, k_spec, k_spec, q_spec, r_spec, r_spec]
+    dq = _tile_call(
+        functools.partial(_flash_bwd_dq_kernel, **tile), tiles(), b * h,
         out_shape=jax.ShapeDtypeStruct((b * h, sq_p, d_p), q.dtype),
-        grid=(b * h, nq, nk),
-        in_specs=[q_spec, k_spec, k_spec, q_spec, r_spec, r_spec],
+        in_specs=in_specs,
         out_specs=q_spec,
         scratch_shapes=[pltpu.VMEM((block_q, d_p), jnp.float32)],
-        **_interp_kw(),
     )(qt, kt, vt, dot, lse, delta)
-    # dkv grid: key blocks resident, query blocks innermost
-    qk_spec = pl.BlockSpec((1, block_q, d_p), lambda i, ki, qi: (i, qi, 0))
-    kk_spec = pl.BlockSpec((1, block_k, d_p), lambda i, ki, qi: (i, ki, 0))
-    rk_spec = pl.BlockSpec((1, 1, block_q), lambda i, ki, qi: (i, 0, qi))
-    dk, dv = pl.pallas_call(
-        functools.partial(_flash_bwd_dkv_kernel, nq=nq, **common),
+    # dk/dv: key blocks resident, their live query blocks stream
+    dk, dv = _tile_call(
+        functools.partial(_flash_bwd_dkv_kernel, **tile),
+        tiles(key_major=True), b * h,
         out_shape=(jax.ShapeDtypeStruct((b * h, sk_p, d_p), k.dtype),
                    jax.ShapeDtypeStruct((b * h, sk_p, d_p), v.dtype)),
-        grid=(b * h, nk, nq),
-        in_specs=[qk_spec, kk_spec, kk_spec, qk_spec, rk_spec, rk_spec],
-        out_specs=(kk_spec, kk_spec),
+        in_specs=in_specs,
+        out_specs=(k_spec, k_spec),
         scratch_shapes=[pltpu.VMEM((block_k, d_p), jnp.float32),
                         pltpu.VMEM((block_k, d_p), jnp.float32)],
-        **_interp_kw(),
     )(qt, kt, vt, dot, lse, delta)
 
     def unfold(a, s):

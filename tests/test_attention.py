@@ -556,3 +556,157 @@ def test_blockwise_scores_are_fp32_off_the_matmul(orca_ctx):
     g = jax.grad(lambda q: blockwise_attention(
         q, q, q, causal=True, block_k=128).astype(jnp.float32).sum())(q)
     assert bool(jnp.all(jnp.isfinite(g.astype(jnp.float32))))
+
+
+# ------------------------------------------- the kernels' table of tiles
+
+def _tiles_by_position(nq, nk, bq, bk, causal, off, kv_len):
+    """``{(qi, ki): kind}`` told the slow way: from the mask of every
+    position of every tile."""
+    from analytics_zoo_tpu.ops import flash_attention as fa
+
+    kinds = {}
+    for qi in range(nq):
+        for ki in range(nk):
+            q_pos = qi * bq + np.arange(bq)[:, None]
+            k_pos = ki * bk + np.arange(bk)[None, :]
+            out = np.zeros((bq, bk), bool)
+            if causal:
+                out |= k_pos > q_pos + off
+            dead = out.all()
+            if kv_len is not None:
+                out = out | (k_pos >= kv_len)
+            kinds[qi, ki] = fa.DEAD if dead else (
+                fa.DIAGONAL if out.any() else fa.INTERIOR)
+    return kinds
+
+
+#: id → (sq, sk, block_q, block_k, causal); the lengths are padded to the
+#: blocks as ``_pad_blocks`` pads them
+TABLES = {
+    "8192_causal_at_512x512": (8192, 8192, 512, 512, True),
+    "8192_causal_at_128x128": (8192, 8192, 128, 128, True),
+    "not_causal": (384, 640, 128, 128, False),
+    "not_causal_ragged_keys": (256, 300, 128, 128, False),
+    "more_keys_than_queries": (256, 512, 128, 128, True),
+    "fewer_keys_than_queries": (320, 128, 128, 128, True),
+    "ragged_causal": (200, 200, 128, 128, True),
+    "unequal_blocks": (512, 512, 256, 128, True),
+}
+
+
+@pytest.mark.parametrize("case", list(TABLES))
+def test_tile_table_lists_live_tiles_in_the_rectangular_order(case):
+    """Pure Python. Each head's grid steps are the tiles that hold work,
+    once each, in the order a rectangular grid would reach them (forward
+    and ``dq``: for each query block its key blocks ascending; ``dk/dv``:
+    for each key block its query blocks ascending); a tile no mask touches
+    is interior; a resident block with no live tile keeps one dead
+    step."""
+    from analytics_zoo_tpu.ops import flash_attention as fa
+
+    sq, sk, bq, bk, causal = TABLES[case]
+    nq, nk = -(-sq // bq), -(-sk // bk)
+    off, kv_len = sk - sq, (sk if sk % bk else None)
+    want = _tiles_by_position(nq, nk, bq, bk, causal, off, kv_len)
+    for key_major in (False, True):
+        table = fa.tile_table(nq, nk, bq, bk, causal, off, kv_len,
+                              key_major=key_major)
+        assert table.dtype == np.int32 and table.shape[1] == 3
+        rows = [tuple(r) for r in table.tolist()]
+        live = [(qi, ki, kind) for (qi, ki), kind in want.items()
+                if kind != fa.DEAD]
+        order = (lambda r: (r[1], r[0])) if key_major else \
+            (lambda r: (r[0], r[1]))
+        assert [r for r in rows if r[2] != fa.DEAD] == sorted(live, key=order)
+        # a kept dead step stands where its block's run would have stood,
+        # and only for a block with no live tile
+        resident = 1 if key_major else 0
+        runs = [r[resident] for r in rows]
+        assert runs == sorted(runs)
+        assert set(runs) == set(range(nk if key_major else nq))
+        for r in rows:
+            if r[2] == fa.DEAD:
+                assert want[r[0], r[1]] == fa.DEAD
+                assert runs.count(r[resident]) == 1
+    per_head = np.bincount(fa.tile_table(nq, nk, bq, bk, causal, off,
+                                         kv_len)[:, 2], minlength=3).tolist()
+    assert per_head == {
+        "8192_causal_at_512x512": [120, 16, 0],
+        "8192_causal_at_128x128": [2016, 64, 0],
+        "not_causal": [15, 0, 0],
+        "not_causal_ragged_keys": [4, 2, 0],
+        "more_keys_than_queries": [5, 2, 0],
+        "fewer_keys_than_queries": [0, 2, 1],
+        "ragged_causal": [1, 2, 0],
+        "unequal_blocks": [2, 4, 0],
+    }[case]
+
+
+#: id → (sq, sk, block_q, block_k, causal, the kinds of step the case is
+#: there for)
+SCHEDULES = {
+    "causal_4x4_tiles": (512, 512, 128, 128, True, {0, 1}),
+    "more_keys_than_queries": (256, 512, 128, 128, True, {0, 1}),
+    "query_blocks_that_see_no_key": (320, 128, 128, 128, True, {1, 2}),
+    "last_key_block_masked_by_its_tail_alone": (
+        256, 300, 128, 128, False, {0, 1}),
+    "not_causal": (256, 384, 128, 128, False, {0}),
+    "unequal_blocks": (512, 512, 256, 128, True, {0, 1}),
+    "ragged_causal": (200, 200, 128, 128, True, {0, 1}),
+}
+
+
+@pytest.mark.parametrize("case", list(SCHEDULES))
+def test_flash_schedule_matches_blockwise_and_its_vjp(orca_ctx, monkeypatch,
+                                                      case):
+    """The real kernel bodies, interpreted, over the table of live tiles:
+    output, logsumexp, ``dq``, ``dk``, ``dv`` (the logsumexp's cotangent
+    included, through ``flash_attention_with_lse``) against
+    ``blockwise_attention`` and its vjp. Rows that see no key at all are
+    degenerate (every implementation's placeholder differs): they carry a
+    zero cotangent, their outputs must be written and finite, and nothing
+    else is asked of them."""
+    import jax
+    import jax.numpy as jnp
+    from analytics_zoo_tpu.ops import flash_attention as fa
+
+    monkeypatch.setenv("ZOO_PALLAS_INTERPRET", "1")
+    sq, sk, bq, bk, causal, kinds = SCHEDULES[case]
+    _, _, _, bq_p, bk_p, sq_p, sk_p, _ = fa._pad_blocks(
+        *(jnp.zeros((1, s, 1, 64)) for s in (sq, sk, sk)), bq, bk)
+    table = fa.tile_table(sq_p // bq_p, sk_p // bk_p, bq_p, bk_p, causal,
+                          sk - sq, sk if sk_p != sk else None)
+    assert set(table[:, 2].tolist()) == kinds
+    rng = np.random.default_rng(sq + sk + bq)
+    b, h, d = 1, 2, 64
+    q = jnp.asarray(rng.normal(size=(b, sq, h, d)), jnp.float32)
+    k, v = (jnp.asarray(rng.normal(size=(b, sk, h, d)), jnp.float32)
+            for _ in range(2))
+    blind = max(0, sq - sk) if causal else 0       # rows that see no key
+    g = rng.normal(size=(b, sq, h, d)).astype(np.float32)
+    g_lse = rng.normal(size=(b * h, sq)).astype(np.float32)
+    g[:, :blind], g_lse[:, :blind] = 0.0, 0.0
+
+    got, vjp = jax.vjp(lambda q, k, v: fa.flash_attention_with_lse(
+        q, k, v, causal, bq, bk), q, k, v)
+    got_grads = vjp((jnp.asarray(g), jnp.asarray(g_lse)))
+    want, ref_vjp = jax.vjp(lambda q, k, v: fa.blockwise_attention(
+        q, k, v, causal=causal, block_k=64, return_lse=True), q, k, v)
+    want_grads = ref_vjp((jnp.asarray(g), jnp.asarray(g_lse)))
+
+    for a in (*got, *got_grads):
+        assert a.shape[1] in (sq, sk) and bool(jnp.all(jnp.isfinite(a)))
+    tol = dict(rtol=1e-3, atol=1e-4)
+    np.testing.assert_allclose(got[0][:, blind:], want[0][:, blind:], **tol)
+    np.testing.assert_allclose(got[1][:, blind:], want[1][:, blind:], **tol)
+    np.testing.assert_allclose(got_grads[0][:, blind:],
+                               want_grads[0][:, blind:], **tol,
+                               err_msg="dq")
+    for name, a, w in zip(("dk", "dv"), got_grads[1:], want_grads[1:]):
+        np.testing.assert_allclose(a, w, **tol, err_msg=name)
+    if 2 in kinds:
+        # a query block the table keeps a dead step for: its rows are
+        # zeros, as the rectangular grid left them
+        assert not np.asarray(got[0][:, :bq_p]).any()
+        assert not np.asarray(got_grads[0][:, :bq_p]).any()

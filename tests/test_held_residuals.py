@@ -376,7 +376,7 @@ def test_v5e_launches_the_forward_kernel_once_where_its_residuals_are_kept(
         layer = jax.checkpoint(layer, policy={
             "products alone": hybrid_decoder._products_saveable,
             "the decoder's": hybrid_decoder._BLOCK_POLICY}[policy])
-    x = jax.ShapeDtypeStruct((1, 1024, 2, 64), jnp.bfloat16,
+    x = jax.ShapeDtypeStruct((1, 2048, 2, 64), jnp.bfloat16,
                              sharding=one_chip)
     w = jax.ShapeDtypeStruct((64, 64), jnp.bfloat16, sharding=one_chip)
     # the loss reads the layer's output, so the forward pass cannot be
@@ -389,3 +389,79 @@ def test_v5e_launches_the_forward_kernel_once_where_its_residuals_are_kept(
     assert profiling.count_kernel_calls(text) == {
         "flash_fwd": forward_launches, "flash_bwd_dq": 1,
         "flash_bwd_dkv": 1}
+    # each launch lists its live tiles alone: two heads of one interior
+    # and two diagonal tiles at 2,048 positions and 1,024 x 1,024 blocks
+    steps = profiling.count_flash_grid_steps(text)
+    assert steps == {
+        f"{kernel}/{kind}": n * (forward_launches
+                                 if kernel == "flash_fwd" else 1)
+        for kernel in profiling.KERNEL_FUNCTIONS
+        for kind, n in (("interior", 2), ("diagonal", 4), ("dead", 0))}
+
+
+@pytest.mark.parametrize("sq,sk,causal,want", [
+    (2048, 2048, True, (12, 8, 0)), (1024, 2048, True, (10, 4, 0)),
+    (2048, 1024, True, (2, 4, 4)), (1024, 1500, False, (8, 4, 0)),
+    (None, None, None, None)],
+    ids=["causal", "more_keys_than_queries", "query_blocks_that_see_no_key",
+         "not_causal_ragged_keys", "no_kernel"])
+def test_v5e_flash_launches_take_no_step_for_a_dead_tile(one_chip, sq, sk,
+                                                         causal, want):
+    """Forward and backward compiled for the chip at the decoder's head
+    size and 512 x 512 blocks: all three kernels build over the table of
+    live tiles (scalar prefetch, data-dependent index maps) and say how
+    many steps of each kind they take — none dead, but one for each query
+    block that sees no key. A step without the kernels has no such
+    count."""
+    from analytics_zoo_tpu.common import profiling
+    from analytics_zoo_tpu.ops.flash_attention import flash_attention
+
+    if want is None:
+        def layer(q, k):
+            return jnp.tanh(q) * k.sum()
+        sq = sk = 1024
+    else:
+        def layer(q, k):
+            return flash_attention(q, k, k, causal, 512, 512)
+    q = jax.ShapeDtypeStruct((1, sq, 2, 64), jnp.bfloat16, sharding=one_chip)
+    k = jax.ShapeDtypeStruct((1, sk, 2, 64), jnp.bfloat16, sharding=one_chip)
+    grad = jax.jit(jax.grad(
+        lambda q, k: (layer(q, k).astype(jnp.float32) ** 2).sum(),
+        argnums=(0, 1)))
+    with compiled_outside_the_cache():
+        text = grad.lower(q, k).compile().as_text()
+    launched = want is not None
+    assert profiling.count_kernel_calls(text) == dict.fromkeys(
+        profiling.KERNEL_FUNCTIONS, int(launched))
+    # a kept dead step belongs to a query block: the key blocks' kernel,
+    # every one of whose blocks some query sees, has none
+    assert profiling.count_flash_grid_steps(text) == ({
+        f"{kernel}/{kind}": n * (kernel != "flash_bwd_dkv" or kind != "dead")
+        for kernel in profiling.KERNEL_FUNCTIONS
+        for kind, n in zip(profiling.TILE_KINDS, want)} if launched else {})
+
+
+@pytest.mark.parametrize("head_dim,dtype", [
+    (64, jnp.bfloat16), (256, jnp.bfloat16), (512, jnp.bfloat16),
+    (128, jnp.float32), (512, jnp.float32)],
+    ids=["bf16_64", "bf16_256", "bf16_512", "fp32_128", "fp32_512"])
+def test_v5e_holds_the_untuned_blocks_at_every_head_row(one_chip, head_dim,
+                                                        dtype):
+    """The blocks a shape without a verdict runs at fit the kernels' VMEM,
+    forward and backward, from the decoder's 64-wide bfloat16 head to the
+    widest the kernels take: 1,024 x 1,024 while the head's row leaves
+    the float32 score tile its 4 MB, 512 x 512 beyond."""
+    from analytics_zoo_tpu.ops import autotune
+    from analytics_zoo_tpu.ops.flash_attention import flash_attention
+
+    blocks = autotune.untuned_blocks(head_dim, dtype)
+    row = max(head_dim, 128) * jnp.dtype(dtype).itemsize
+    assert blocks == ((1024, 1024) if row <= 512 else (512, 512))
+    q = jax.ShapeDtypeStruct((1, 2048, 1, head_dim), dtype,
+                             sharding=one_chip)
+    grad = jax.jit(jax.grad(
+        lambda q, k, v: (flash_attention(q, k, v, True, *blocks)
+                         .astype(jnp.float32) ** 2).sum(),
+        argnums=(0, 1, 2)))
+    with compiled_outside_the_cache():
+        grad.lower(q, q, q).compile()

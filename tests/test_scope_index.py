@@ -285,10 +285,60 @@ def test_kernel_calls_counted_and_published(fixture, want):
         text.replace('"body":"', '"body":"A')).values()) == 0
 
 
+LIVE_TILES_LAYER = os.path.join(
+    os.path.dirname(__file__), "fixtures",
+    "hlo_v5e_live_tiles_attention_layer_entry.txt")
+
+
+@pytest.mark.parametrize("fixture,want", [
+    (LIVE_TILES_LAYER, {f"{kernel}/{kind}": n
+                        for kernel in profiling.KERNEL_FUNCTIONS
+                        for kind, n in (("interior", 2), ("diagonal", 4),
+                                        ("dead", 0))}),
+    (REMAT_LAYER, {}), (PARENT_FUSIONS, {})],
+    ids=["launches_that_list_their_tiles", "launches_that_list_nothing",
+         "no_kernel"])
+def test_flash_grid_steps_counted_and_published(fixture, want):
+    """The same layer under the decoder's policy at ``[1, 1024, 2, 64]``
+    and 512 x 512 blocks, as the v5e compiler left it: each launch says in
+    its call's ``kernel_metadata`` how many grid steps of each kind it
+    listed (two heads of one interior and two diagonal tiles, no dead
+    step). A launch that says nothing (the kernels before they listed
+    their tiles) and a program without the kernels give no series."""
+    with open(fixture) as fh:
+        text = fh.read()
+    assert profiling.count_flash_grid_steps(text) == want
+    profiling.note_executable("canned", _CannedExe(text))
+    counts = profiling.step_counts("canned")
+    assert {k: n for k, n in counts.items() if "/" in k} == want
+    assert telemetry.snapshot().get("zoo_flash_grid_steps") == ({
+        "executable=canned,kernel={},kind={}".format(*k.split("/")): n
+        for k, n in want.items()} or None)
+
+
+def test_an_instruction_printed_over_several_lines_keeps_its_scope():
+    """XLA prints a call's non-empty ``kernel_metadata`` one key a line;
+    the three kernels are still counted once each and still belong to the
+    attention layer's scope, forward and backward."""
+    with open(LIVE_TILES_LAYER) as fh:
+        text = fh.read()
+    assert 'kernel_metadata={\n"dead":"0"' in text
+    assert profiling.count_kernel_calls(text) == {
+        "flash_fwd": 1, "flash_bwd_dq": 1, "flash_bwd_dkv": 1}
+    kernels = {name: (e["scope"], e["phase"])
+               for name, e in profiling.parse_scope_index(text).items()
+               if e["opcode"] == "custom-call"}
+    assert sorted(kernels.values()) == [
+        ("block_1/attention", "forward"),
+        ("checkpoint/block_1/attention", "backward"),
+        ("checkpoint/block_1/attention", "backward")]
+
+
 def test_the_counted_kernel_functions_are_flash_attentions():
     from analytics_zoo_tpu.ops import flash_attention
     for function in profiling.KERNEL_FUNCTIONS.values():
         assert callable(getattr(flash_attention, function.decode()))
+    assert profiling.TILE_KINDS == flash_attention.TILE_KINDS
 
 
 def test_fit_publishes_the_counts_where_metrics_are_scraped(orca_ctx):

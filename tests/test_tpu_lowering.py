@@ -176,5 +176,9 @@ def test_every_pallas_call_in_ops_is_covered():
                 n = len(re.findall(r"pl\.pallas_call\(", fh.read()))
             if n:
                 sites[name] = n
-    assert sites == {"embedding_bag.py": 2, "flash_attention.py": 3,
+    assert sites == {"embedding_bag.py": 2, "flash_attention.py": 1,
                      "paged_attention.py": 2}, sites
+    # flash attention's one site is ``_tile_call``, which the forward,
+    # ``dq`` and ``dk/dv`` launches go through
+    with open(fa.__file__) as fh:
+        assert len(re.findall(r"(?<!def )_tile_call\(", fh.read())) == 3
