@@ -112,16 +112,37 @@ def _token_nll_in_blocks(logits, idx):
     return out.reshape(idx.shape)
 
 
-def sparse_categorical_crossentropy_from_logits(y_true, y_pred):
+def _token_nll_of(y_true, logits):
+    """``_token_nll``, in blocks of positions where the float32 copy of
+    ``[batch, seq, vocab]`` logits would pass ``LOGITS_BLOCK_BYTES``."""
     idx = jnp.asarray(y_true).astype(jnp.int32)
-    y_pred = jnp.asarray(y_pred)
-    if y_pred.ndim > 2 and y_pred.size * 4 > LOGITS_BLOCK_BYTES:
-        out = _token_nll_in_blocks(y_pred, idx)
-    else:
-        out = _token_nll(y_pred, idx)
+    logits = jnp.asarray(logits)
+    if logits.ndim > 2 and logits.size * 4 > LOGITS_BLOCK_BYTES:
+        return _token_nll_in_blocks(logits, idx)
+    return _token_nll(logits, idx)
+
+
+def sparse_categorical_crossentropy_from_logits(y_true, y_pred):
+    out = _token_nll_of(y_true, y_pred)
     if out.ndim > 1:  # e.g. seq models: mean over time
         out = out.mean(axis=tuple(range(1, out.ndim)))
     return out
+
+
+def weighted_sparse_categorical_crossentropy_from_logits(y_true, y_pred):
+    """Token cross-entropy with a weight a position: ``y_pred`` is
+    ``(logits [batch, seq, vocab], weights [batch, seq])`` and the loss of
+    a row ``sum_i weights_i * nll_i / seq`` — block-diffusion training's
+    ``1[masked] / t`` (text/block_diffusion.py). A bare array of logits
+    (the same module outside training) weighs every position 1. The
+    float32 copy of large logits is taken in blocks, as the unweighted
+    loss takes it."""
+    logits, weights = y_pred if isinstance(y_pred, (tuple, list)) \
+        else (y_pred, None)
+    nll = _token_nll_of(y_true, logits)
+    if weights is not None:
+        nll = nll * _f32(weights)
+    return nll.mean(axis=tuple(range(1, nll.ndim)))
 
 
 def jax_logsumexp(x):
@@ -178,6 +199,8 @@ _REGISTRY = {
     "sparse_categorical_crossentropy": sparse_categorical_crossentropy,
     "sparse_categorical_crossentropy_logits":
         sparse_categorical_crossentropy_from_logits,
+    "weighted_sparse_categorical_crossentropy_logits":
+        weighted_sparse_categorical_crossentropy_from_logits,
     "hinge": hinge, "squared_hinge": squared_hinge,
     "kld": kullback_leibler_divergence,
     "poisson": poisson,

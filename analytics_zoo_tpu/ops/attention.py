@@ -36,12 +36,21 @@ def dot_product_attention(q, k, v, mask=None, causal: bool = False,
     the measurement says the kernel beats blockwise, the blockwise
     reference otherwise — so forcing flash can never be slower than the
     fallback as measured.
+
+    ``mask``: an array of allowed pairs, which always takes the dense
+    path, or a STATIC mask (``flash_attention.TileMask``, given in place
+    of ``causal``), which the kernels and the blockwise scan take as they
+    take ``causal`` and the dense path takes as the array it stands for.
     """
+    from analytics_zoo_tpu.ops.flash_attention import TileMask
+    static = mask if isinstance(mask, TileMask) else None
     if use_flash is None:
         use_flash = _flash_ok(q, k, mask)
-    if use_flash and mask is None:
+    if use_flash and (mask is None or static is not None):
         from analytics_zoo_tpu.ops.autotune import auto_flash_attention
-        return auto_flash_attention(q, k, v, causal=causal)
+        return auto_flash_attention(q, k, v, causal=causal, mask=static)
+    if static is not None:
+        mask = static.dense(q.shape[1], k.shape[1])
     return _reference_attention(q, k, v, mask=mask, causal=causal)
 
 
@@ -52,14 +61,18 @@ def _flash_ok(q, k, mask) -> bool:
     matrix would blow HBM (measured on v5e: XLA's fused attention is
     faster up to ~4k seq; beyond that the O(s²) buffer dominates). The
     kernels pad internally now, so neither ragged seq lengths nor
-    head_dim % 128 != 0 (the 64-dim BERT class) disqualify a shape."""
-    from analytics_zoo_tpu.ops.flash_attention import on_tpu
-    if mask is not None or not on_tpu():
+    head_dim % 128 != 0 (the 64-dim BERT class) disqualify a shape. An
+    ARRAY mask means the dense path (the kernels read no mask from
+    memory); a static one goes by the heuristic alone (no verdict is
+    kept for a masked shape)."""
+    from analytics_zoo_tpu.ops.flash_attention import TileMask, on_tpu
+    static = isinstance(mask, TileMask)
+    if (mask is not None and not static) or not on_tpu():
         return False
     b, sq, h, d = q.shape
     sk = k.shape[1]
     from analytics_zoo_tpu.ops import autotune
-    rec = autotune.get_tuner().lookup(
+    rec = None if static else autotune.get_tuner().lookup(
         autotune.attention_key(b, sq, sk, h, d, q.dtype, False),
         "flash_attention")
     if rec is not None:
@@ -180,13 +193,16 @@ class AttentionModule(nn.Module):
 
 # ------------------------------------ grouped-query heads, rotary positions
 
-def rotary_embedding(x, theta: float):
-    """Rotary positions 0..seq-1 on ``x`` [batch, seq, heads, head_dim]
-    with the halves rotated (``rotate_half``: element i pairs with
-    i + d/2), the angles in float32: ``x * cos + rotate_half(x) * sin``."""
+def rotary_embedding(x, theta: float, positions=None):
+    """Rotary positions on ``x`` [batch, seq, heads, head_dim] with the
+    halves rotated (``rotate_half``: element i pairs with i + d/2), the
+    angles in float32: ``x * cos + rotate_half(x) * sin``. ``positions``
+    [seq]: each row's position (default 0..seq-1; they may repeat)."""
     d = x.shape[-1]
     inv_freq = 1.0 / (theta ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d))
-    angles = jnp.arange(x.shape[1], dtype=jnp.float32)[:, None] \
+    if positions is None:
+        positions = jnp.arange(x.shape[1], dtype=jnp.float32)
+    angles = jnp.asarray(positions, jnp.float32)[:, None] \
         * inv_freq[None, :]
     cos = jnp.concatenate([jnp.cos(angles)] * 2, -1)[None, :, None, :]
     sin = jnp.concatenate([jnp.sin(angles)] * 2, -1)[None, :, None, :]
@@ -195,21 +211,23 @@ def rotary_embedding(x, theta: float):
     return (xf * cos + rotated * sin).astype(x.dtype)
 
 
-def grouped_query_attention(q, k, v):
-    """Causal attention of ``q`` [batch, seq, heads, d] over ``k``, ``v``
+def grouped_query_attention(q, k, v, mask=None):
+    """Attention of ``q`` [batch, seq, heads, d] over ``k``, ``v``
     [batch, seq, kv_heads, d] with ``heads`` a multiple of ``kv_heads``:
     each key-value head serves ``heads // kv_heads`` consecutive query
-    heads."""
+    heads. Causal, or under the static ``mask``."""
     groups = q.shape[2] // k.shape[2]
     if groups > 1:
         k, v = (jnp.repeat(t, groups, axis=2) for t in (k, v))
-    return dot_product_attention(q, k, v, causal=True)
+    return dot_product_attention(q, k, v, mask=mask, causal=mask is None)
 
 
 class GroupedQueryAttention(nn.Module):
-    """Causal self-attention with grouped-query heads, an RMSNorm over
-    each head of q and of k, rotary positions and no bias: projections
-    ``q``, ``k``, ``v``, ``out``; norms ``q_norm``, ``k_norm``."""
+    """Self-attention with grouped-query heads, an RMSNorm over each
+    head of q and of k, rotary positions and no bias: projections ``q``,
+    ``k``, ``v``, ``out``; norms ``q_norm``, ``k_norm``. Causal over
+    positions 0..seq-1, or under a static ``mask``
+    (``flash_attention.TileMask``) at the ``positions`` [seq] given."""
 
     num_heads: int
     num_kv_heads: int
@@ -220,7 +238,7 @@ class GroupedQueryAttention(nn.Module):
     kernel_init: nn.initializers.Initializer = nn.initializers.lecun_normal()
 
     @nn.compact
-    def __call__(self, x):
+    def __call__(self, x, positions=None, mask=None):
         b, s, hidden = x.shape
         h, g, d = self.num_heads, self.num_kv_heads, self.head_dim
 
@@ -234,9 +252,9 @@ class GroupedQueryAttention(nn.Module):
                        name="q_norm")(q)
         k = nn.RMSNorm(epsilon=self.norm_eps, dtype=self.dtype,
                        name="k_norm")(k)
-        q = rotary_embedding(q, self.rope_theta)
-        k = rotary_embedding(k, self.rope_theta)
-        out = grouped_query_attention(q, k, v)
+        q = rotary_embedding(q, self.rope_theta, positions)
+        k = rotary_embedding(k, self.rope_theta, positions)
+        out = grouped_query_attention(q, k, v, mask)
         return nn.Dense(hidden, use_bias=False, dtype=self.dtype,
                         kernel_init=self.kernel_init,
                         name="out")(out.reshape(b, s, h * d))

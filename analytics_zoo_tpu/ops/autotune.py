@@ -448,15 +448,19 @@ def untuned_blocks(head_dim: int, dtype) -> Tuple[int, int]:
     return UNTUNED_BLOCKS if row <= UNTUNED_HEAD_ROW else (512, 512)
 
 
-def auto_flash_attention(q, k, v, causal: bool = False):
+def auto_flash_attention(q, k, v, causal: bool = False, mask=None):
     """Verdict-driven attention dispatch: the tuned flash config when the
     measurement says it wins, the blockwise reference otherwise. This is
-    the path that can never lose to its own fallback."""
+    the path that can never lose to its own fallback. Under a static
+    ``mask`` (``flash_attention.TileMask``) no verdict is looked up or
+    asked for — the tuner times causal and full attention only — so such
+    a call takes the untuned kernels or the blockwise scan."""
     from analytics_zoo_tpu.ops.flash_attention import blockwise_attention
     b, s_q, h, d = q.shape
     s_k = k.shape[1]
     concrete = not isinstance(q, jax.core.Tracer)
-    rec = attention_decision(b, s_q, s_k, h, d, q.dtype, causal, concrete)
+    rec = None if mask is not None else attention_decision(
+        b, s_q, s_k, h, d, q.dtype, causal, concrete)
     if rec and rec.get("use_kernel") and rec.get("best"):
         from analytics_zoo_tpu.ops.flash_attention import flash_attention
         bq, bk = (int(t) for t in rec["best"].split("x"))
@@ -468,5 +472,5 @@ def auto_flash_attention(q, k, v, causal: bool = False):
         # kernels' backward is not
         from analytics_zoo_tpu.ops.flash_attention import flash_attention
         return flash_attention(q, k, v, causal,
-                               *untuned_blocks(d, q.dtype))
-    return blockwise_attention(q, k, v, causal=causal)
+                               *untuned_blocks(d, q.dtype), mask)
+    return blockwise_attention(q, k, v, causal=causal, mask=mask)

@@ -26,6 +26,13 @@ to −∞ inside the kernels (the same ``k_pos < kv_len`` guard
 ``blockwise_attention`` applies to its tail block). Padded query rows and
 head lanes are sliced off the outputs and gradients.
 
+What a launch leaves out is decided by a STATIC mask (``TileMask``: the
+causal one behind ``causal=True``, ``BlockDiffusionMask`` for
+block-diffusion training's noisy and clean copy of a sequence): each
+launch lists the tiles the mask leaves work in ahead of time
+(``tile_table``) and takes no grid step for the others; the scan takes
+the same object.
+
 ``ZOO_PALLAS_INTERPRET=1`` runs every kernel through the pallas
 interpreter, which works on CPU — the parity tests in
 tests/test_attention.py exercise the real kernel bodies without a TPU.
@@ -36,6 +43,7 @@ it beats this file's blockwise reference.
 
 from __future__ import annotations
 
+import dataclasses
 import functools
 import math
 import os
@@ -97,12 +105,14 @@ def _interp_kw() -> dict:
 # ---------------------------------------------------------------- blockwise
 
 def blockwise_attention(q, k, v, causal: bool = False, block_k: int = 128,
-                        return_lse: bool = False):
+                        return_lse: bool = False, mask=None):
     """q,k,v: [b, s, h, d] → [b, s, h, d]; O(s·block_k) memory.
     ``return_lse``: also return the per-row logsumexp as [b·h, s] fp32
-    (the layout the pallas kernels use)."""
+    (the layout the pallas kernels use). ``mask``: a static ``TileMask``
+    in place of ``causal`` (``static_mask``)."""
     b, sq, h, d = q.shape
     sk = k.shape[1]
+    mask = static_mask(causal, mask, sq, sk)
     block_k = min(block_k, sk)
     nk = (sk + block_k - 1) // block_k
     pad = nk * block_k - sk
@@ -110,10 +120,10 @@ def blockwise_attention(q, k, v, causal: bool = False, block_k: int = 128,
         k = jnp.pad(k, ((0, 0), (0, pad), (0, 0), (0, 0)))
         v = jnp.pad(v, ((0, 0), (0, pad), (0, 0), (0, 0)))
     scale = 1.0 / math.sqrt(d)
+    # the causal mask is bottom-right aligned (query i sees keys <= i + sk
+    # - sq), matching _reference_attention's tril(k=sk-sq) KV-cache-decode
+    # semantics
     q_pos = jnp.arange(sq)
-    # bottom-right-aligned causal mask (query i sees keys <= i + sk - sq),
-    # matching _reference_attention's tril(k=sk-sq) KV-cache-decode semantics
-    causal_off = sk - sq
 
     def body(carry, kb):
         o, m, l = carry
@@ -127,8 +137,9 @@ def blockwise_attention(q, k, v, causal: bool = False, block_k: int = 128,
                        preferred_element_type=jnp.float32) * scale
         k_pos = kb_idx * block_k + jnp.arange(block_k)
         valid = k_pos < sk
-        if causal:
-            valid = valid[None, :] & (k_pos[None, :] <= q_pos[:, None] + causal_off)
+        if mask is not None:
+            valid = valid[None, :] & ~mask.excluded(q_pos[:, None],
+                                                    k_pos[None, :])
             s = jnp.where(valid[None, None, :, :], s, NEG_INF)
         else:
             s = jnp.where(valid[None, None, None, :], s, NEG_INF)
@@ -184,11 +195,11 @@ def _pad_axis(a, axis: int, to: int):
 
 #: every score of the tile is wanted: computed with no mask at all
 INTERIOR = 0
-#: the causal diagonal or the padded key tail crosses the tile: computed
-#: under the mask
+#: the mask's edge or the padded key tail crosses the tile: computed under
+#: the mask
 DIAGONAL = 1
-#: the causal triangle holds nothing of the tile: no grid step — but for a
-#: resident block with no live tile at all (``sk < sq``), which keeps one
+#: the mask allows nothing of the tile: no grid step — but for a resident
+#: block with no live tile at all (causal, ``sk < sq``), which keeps one
 #: step that computes nothing, so that its zeros are still written
 DEAD = 2
 #: the kinds by name, in the order of their codes (the labels of
@@ -196,9 +207,150 @@ DEAD = 2
 TILE_KINDS = ("interior", "diagonal", "dead")
 
 
+class TileMask:
+    """A static attention mask: which (query, key) pairs are EXCLUDED, as
+    a function of their positions alone, known at trace time. It gives
+    the tile table a tile's kind and the kernels the predicate of a
+    DIAGONAL tile; hashable, so that it rides as a static argument."""
+
+    #: what ``excluded`` is handed inside a kernel: the tile's row
+    #: positions as a ``[block_q, 1]`` column and its key positions as a
+    #: ``[1, block_k]`` row (whatever depends on one of them alone is then
+    #: computed on a vector, not on the tile), or both as whole-tile iotas
+    tile_iotas = False
+
+    def excluded(self, q_pos, k_pos):
+        """Boolean, broadcast over ``q_pos`` and ``k_pos`` (integer
+        arrays, numpy or jax): the pairs the mask takes out."""
+        raise NotImplementedError
+
+    def representatives(self, start: int, stop: int):
+        """Positions of ``[start, stop)`` among which every other one
+        has a twin that the mask treats alike (all of them, unless a
+        subclass knows better)."""
+        return np.arange(start, stop)
+
+    def kind(self, q0: int, q1: int, k0: int, k1: int) -> int:
+        """The kind of the tile of queries ``[q0, q1)`` and keys
+        ``[k0, k1)``, classified in numpy."""
+        out = self.excluded(self.representatives(q0, q1)[:, None],
+                            self.representatives(k0, k1)[None, :])
+        return DEAD if out.all() else DIAGONAL if out.any() else INTERIOR
+
+    def dense(self, sq: int, sk: int):
+        """The ALLOWED pairs as a boolean ``[sq, sk]`` array (the dense
+        path's ``mask`` argument)."""
+        return ~self.excluded(jnp.arange(sq)[:, None],
+                              jnp.arange(sk)[None, :])
+
+    def check_lengths(self, sq: int, sk: int) -> None:
+        """Raise if the mask is not one over ``sq`` queries and ``sk``
+        keys (a mask defined for every pair of lengths: nothing)."""
+
+
+@dataclasses.dataclass(frozen=True)
+class CausalMask(TileMask):
+    """Query ``i`` sees keys ``<= i + offset`` (``offset = sk - sq``:
+    bottom-right aligned)."""
+
+    offset: int = 0
+
+    # the causal kernels' predicate on whole-tile iotas, as it was before
+    # masks were objects: a causal launch keeps its instructions
+    tile_iotas = True
+
+    def excluded(self, q_pos, k_pos):
+        return k_pos > q_pos + self.offset
+
+    def kind(self, q0, q1, k0, k1):
+        limit = q0 + self.offset               # the first query's last key
+        if k0 > limit + (q1 - q0) - 1:
+            return DEAD
+        return DIAGONAL if k1 - 1 > limit else INTERIOR
+
+
+def _where(c, a, b):
+    return (np if isinstance(c, np.ndarray) else jnp).where(c, a, b)
+
+
+@dataclasses.dataclass(frozen=True)
+class BlockDiffusionMask(TileMask):
+    """The three-region mask of block-diffusion training (BD3-LM,
+    arXiv:2503.09573) over ``2 * seq_len`` rows, a NOISY copy of a
+    sequence followed by its CLEAN copy, in blocks of ``block`` positions
+    (``text/block_diffusion.py``):
+
+    - a noisy row of block ``b`` sees the noisy keys of block ``b`` and
+      the clean keys of blocks ``< b``;
+    - a clean row of block ``b`` sees the clean keys of blocks ``<= b``
+      and no noisy key.
+
+    ``noisy=False`` is the clean half alone over ``seq_len`` rows:
+    attention both ways inside a block, causal across blocks."""
+
+    seq_len: int
+    block: int
+    noisy: bool = True
+
+    def __post_init__(self):
+        if self.seq_len % self.block:
+            raise ValueError(f"seq_len {self.seq_len} is not a multiple of "
+                             f"block {self.block}")
+
+    @property
+    def rows(self) -> int:
+        """The sequence length the mask is over."""
+        return self.seq_len * (2 if self.noisy else 1)
+
+    def check_lengths(self, sq, sk):
+        if not sq == sk == self.rows:
+            raise ValueError(f"{self} is over {self.rows} rows; got {sq} "
+                             f"queries and {sk} keys")
+
+    def _block_of(self, pos):
+        shift = self.block.bit_length() - 1
+        return pos >> shift if self.block == 1 << shift \
+            else pos // self.block
+
+    def excluded(self, q_pos, k_pos):
+        # a key's code: its block if clean, ``far`` + its block if noisy.
+        # A clean row of block b sees the codes <= b; a noisy one the
+        # codes <= b - 1 and the one code far + b: two compares on the
+        # tile, everything else on the row and the column alone
+        first_clean = self.seq_len if self.noisy else 0
+        far = 1 << 24
+        q_noisy = q_pos < first_clean
+        q_block = self._block_of(_where(q_noisy, q_pos, q_pos - first_clean))
+        below = _where(q_noisy, q_block - 1, q_block)
+        own = _where(q_noisy, q_block + far, -1)
+        code = _where(k_pos < first_clean, self._block_of(k_pos) + far,
+                      self._block_of(k_pos - first_clean))
+        return (code > below) & (code != own)
+
+    def representatives(self, start, stop):
+        # the mask reads a position's half and block alone, and both
+        # change at multiples of ``block`` only
+        inner = np.arange(-(-start // self.block) * self.block, stop,
+                          self.block)
+        return np.unique(np.concatenate([[start], inner])).astype(np.int64)
+
+
+def static_mask(causal: bool, mask, sq: int, sk: int):
+    """The ``TileMask`` of a call: ``mask`` if one is given (it must be
+    over the call's lengths), the bottom-right-aligned causal one for
+    ``causal``, else ``None``: every pair allowed."""
+    if mask is None:
+        return CausalMask(sk - sq) if causal else None
+    if causal or not isinstance(mask, TileMask):
+        raise ValueError("a static mask is a TileMask, given in place of "
+                         "causal")
+    mask.check_lengths(sq, sk)
+    return mask
+
+
 @functools.lru_cache(maxsize=None)
-def tile_table(nq: int, nk: int, block_q: int, block_k: int, causal: bool,
-               causal_off: int, kv_len, key_major: bool = False):
+def tile_table(nq: int, nk: int, block_q: int, block_k: int, mask, kv_len,
+               key_major: bool = False):
     """The grid steps ONE head of a launch takes: an int32 ``[steps, 3]``
     array of ``(qi, ki, kind)`` rows in visiting order — for each query
     block its live key blocks ascending (forward, ``dq``), or with
@@ -206,18 +358,18 @@ def tile_table(nq: int, nk: int, block_q: int, block_k: int, causal: bool,
     (``dk/dv``). The resident block's accumulator is opened on the first
     step of its run of rows and flushed on the last, so every sum takes
     the terms a rectangular grid would give it, in the same order.
-    ``kv_len`` is the true key length where the last key block is padded,
-    else ``None``. ``causal=False`` lists every tile once."""
+    ``mask`` is the launch's ``TileMask`` or ``None``, which lists every
+    tile once. ``kv_len`` is the true key length where the last key block
+    is padded, else ``None``."""
 
     def kind(qi, ki):
-        limit = qi * block_q + causal_off      # the first query's last key
-        if causal and ki * block_k > limit + block_q - 1:
-            return DEAD
-        if causal and ki * block_k + block_k - 1 > limit:
+        k = INTERIOR if mask is None else mask.kind(
+            qi * block_q, (qi + 1) * block_q, ki * block_k,
+            (ki + 1) * block_k)
+        if k == INTERIOR and kv_len is not None \
+                and ki * block_k + block_k > kv_len:
             return DIAGONAL
-        if kv_len is not None and ki * block_k + block_k > kv_len:
-            return DIAGONAL
-        return INTERIOR
+        return k
 
     rows = []
     for outer in range(nk if key_major else nq):
@@ -260,18 +412,20 @@ def _by_kind(kind, kinds, compute) -> None:
         pl.when(kind == k)(functools.partial(compute, k == DIAGONAL))
 
 
-def _masked_scores(s, qi, ki, *, block_q, block_k, causal, causal_off,
-                   kv_len):
-    """A diagonal tile's scores with what the causal triangle and the
-    padded key tail exclude set to ``NEG_INF`` — the kernel-side mirror of
-    ``blockwise_attention``'s ``k_pos <= q_pos + off`` and ``k_pos <
-    sk``."""
-    k_pos = ki * block_k + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+def _masked_scores(s, qi, ki, *, block_q, block_k, mask, kv_len):
+    """A diagonal tile's scores with what the mask and the padded key
+    tail exclude set to ``NEG_INF`` — the kernel-side mirror of
+    ``blockwise_attention``'s ``mask.excluded`` and ``k_pos < sk``."""
+    iota = functools.partial(jax.lax.broadcasted_iota, jnp.int32)
+    k_pos = ki * block_k + iota(s.shape, 1)
     masked = None
-    if causal:
-        q_pos = qi * block_q + jax.lax.broadcasted_iota(
-            jnp.int32, s.shape, 0)
-        masked = k_pos > q_pos + causal_off
+    if mask is not None and mask.tile_iotas:
+        q_pos = qi * block_q + iota(s.shape, 0)
+        masked = mask.excluded(q_pos, k_pos)
+    elif mask is not None:
+        masked = mask.excluded(
+            qi * block_q + iota((s.shape[0], 1), 0),
+            ki * block_k + iota((1, s.shape[1]), 1))
     if kv_len is not None:
         over = k_pos >= kv_len
         masked = over if masked is None else (masked | over)
@@ -310,7 +464,7 @@ def _tile_call(kernel, table, heads: int, *, out_shape, in_specs, out_specs,
 # ---------------------------------------------------------------- pallas fwd
 
 def _flash_fwd_kernel(qi_ref, ki_ref, kind_ref, q_ref, k_ref, v_ref, o_ref,
-                      *rest, kinds, sm_scale: float, **mask):
+                      *rest, kinds, sm_scale: float, **tile):
     import jax.experimental.pallas as pl
 
     # rest = (lse_ref?, o_scr, m_scr, l_scr): the lse output only exists
@@ -341,7 +495,7 @@ def _flash_fwd_kernel(qi_ref, ki_ref, kind_ref, q_ref, k_ref, v_ref, o_ref,
             q, k_blk, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * sm_scale
         if masked:
-            s = _masked_scores(s, qi, ki, **mask)
+            s = _masked_scores(s, qi, ki, **tile)
         # softmax state stays 2-D ([block_q, 1] columns) end to end:
         # Mosaic works in sublane × lane tiles, and a column broadcasts
         # along the lanes as it is
@@ -402,15 +556,16 @@ def _k_block(i, step, qi_ref, ki_ref, kind_ref):
 
 
 def _flash_fwd(q, k, v, causal: bool, block_q: int, block_k: int,
-               return_lse: bool = False):
+               return_lse: bool = False, mask=None):
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     b, sq, h, d = q.shape
     sk = k.shape[1]
-    # the causal offset is defined by the ORIGINAL lengths (bottom-right
-    # aligned mask, see blockwise_attention); padding must not shift it
-    causal_off = sk - sq
+    # the mask is defined by the ORIGINAL lengths (the causal one is
+    # bottom-right aligned, see blockwise_attention); padding must not
+    # shift it
+    mask = static_mask(causal, mask, sq, sk)
     sm_scale = 1.0 / math.sqrt(d)
     q, k, v, block_q, block_k, sq_p, sk_p, d_p = _pad_blocks(
         q, k, v, block_q, block_k)
@@ -424,7 +579,7 @@ def _flash_fwd(q, k, v, causal: bool, block_q: int, block_k: int,
     vt = v.transpose(0, 2, 1, 3).reshape(b * h, sk_p, d_p)
     kv_len = sk if sk_p != sk else None
     table = tile_table(sq_p // block_q, sk_p // block_k, block_q, block_k,
-                       causal, causal_off, kv_len)
+                       mask, kv_len)
     out_shape = [jax.ShapeDtypeStruct((b * h, sq_p, d_p), q.dtype)]
     out_specs = [pl.BlockSpec((1, block_q, d_p), _q_block)]
     if return_lse:
@@ -433,8 +588,7 @@ def _flash_fwd(q, k, v, causal: bool, block_q: int, block_k: int,
         out_specs.append(pl.BlockSpec((1, block_q, LANE), _q_block))
     res = _tile_call(
         functools.partial(_flash_fwd_kernel, block_k=block_k,
-                          causal=causal, block_q=block_q,
-                          causal_off=causal_off, sm_scale=sm_scale,
+                          block_q=block_q, mask=mask, sm_scale=sm_scale,
                           kv_len=kv_len),
         table, b * h,
         out_shape=tuple(out_shape),
@@ -468,7 +622,7 @@ def _flash_fwd(q, k, v, causal: bool, block_q: int, block_k: int,
 # input dtype with fp32 accumulation; accumulators live in VMEM scratch.
 
 def _bwd_block(q, k_blk, v_blk, do, lse, delta, qi, ki, masked: bool, *,
-               sm_scale, **mask):
+               sm_scale, **tile):
     """Shared per-tile math: returns (p, ds) as fp32 [block_q, block_k].
     ``lse`` and ``delta`` are [block_q] rows; ``delta`` already has the
     cotangent of the row logsumexp subtracted (see ``_flash_bwd``).
@@ -478,7 +632,7 @@ def _bwd_block(q, k_blk, v_blk, do, lse, delta, qi, ki, masked: bool, *,
         q, k_blk, (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32) * sm_scale
     if masked:
-        s = _masked_scores(s, qi, ki, **mask)
+        s = _masked_scores(s, qi, ki, **tile)
     p = jnp.exp(s - lse[:, None])                     # [bq, bk] fp32
     dp = jax.lax.dot_general(                         # dO · Vᵀ
         do, v_blk, (((1,), (1,)), ((), ())),
@@ -546,13 +700,13 @@ def _flash_bwd_dkv_kernel(qi_ref, ki_ref, kind_ref, q_ref, k_ref, v_ref,
 
 
 def _flash_bwd(q, k, v, o, lse, g, causal: bool, block_q: int,
-               block_k: int, g_lse=None):
+               block_k: int, g_lse=None, mask=None):
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     b, sq, h, d = q.shape
     sk = k.shape[1]
-    causal_off = sk - sq
+    mask = static_mask(causal, mask, sq, sk)
     sm_scale = 1.0 / math.sqrt(d)
     q, k, v, block_q, block_k, sq_p, sk_p, d_p = _pad_blocks(
         q, k, v, block_q, block_k)
@@ -581,10 +735,10 @@ def _flash_bwd(q, k, v, o, lse, g, causal: bool, block_q: int,
     # not a legal TPU tile)
     lse, delta = lse[:, None, :], delta[:, None, :]
     kv_len = sk if sk_p != sk else None
-    tile = dict(block_q=block_q, block_k=block_k, causal=causal,
-                causal_off=causal_off, sm_scale=sm_scale, kv_len=kv_len)
+    tile = dict(block_q=block_q, block_k=block_k, mask=mask,
+                sm_scale=sm_scale, kv_len=kv_len)
     tiles = functools.partial(tile_table, sq_p // block_q, sk_p // block_k,
-                              block_q, block_k, causal, causal_off, kv_len)
+                              block_q, block_k, mask, kv_len)
     q_spec = pl.BlockSpec((1, block_q, d_p), _q_block)
     k_spec = pl.BlockSpec((1, block_k, d_p), _k_block)
     r_spec = pl.BlockSpec(
@@ -617,60 +771,65 @@ def _flash_bwd(q, k, v, o, lse, g, causal: bool, block_q: int,
             unfold(dv, sk_p)[:, :sk, :, :d])
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
 def flash_attention(q, k, v, causal: bool = False, block_q: int = 128,
-                    block_k: int = 128):
+                    block_k: int = 128, mask=None):
     """Pallas forward + pallas FlashAttention-2 backward (dq and dk/dv
     kernels over the saved logsumexp). Ragged seq lengths and unaligned
     head dims are padded internally (module docstring); callers wanting
     the measured-fastest block config should go through
-    ``ops.autotune.auto_flash_attention`` instead of picking blocks."""
-    return _flash_fwd(q, k, v, causal, block_q, block_k)
+    ``ops.autotune.auto_flash_attention`` instead of picking blocks.
+    ``mask``: a static ``TileMask`` in place of ``causal``; its dead
+    tiles are no grid step in any of the three kernels."""
+    return _flash_fwd(q, k, v, causal, block_q, block_k, mask=mask)
 
 
-def _named_fwd(q, k, v, causal, block_q, block_k):
+def _named_fwd(q, k, v, causal, block_q, block_k, mask):
     """The forward kernel's two results as both ``custom_vjp`` rules hand
     them to the backward, under ``RESIDUAL_NAMES``."""
     out, lse = _flash_fwd(q, k, v, causal, block_q, block_k,
-                          return_lse=True)
+                          return_lse=True, mask=mask)
     return (checkpoint_name(out, RESIDUAL_NAMES[0]),
             checkpoint_name(lse, RESIDUAL_NAMES[1]))
 
 
-def _fa_fwd(q, k, v, causal, block_q, block_k):
-    out, lse = _named_fwd(q, k, v, causal, block_q, block_k)
+def _fa_fwd(q, k, v, causal, block_q, block_k, mask):
+    out, lse = _named_fwd(q, k, v, causal, block_q, block_k, mask)
     return out, (q, k, v, out, lse)
 
 
-def _fa_bwd(causal, block_q, block_k, res, g):
+def _fa_bwd(causal, block_q, block_k, mask, res, g):
     q, k, v, o, lse = res
-    return _flash_bwd(q, k, v, o, lse, g, causal, block_q, block_k)
+    return _flash_bwd(q, k, v, o, lse, g, causal, block_q, block_k,
+                      mask=mask)
 
 
 flash_attention.defvjp(_fa_fwd, _fa_bwd)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
 def flash_attention_with_lse(q, k, v, causal: bool = False,
-                             block_q: int = 128, block_k: int = 128):
+                             block_q: int = 128, block_k: int = 128,
+                             mask=None):
     """Like ``flash_attention`` but also returns the per-row logsumexp
     ([b·h, s] fp32). Differentiable in BOTH outputs — the lse cotangent
     folds into the backward kernels' softmax-Jacobian term — which is
     what ring attention needs to merge per-ring-step partial softmaxes
     (ops/ring_attention.py use_flash path)."""
-    return _flash_fwd(q, k, v, causal, block_q, block_k, return_lse=True)
+    return _flash_fwd(q, k, v, causal, block_q, block_k, return_lse=True,
+                      mask=mask)
 
 
-def _fal_fwd(q, k, v, causal, block_q, block_k):
-    out, lse = _named_fwd(q, k, v, causal, block_q, block_k)
+def _fal_fwd(q, k, v, causal, block_q, block_k, mask):
+    out, lse = _named_fwd(q, k, v, causal, block_q, block_k, mask)
     return (out, lse), (q, k, v, out, lse)
 
 
-def _fal_bwd(causal, block_q, block_k, res, g):
+def _fal_bwd(causal, block_q, block_k, mask, res, g):
     q, k, v, o, lse = res
     g_out, g_lse = g
     return _flash_bwd(q, k, v, o, lse, g_out, causal, block_q, block_k,
-                      g_lse=g_lse)
+                      g_lse=g_lse, mask=mask)
 
 
 flash_attention_with_lse.defvjp(_fal_fwd, _fal_bwd)

@@ -144,6 +144,24 @@ def sigmoid_top_k_routing(logits, bias, k: int, normalize: bool = True,
     return ids.astype(jnp.int32), weights * scale
 
 
+def softmax_top_k_routing(logits, k: int, normalize: bool = True,
+                          scale: float = 1.0):
+    """``logits`` [N, E] -> (expert ids [N, k] int32, weights [N, k] f32).
+    ``p = softmax(logits)`` over all ``E`` in float32; the ``k`` experts
+    with the largest ``p`` are selected; the weights are ``p`` at the
+    selected, divided by their sum when ``normalize``, times ``scale``."""
+    probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
+    weights, ids = jax.lax.top_k(probs, k)
+    if normalize:
+        weights = weights / jnp.sum(weights, -1, keepdims=True)
+    return ids.astype(jnp.int32), weights * scale
+
+
+#: the ways a ``Router`` scores: sigmoid scores with a selection-only
+#: ``expert_bias`` leaf, or a softmax over all experts and no such leaf
+ROUTER_SCORINGS = ("sigmoid_bias", "softmax")
+
+
 @jax.custom_vjp
 def _take_rows(x, tok, rows, inside):
     """``x[tok]``: the window's rows gathered from the tokens. ``rows``
@@ -295,22 +313,33 @@ class HeldExperts(nn.Module):
 
 
 class Router(nn.Module):
-    """Scores over ALL experts in float32, and the selection."""
+    """Scores over ALL experts in float32, and the selection, by
+    ``scoring`` (``ROUTER_SCORINGS``): ``"sigmoid_bias"``
+    (``sigmoid_top_k_routing``, with an ``expert_bias`` leaf) or
+    ``"softmax"`` (``softmax_top_k_routing``, the kernel alone)."""
 
     n_experts: int
     k: int
     normalize: bool = True
     scale: float = 1.0
     kernel_init: nn.initializers.Initializer = nn.initializers.lecun_normal()
+    scoring: str = "sigmoid_bias"
 
     @nn.compact
     def __call__(self, x):
+        if self.scoring not in ROUTER_SCORINGS:
+            raise ValueError(f"scoring {self.scoring!r} is not one of "
+                             f"{ROUTER_SCORINGS}")
         kernel = self.param("kernel", self.kernel_init,
                             (x.shape[-1], self.n_experts), jnp.float32)
-        bias = self.param("expert_bias", nn.initializers.zeros,
-                          (self.n_experts,), jnp.float32)
+        if self.scoring == "sigmoid_bias":
+            bias = self.param("expert_bias", nn.initializers.zeros,
+                              (self.n_experts,), jnp.float32)
         logits = jnp.dot(x.astype(jnp.float32), kernel,
                          precision=jax.lax.Precision.HIGHEST)
+        if self.scoring == "softmax":
+            return softmax_top_k_routing(logits, self.k, self.normalize,
+                                         self.scale)
         return sigmoid_top_k_routing(logits, bias, self.k, self.normalize,
                                      self.scale)
 
@@ -343,9 +372,10 @@ def sow_last(module: nn.Module, name: str, value) -> None:
 
 
 class DroplessMoE(nn.Module):
-    """Sparse feed-forward block that drops no token: sigmoid scores over
-    ``n_experts`` with a selection-only ``expert_bias``, ``k`` experts a
-    token, gated (SwiGLU) experts of width ``d_hidden``.
+    """Sparse feed-forward block that drops no token: a ``Router`` over
+    ``n_experts`` (``scoring``: sigmoid scores with a selection-only
+    ``expert_bias``, or a softmax and no bias), ``k`` experts a token,
+    gated (SwiGLU) experts of width ``d_hidden``.
 
     ``held``: the ids of the experts THIS layer holds (default: all, the
     published layer). It routes over all ``n_experts``, computes
@@ -371,6 +401,7 @@ class DroplessMoE(nn.Module):
     scale: float = 1.0
     dtype: Optional[object] = None
     kernel_init: nn.initializers.Initializer = nn.initializers.lecun_normal()
+    scoring: str = "sigmoid_bias"
 
     @nn.compact
     def __call__(self, x):
@@ -378,7 +409,7 @@ class DroplessMoE(nn.Module):
             else tuple(self.held)
         tokens = x.reshape(-1, x.shape[-1])
         ids, weights = Router(self.n_experts, self.k, self.normalize,
-                              self.scale, self.kernel_init,
+                              self.scale, self.kernel_init, self.scoring,
                               name="router")(tokens)
         out, counts, window = HeldExperts(
             held, self.n_experts, self.d_hidden, self.dtype,

@@ -3,16 +3,21 @@ per-layer list: a gated short convolution (``ops/short_conv.py``) or
 grouped-query attention with rotary positions (``ops/attention.py``), each
 followed by a gated (SwiGLU) feed-forward block that is dense in the first
 ``num_dense_layers`` layers and a dropless mixture of experts
-(``ops/moe.DroplessMoE``) in the rest. Pre-norm with RMSNorm, tied
-embeddings, no learned positions, no bias, no dropout:
+(``ops/moe.DroplessMoE``) in the rest. Pre-norm with RMSNorm, no learned
+positions, no bias, no dropout; the head is the embedding transposed
+(``tie_embeddings``, the default) or a matrix of its own:
 
     x = embed[ids]
     h = x + op(RMSNorm(x));  x = h + ffn(RMSNorm(h))      per layer
-    logits = RMSNorm(x) @ embed.T
+    logits = RMSNorm(x) @ embed.T          or  RMSNorm(x) @ lm_head
 
-The stack is the hybrid family of ``transformers``' ``lfm2_moe``; a layer
-is told which experts it holds (``held_experts``), so that one chip of
-several that share each layer runs its share through the same module.
+The stack is the hybrid family of ``transformers``' ``lfm2_moe`` and,
+with attention layers only, a softmax router and an untied head, that of
+``qwen3_moe`` / ``sdar_moe``; a layer is told which experts it holds
+(``held_experts``), so that one chip of several that share each layer
+runs its share through the same module. The attention layers are causal
+over positions 0..seq-1 unless the call hands down a static ``mask`` and
+``positions`` (``text/block_diffusion.py`` does).
 """
 
 from __future__ import annotations
@@ -54,9 +59,17 @@ class HybridDecoderConfig:
     initializer_range: float = 0.02
     # computation dtype (parameters stay float32)
     dtype: Optional[object] = None
+    # a head's width where it is not hidden_size / num_heads
+    head_size: Optional[int] = None
+    # ops/moe.ROUTER_SCORINGS
+    router_scoring: str = "sigmoid_bias"
+    # the head is the embedding transposed; else a matrix ``lm_head``
+    tie_embeddings: bool = True
 
     @property
     def head_dim(self) -> int:
+        if self.head_size is not None:
+            return self.head_size
         assert self.hidden_size % self.num_heads == 0
         return self.hidden_size // self.num_heads
 
@@ -99,9 +112,11 @@ class DecoderBlock(nn.Module):
     config: HybridDecoderConfig
     layer_type: str
     sparse: bool
+    # the attention layer's static mask; None: causal
+    mask: Optional[object] = None
 
     @nn.compact
-    def __call__(self, x):
+    def __call__(self, x, positions=None):
         cfg = self.config
         init = nn.initializers.normal(cfg.initializer_range)
 
@@ -117,7 +132,7 @@ class DecoderBlock(nn.Module):
             y = GroupedQueryAttention(
                 cfg.num_heads, cfg.num_kv_heads, cfg.head_dim,
                 cfg.rope_theta, cfg.norm_eps, dtype=cfg.dtype,
-                kernel_init=init, name="attention")(y)
+                kernel_init=init, name="attention")(y, positions, self.mask)
         else:
             raise ValueError(f"layer type {self.layer_type!r} is not one "
                              f"of {LAYER_TYPES}")
@@ -128,7 +143,8 @@ class DecoderBlock(nn.Module):
                 cfg.num_experts, cfg.num_experts_per_tok,
                 cfg.moe_intermediate_size, cfg.held_experts,
                 cfg.norm_topk_prob, cfg.routed_scaling_factor,
-                dtype=cfg.dtype, kernel_init=init, name="moe")(y)
+                dtype=cfg.dtype, kernel_init=init,
+                scoring=cfg.router_scoring, name="moe")(y)
         else:
             y = GatedMLP(cfg.intermediate_size, cfg.dtype, init,
                          name="mlp")(y)
@@ -136,12 +152,17 @@ class DecoderBlock(nn.Module):
 
 
 class HybridDecoder(nn.Module):
-    """``ids`` [batch, seq] -> logits [batch, seq, vocab]."""
+    """``ids`` [batch, seq] -> logits [batch, seq, vocab]. ``positions``
+    [seq] and ``mask`` (a static ``flash_attention.TileMask``) go to
+    every attention layer in place of 0..seq-1 and the causal mask;
+    ``head_rows`` ``(start, stop)``: the rows of the sequence the head is
+    applied to (logits [batch, stop - start, vocab])."""
 
     config: HybridDecoderConfig
 
     @nn.compact
-    def __call__(self, input_ids, train: bool = False):
+    def __call__(self, input_ids, train: bool = False, positions=None,
+                 mask=None, head_rows=None):
         cfg = self.config
         ids = jnp.asarray(input_ids).astype(jnp.int32)
         embed = nn.Embed(cfg.vocab, cfg.hidden_size, dtype=cfg.dtype,
@@ -150,11 +171,17 @@ class HybridDecoder(nn.Module):
         x = embed(ids)
         block_cls = nn.remat(DecoderBlock, policy=_BLOCK_POLICY)
         for i, layer_type in enumerate(cfg.layer_types):
-            x = block_cls(cfg, layer_type, i >= cfg.num_dense_layers,
-                          name=f"block_{i}")(x)
+            x = block_cls(cfg, layer_type, i >= cfg.num_dense_layers, mask,
+                          name=f"block_{i}")(x, positions)
+        if head_rows is not None:
+            x = x[:, head_rows[0]:head_rows[1]]
         x = nn.RMSNorm(epsilon=cfg.norm_eps, dtype=cfg.dtype,
                        name="out_norm")(x)
+        # in the compute dtype: the loss takes its float32 copy a block
+        # of positions at a time (learn/losses.py)
+        if not cfg.tie_embeddings:
+            return nn.Dense(cfg.vocab, use_bias=False, dtype=cfg.dtype,
+                            kernel_init=nn.initializers.normal(
+                                cfg.initializer_range), name="lm_head")(x)
         with jax.named_scope("lm_head"):
-            # in the compute dtype: the loss takes its float32 copy a
-            # block of positions at a time (learn/losses.py)
             return embed.attend(x)

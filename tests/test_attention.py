@@ -610,8 +610,9 @@ def test_tile_table_lists_live_tiles_in_the_rectangular_order(case):
     off, kv_len = sk - sq, (sk if sk % bk else None)
     want = _tiles_by_position(nq, nk, bq, bk, causal, off, kv_len)
     for key_major in (False, True):
-        table = fa.tile_table(nq, nk, bq, bk, causal, off, kv_len,
-                              key_major=key_major)
+        table = fa.tile_table(nq, nk, bq, bk, fa.static_mask(causal, None,
+                                                             sq, sk),
+                              kv_len, key_major=key_major)
         assert table.dtype == np.int32 and table.shape[1] == 3
         rows = [tuple(r) for r in table.tolist()]
         live = [(qi, ki, kind) for (qi, ki), kind in want.items()
@@ -629,8 +630,9 @@ def test_tile_table_lists_live_tiles_in_the_rectangular_order(case):
             if r[2] == fa.DEAD:
                 assert want[r[0], r[1]] == fa.DEAD
                 assert runs.count(r[resident]) == 1
-    per_head = np.bincount(fa.tile_table(nq, nk, bq, bk, causal, off,
-                                         kv_len)[:, 2], minlength=3).tolist()
+    per_head = np.bincount(
+        fa.tile_table(nq, nk, bq, bk, fa.static_mask(causal, None, sq, sk),
+                      kv_len)[:, 2], minlength=3).tolist()
     assert per_head == {
         "8192_causal_at_512x512": [120, 16, 0],
         "8192_causal_at_128x128": [2016, 64, 0],
@@ -675,8 +677,9 @@ def test_flash_schedule_matches_blockwise_and_its_vjp(orca_ctx, monkeypatch,
     sq, sk, bq, bk, causal, kinds = SCHEDULES[case]
     _, _, _, bq_p, bk_p, sq_p, sk_p, _ = fa._pad_blocks(
         *(jnp.zeros((1, s, 1, 64)) for s in (sq, sk, sk)), bq, bk)
-    table = fa.tile_table(sq_p // bq_p, sk_p // bk_p, bq_p, bk_p, causal,
-                          sk - sq, sk if sk_p != sk else None)
+    table = fa.tile_table(sq_p // bq_p, sk_p // bk_p, bq_p, bk_p,
+                          fa.static_mask(causal, None, sq, sk),
+                          sk if sk_p != sk else None)
     assert set(table[:, 2].tolist()) == kinds
     rng = np.random.default_rng(sq + sk + bq)
     b, h, d = 1, 2, 64

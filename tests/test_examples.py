@@ -19,7 +19,7 @@ ALL = ["recommendation_ncf.py", "anomaly_detection.py",
        "image_similarity.py", "wide_and_deep.py", "object_detection.py",
        "image_augmentation.py", "model_inference.py",
        "automl_hp_search.py", "qa_ranker.py", "multihost_launch.py",
-       "image_classification_serving.py"]
+       "image_classification_serving.py", "block_diffusion_training.py"]
 
 # the heavyweight end-to-end examples (multi-process launches, real
 # training loops: 10-25s each on 1 core) run in the examples lane only

@@ -441,6 +441,38 @@ def test_v5e_flash_launches_take_no_step_for_a_dead_tile(one_chip, sq, sk,
         for kind, n in zip(profiling.TILE_KINDS, want)} if launched else {})
 
 
+def test_v5e_compiles_the_three_kernels_under_the_block_diffusion_mask(
+        one_chip):
+    """Forward and backward under ``BlockDiffusionMask`` compiled for the
+    chip at the block-diffusion cell's head (128 wide, bfloat16) and its
+    untuned 1,024 x 1,024 blocks, at 2 x 2,048 rows: the mask's predicate
+    on a row and a column of positions builds in Mosaic, and each launch
+    says it takes, a head, 2 + 3 + 3 = 8 live tiles of 16, 6 of them
+    masked, none dead."""
+    from analytics_zoo_tpu.common import profiling
+    from analytics_zoo_tpu.ops import autotune
+    from analytics_zoo_tpu.ops.flash_attention import (BlockDiffusionMask,
+                                                       flash_attention)
+
+    blocks = autotune.untuned_blocks(128, jnp.bfloat16)
+    assert blocks == (1024, 1024)
+    mask = BlockDiffusionMask(2048, 4)
+    q = jax.ShapeDtypeStruct((1, 4096, 4, 128), jnp.bfloat16,
+                             sharding=one_chip)
+    grad = jax.jit(jax.grad(
+        lambda q, k, v: (flash_attention(q, k, v, False, *blocks, mask)
+                         .astype(jnp.float32) ** 2).sum(),
+        argnums=(0, 1, 2)))
+    with compiled_outside_the_cache():
+        text = grad.lower(q, q, q).compile().as_text()
+    assert profiling.count_kernel_calls(text) == dict.fromkeys(
+        profiling.KERNEL_FUNCTIONS, 1)
+    assert profiling.count_flash_grid_steps(text) == {
+        f"{kernel}/{kind}": n * 4
+        for kernel in profiling.KERNEL_FUNCTIONS
+        for kind, n in (("interior", 2), ("diagonal", 6), ("dead", 0))}
+
+
 @pytest.mark.parametrize("head_dim,dtype", [
     (64, jnp.bfloat16), (256, jnp.bfloat16), (512, jnp.bfloat16),
     (128, jnp.float32), (512, jnp.float32)],

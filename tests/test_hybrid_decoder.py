@@ -398,10 +398,11 @@ def test_an_untuned_long_sequence_takes_the_kernel_not_the_scan(monkeypatch):
     monkeypatch.setattr(autotune, "attention_decision", lambda *a: None)
     monkeypatch.setattr(
         flash_attention, "flash_attention",
-        lambda q, k, v, causal, bq, bk: calls.append((causal, bq, bk)) or q)
+        lambda q, k, v, causal, bq, bk, mask: calls.append(
+            (causal, bq, bk)) or q)
     monkeypatch.setattr(
         flash_attention, "blockwise_attention",
-        lambda q, k, v, causal=False: calls.append("scan") or q)
+        lambda q, k, v, causal=False, mask=None: calls.append("scan") or q)
     long = jnp.zeros((1, 32768, 1, 8), jnp.bfloat16)
     autotune.auto_flash_attention(long, long, long, causal=True)
     assert calls == [(True,) + autotune.UNTUNED_BLOCKS]
