@@ -67,7 +67,7 @@ __all__ = [
     "backend_state", "DUMP_DIR", "reset_for_tests",
     "note_executable", "scope_index", "parse_scope_index",
     "step_counts", "count_elementwise_evals", "count_kernel_calls",
-    "count_flash_grid_steps",
+    "count_flash_grid_steps", "count_flash_layouts",
 ]
 
 logger = logging.getLogger(__name__)
@@ -364,7 +364,8 @@ def note_executable(name: str, exe, fn=None, sig=None,
         flops = float(cost.get("flops", 0.0)) or None
         counts = {**count_elementwise_evals(text),
                   **count_kernel_calls(text),
-                  **count_flash_grid_steps(text)}
+                  **count_flash_grid_steps(text),
+                  **count_flash_layouts(text)}
         if lowered is not None:
             counts["held_values"] = lowered.as_text().count(_BARRIER)
     except Exception:
@@ -482,6 +483,27 @@ def count_flash_grid_steps(hlo_text: str) -> Dict[str, int]:
     return counts
 
 
+def count_flash_layouts(hlo_text: str) -> Dict[str, int]:
+    """``{"<kernel>@<layout>,<kv>": n}``: the launches of each flash
+    attention kernel in the optimized HLO of a TPU executable by how they
+    find a head's blocks, as each launch wrote it into its call's
+    metadata — ``layout`` ``rows`` (operands ``[batch, seq, heads·d]``
+    as the projections write them, the head named by the index maps) or
+    ``heads`` (lane-padded head-major copies); ``kv`` ``grouped`` (a
+    key/value head read by the query heads of its group) or ``own`` (as
+    many key/value heads as query heads; ``flash_attention._Operands``).
+    A launch that says neither (a
+    program compiled before the keys were written) is not counted; an
+    executable without the kernels gives ``{}``."""
+    counts: Dict[str, int] = {}
+    for kernel, metadata in _flash_launches(hlo_text):
+        layout, kv = metadata.get("layout"), metadata.get("kv")
+        if layout and kv:
+            key = f"{kernel}@{layout},{kv}"
+            counts[key] = counts.get(key, 0) + 1
+    return counts
+
+
 def _publish_counts(name: str, counts: Dict[str, int]) -> None:
     reg = telemetry.get_registry()
     if "held_values" in counts:
@@ -509,6 +531,19 @@ def _publish_counts(name: str, counts: Dict[str, int]) -> None:
                 ("executable", "kernel")).labels(name, kernel).set(
                     counts[kernel])
     for key in counts:
+        kernel, _, how = key.partition("@")
+        if how:
+            layout, _, kv = how.partition(",")
+            reg.gauge(
+                "zoo_flash_launches", "Launches of a flash attention "
+                "kernel in the compiled program by how they find a head's "
+                "blocks: layout rows (operands [batch, seq, heads*d], the "
+                "head named by the index maps) or heads (lane-padded "
+                "head-major copies); kv grouped (a key/value head read by "
+                "its group's query heads) or own",
+                ("executable", "kernel", "layout", "kv")).labels(
+                    name, kernel, layout, kv).set(counts[key])
+            continue
         kernel, _, kind = key.partition("/")
         if kind:
             reg.gauge(
@@ -523,15 +558,17 @@ def _publish_counts(name: str, counts: Dict[str, int]) -> None:
 def step_counts(name: str) -> Optional[Dict[str, int]]:
     """``{"held_values", "erfc", "mask", "flash_fwd", "flash_bwd_dq",
     "flash_bwd_dkv"}`` of the executable last compiled ahead of time under
-    ``name``, and ``"<kernel>/<kind>"`` for each flash kernel it launches:
-    the optimization barriers of the program as lowered (left out where
-    the lowered program was not at hand), :func:`count_elementwise_evals`,
-    :func:`count_kernel_calls` and :func:`count_flash_grid_steps` of its
+    ``name``, and ``"<kernel>/<kind>"`` and ``"<kernel>@<layout>,<kv>"``
+    for each flash kernel it launches: the optimization barriers of the
+    program as lowered (left out where the lowered program was not at
+    hand), :func:`count_elementwise_evals`, :func:`count_kernel_calls`,
+    :func:`count_flash_grid_steps` and :func:`count_flash_layouts` of its
     optimized HLO; ``None`` when nothing was compiled under that name. The
     same numbers are the gauges ``zoo_step_held_values{executable}``,
     ``zoo_step_elementwise_evals{executable,kind}``,
-    ``zoo_step_kernel_calls{executable,kernel}`` and
-    ``zoo_flash_grid_steps{executable,kernel,kind}``."""
+    ``zoo_step_kernel_calls{executable,kernel}``,
+    ``zoo_flash_grid_steps{executable,kernel,kind}`` and
+    ``zoo_flash_launches{executable,kernel,layout,kv}``."""
     with _executables_lock:
         rec = _executables.get(name)
     return dict(rec.counts) if rec is not None else None

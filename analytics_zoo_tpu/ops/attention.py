@@ -22,12 +22,16 @@ import jax
 import jax.numpy as jnp
 from flax.linen.dtypes import promote_dtype
 
-from analytics_zoo_tpu.ops.hold import Dropout
+from analytics_zoo_tpu.ops.hold import Dropout, hold_both_ways
 
 
 def dot_product_attention(q, k, v, mask=None, causal: bool = False,
                           use_flash: Optional[bool] = None):
-    """q,k,v: [batch, seq, heads, head_dim] → [batch, seq, heads, head_dim].
+    """q,k,v: [batch, seq, heads, head_dim] → [batch, seq, heads, head_dim];
+    ``k`` and ``v`` may come at fewer heads, each serving the
+    ``heads // kv_heads`` consecutive query heads of its group: the
+    kernels read them so, and the dense path and the blockwise scan get
+    them repeated (``flash_attention.repeat_kv_heads``).
 
     ``use_flash=None`` auto-selects the pallas path on TPU: a persisted
     autotuner verdict for the shape wins outright; without one, the HBM
@@ -42,7 +46,8 @@ def dot_product_attention(q, k, v, mask=None, causal: bool = False,
     of ``causal``), which the kernels and the blockwise scan take as they
     take ``causal`` and the dense path takes as the array it stands for.
     """
-    from analytics_zoo_tpu.ops.flash_attention import TileMask
+    from analytics_zoo_tpu.ops.flash_attention import (TileMask,
+                                                       repeat_kv_heads)
     static = mask if isinstance(mask, TileMask) else None
     if use_flash is None:
         use_flash = _flash_ok(q, k, mask)
@@ -51,7 +56,8 @@ def dot_product_attention(q, k, v, mask=None, causal: bool = False,
         return auto_flash_attention(q, k, v, causal=causal, mask=static)
     if static is not None:
         mask = static.dense(q.shape[1], k.shape[1])
-    return _reference_attention(q, k, v, mask=mask, causal=causal)
+    return _reference_attention(q, *repeat_kv_heads(q, k, v), mask=mask,
+                                causal=causal)
 
 
 def _flash_ok(q, k, mask) -> bool:
@@ -215,10 +221,8 @@ def grouped_query_attention(q, k, v, mask=None):
     """Attention of ``q`` [batch, seq, heads, d] over ``k``, ``v``
     [batch, seq, kv_heads, d] with ``heads`` a multiple of ``kv_heads``:
     each key-value head serves ``heads // kv_heads`` consecutive query
-    heads. Causal, or under the static ``mask``."""
-    groups = q.shape[2] // k.shape[2]
-    if groups > 1:
-        k, v = (jnp.repeat(t, groups, axis=2) for t in (k, v))
+    heads. Causal, or under the static ``mask``. On the kernel path k and
+    v go as they are: the kernels name a key/value head by its group."""
     return dot_product_attention(q, k, v, mask=mask, causal=mask is None)
 
 
@@ -254,6 +258,10 @@ class GroupedQueryAttention(nn.Module):
                        name="k_norm")(k)
         q = rotary_embedding(q, self.rope_theta, positions)
         k = rotary_embedding(k, self.rope_theta, positions)
+        # q and k come out of the norms and rotations in (heads, d) tiles
+        # and the kernels read [batch, seq, heads·d] rows; v and the
+        # kernels' output meet products on both sides and cross nothing
+        q, k = hold_both_ways(q), hold_both_ways(k)
         out = grouped_query_attention(q, k, v, mask)
         return nn.Dense(hidden, use_bias=False, dtype=self.dtype,
                         kernel_init=self.kernel_init,

@@ -454,8 +454,11 @@ def auto_flash_attention(q, k, v, causal: bool = False, mask=None):
     the path that can never lose to its own fallback. Under a static
     ``mask`` (``flash_attention.TileMask``) no verdict is looked up or
     asked for — the tuner times causal and full attention only — so such
-    a call takes the untuned kernels or the blockwise scan."""
-    from analytics_zoo_tpu.ops.flash_attention import blockwise_attention
+    a call takes the untuned kernels or the blockwise scan. ``k`` and
+    ``v`` may come at fewer heads than ``q`` (grouped-query attention):
+    the kernels take them so, the scan repeated."""
+    from analytics_zoo_tpu.ops.flash_attention import (blockwise_attention,
+                                                       repeat_kv_heads)
     b, s_q, h, d = q.shape
     s_k = k.shape[1]
     concrete = not isinstance(q, jax.core.Tracer)
@@ -473,4 +476,5 @@ def auto_flash_attention(q, k, v, causal: bool = False, mask=None):
         from analytics_zoo_tpu.ops.flash_attention import flash_attention
         return flash_attention(q, k, v, causal,
                                *untuned_blocks(d, q.dtype), mask)
-    return blockwise_attention(q, k, v, causal=causal, mask=mask)
+    return blockwise_attention(q, *repeat_kv_heads(q, k, v), causal=causal,
+                               mask=mask)

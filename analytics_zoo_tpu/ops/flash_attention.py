@@ -26,6 +26,20 @@ to −∞ inside the kernels (the same ``k_pos < kv_len`` guard
 ``blockwise_attention`` applies to its tail block). Padded query rows and
 head lanes are sliced off the outputs and gradients.
 
+A head's block is found by the launches' INDEX MAPS, not by copies
+(``_Operands``). At a head of whole lanes (``head_dim % 128 == 0``) the
+operands stay ``[batch, seq, heads·head_dim]`` as the projections write
+them and a ``(1, block, head_dim)`` block at ``(batch, block index,
+head)`` is the head's: no transpose before or after a launch. At any other
+width such a block is no legal TPU block, and the operands are the
+lane-padded head-major copies ``[batch·heads, seq, 128·k]``. In both, k
+and v may come at ``kv_heads`` dividing q's heads (grouped-query
+attention): the forward and ``dq`` launches name a key/value block by
+``head // groups``, and the ``dk/dv`` launch keeps a key block resident
+over ALL query heads of its group, so dk and dv come out at ``kv_heads``,
+summed over the group in the float32 accumulators. k and v are never
+repeated.
+
 What a launch leaves out is decided by a STATIC mask (``TileMask``: the
 causal one behind ``causal=True``, ``BlockDiffusionMask`` for
 block-diffusion training's noisy and clean copy of a sequence): each
@@ -103,6 +117,17 @@ def _interp_kw() -> dict:
 
 
 # ---------------------------------------------------------------- blockwise
+
+def repeat_kv_heads(q, k, v):
+    """``k`` and ``v`` [b, s, kv_heads, d] at ``q``'s heads, each
+    key/value head repeated for the consecutive query heads it serves:
+    what the dense path and the blockwise scan take. The kernels read a
+    key/value head by its group and are never given this copy."""
+    groups = q.shape[2] // k.shape[2]
+    if groups == 1:
+        return k, v
+    return jnp.repeat(k, groups, axis=2), jnp.repeat(v, groups, axis=2)
+
 
 def blockwise_attention(q, k, v, causal: bool = False, block_k: int = 128,
                         return_lse: bool = False, mask=None):
@@ -350,7 +375,7 @@ def static_mask(causal: bool, mask, sq: int, sk: int):
 
 @functools.lru_cache(maxsize=None)
 def tile_table(nq: int, nk: int, block_q: int, block_k: int, mask, kv_len,
-               key_major: bool = False):
+               key_major: bool = False, groups: int = 1):
     """The grid steps ONE head of a launch takes: an int32 ``[steps, 3]``
     array of ``(qi, ki, kind)`` rows in visiting order — for each query
     block its live key blocks ascending (forward, ``dq``), or with
@@ -360,7 +385,17 @@ def tile_table(nq: int, nk: int, block_q: int, block_k: int, mask, kv_len,
     the terms a rectangular grid would give it, in the same order.
     ``mask`` is the launch's ``TileMask`` or ``None``, which lists every
     tile once. ``kv_len`` is the true key length where the last key block
-    is padded, else ``None``."""
+    is padded, else ``None``.
+
+    ``groups`` (``key_major`` only): the query heads ONE key/value head
+    serves. Beyond one the table is that key/value head's, ``[steps, 4]``
+    rows of ``(qi, ki, kind, head of the group)``: a key block's run
+    takes the group's heads in turn, each over the block's live query
+    blocks ascending, so the block stays resident — and its ``dk``,
+    ``dv`` accumulate — over every query head that read it."""
+    if groups > 1 and not key_major:
+        raise ValueError("a group's heads share a run of the key-major "
+                         "table only")
 
     def kind(qi, ki):
         k = INTERIOR if mask is None else mask.kind(
@@ -376,7 +411,12 @@ def tile_table(nq: int, nk: int, block_q: int, block_k: int, mask, kv_len,
         run = [(qi, ki, kind(qi, ki)) for qi, ki in (
             (inner, outer) if key_major else (outer, inner)
             for inner in range(nq if key_major else nk))]
-        rows += [t for t in run if t[2] != DEAD] or run[:1]
+        live = [t for t in run if t[2] != DEAD]
+        if groups == 1:
+            rows += live or run[:1]
+        else:               # the kept dead step writes zeros: one does
+            rows += [t + (head,) for head in range(groups) for t in live] \
+                or [run[0] + (0,)]
     table = np.asarray(rows, np.int32)
     table.setflags(write=False)
     return table
@@ -432,17 +472,19 @@ def _masked_scores(s, qi, ki, *, block_q, block_k, mask, kv_len):
     return s if masked is None else jnp.where(masked, NEG_INF, s)
 
 
-def _tile_call(kernel, table, heads: int, *, out_shape, in_specs, out_specs,
-               scratch_shapes):
+def _tile_call(kernel, table, heads: int, operands: "_Operands", *,
+               out_shape, in_specs, out_specs, scratch_shapes):
     """``pl.pallas_call`` of ``kernel`` over ``(heads, the table's
-    steps)``: the table's three columns go ahead of the operands by scalar
+    steps)``: the table's columns go ahead of the operands by scalar
     prefetch, to the kernel and to every index map, which take ``(head,
-    step, qi_ref, ki_ref, kind_ref)``. One-dimensional columns: SMEM pads
-    an array's last dim to 128 words, so ``[steps, 3]`` as it stands
-    would take 512 bytes a step. The steps the launch takes, by kind, ride
-    in the custom call as its kernel metadata
-    (``profiling.count_flash_grid_steps`` reads them from the compiled
-    program)."""
+    step, qi_ref, ki_ref, kind_ref)`` and, where a key/value head's table
+    lists its group's heads, a fourth ``head_ref``. One-dimensional
+    columns: SMEM pads an array's last dim to 128 words, so ``[steps, 3]``
+    as it stands would take 512 bytes a step. The steps the launch takes,
+    by kind, ride in the custom call as its kernel metadata beside how it
+    finds a head's blocks — ``layout`` and ``kv``, ``_Operands`` —
+    (``profiling.count_flash_grid_steps`` and ``count_flash_layouts``
+    read them from the compiled program)."""
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -452,13 +494,15 @@ def _tile_call(kernel, table, heads: int, *, out_shape, in_specs, out_specs,
         functools.partial(kernel, kinds=frozenset(kinds.tolist())),
         out_shape=out_shape,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=3, grid=(heads, len(table)),
+            num_scalar_prefetch=table.shape[1], grid=(heads, len(table)),
             in_specs=in_specs, out_specs=out_specs,
             scratch_shapes=scratch_shapes),
-        metadata={name: str(n) for name, n in zip(TILE_KINDS, counts)},
+        metadata={**{name: str(n) for name, n in zip(TILE_KINDS, counts)},
+                  "layout": "rows" if operands.rows else "heads",
+                  "kv": "grouped" if operands.groups > 1 else "own"},
         **_interp_kw())
-    columns = [jnp.asarray(table[:, c]) for c in range(3)]
-    return lambda *operands: call(*columns, *operands)
+    columns = [jnp.asarray(table[:, c]) for c in range(table.shape[1])]
+    return lambda *arrays: call(*columns, *arrays)
 
 
 # ---------------------------------------------------------------- pallas fwd
@@ -527,12 +571,13 @@ def _flash_fwd_kernel(qi_ref, ki_ref, kind_ref, q_ref, k_ref, v_ref, o_ref,
 
 def _pad_blocks(q, k, v, block_q: int, block_k: int):
     """Clamp blocks to the (tile-rounded) sequence lengths, then pad seq
-    dims to the block grid and the head dim to the lane width. Returns the
-    padded tensors, effective blocks, and the padded dims. ``block_q`` is
-    a lane multiple unless one block covers the whole query length: the
-    backward reads the per-row statistics as lane-dense ``[1, block_q]``
-    rows, and a TPU block's last dim is a multiple of 128 or the full
-    dim."""
+    dims to the block grid and the head dim to the lane width (nothing to
+    pad at a head of 128 lanes or a multiple). Returns the padded
+    ``[b, seq, heads, d]`` tensors, effective blocks, and the padded dims.
+    ``block_q`` is a lane multiple unless one block covers the whole query
+    length: the backward reads the per-row statistics as lane-dense
+    ``[1, block_q]`` rows, and a TPU block's last dim is a multiple of 128
+    or the full dim."""
     b, sq, h, d = q.shape
     sk = k.shape[1]
     block_q = min(ceil_to(block_q, LANE), ceil_to(sq, 16))
@@ -545,14 +590,96 @@ def _pad_blocks(q, k, v, block_q: int, block_k: int):
     return q, k, v, block_q, block_k, sq_p, sk_p, d_p
 
 
-def _q_block(i, step, qi_ref, ki_ref, kind_ref):
-    """Index map of a ``[heads, seq, d]`` operand's query block."""
-    return i, qi_ref[step], 0
+def _div(i, n: int):
+    """``i // n``, and ``i`` itself for one."""
+    return i if n == 1 else i // n
 
 
-def _k_block(i, step, qi_ref, ki_ref, kind_ref):
-    """Index map of a ``[heads, seq, d]`` operand's key block."""
-    return i, ki_ref[step], 0
+@dataclasses.dataclass(frozen=True)
+class _Operands:
+    """How the launches of one call find a head's blocks: the layout of
+    their operands and the index maps over them. One rule picks the
+    layout, from the head's width alone:
+
+    - ``rows`` (``head_dim % 128 == 0``): q, dO, out, dq stay ``[batch,
+      seq, heads·d]`` as the projections write them, k, v, dk, dv
+      ``[batch, seq, kv_heads·d]``; a ``(1, block, d)`` block at ``(batch,
+      block index, head)`` IS the head's block, no transpose on either
+      side of a launch.
+    - ``heads`` (any other width): a ``d``-wide block of such an array is
+      no legal TPU block, so the operands are padded to the 128 lanes and
+      folded head-major, ``[batch·heads, seq, d_p]``.
+
+    Either way a key/value head is read by the ``groups`` consecutive
+    query heads it serves — its blocks are named ``head // groups`` — and
+    is never repeated. The grid's first index is ``batch·heads + head``;
+    ``by_group`` is the ``dk/dv`` launch, whose first index counts
+    key/value heads and whose table names the head of the group
+    (``tile_table``)."""
+
+    heads: int
+    groups: int
+    rows: bool
+    by_group: bool = False
+
+    @property
+    def kv_heads(self) -> int:
+        return self.heads // self.groups
+
+    def fold(self, a):
+        """A padded ``[b, seq, h, d]`` operand as the kernels read it."""
+        b, s, h, d = a.shape
+        if self.rows:
+            return a.reshape(b, s, h * d)
+        return a.transpose(0, 2, 1, 3).reshape(b * h, s, d)
+
+    def unfold(self, a, b: int, kv: bool = False):
+        """A result of the kernels back as ``[b, seq, h, d]`` (``kv``: at
+        the key/value heads)."""
+        h = self.kv_heads if kv else self.heads
+        if self.rows:
+            return a.reshape(b, a.shape[1], h, -1)
+        return a.reshape(b, h, *a.shape[1:]).transpose(0, 2, 1, 3)
+
+    def _heads_of(self, i, step, refs):
+        """This step's query head and key/value head, both counted over
+        the batch."""
+        if self.by_group and self.groups > 1:
+            return i * self.groups + refs[3][step], i
+        return i, _div(i, self.groups)
+
+    def q_block(self, i, step, *refs):
+        """Index map of a query-side operand's block."""
+        n, _ = self._heads_of(i, step, refs)
+        if self.rows:
+            return n // self.heads, refs[0][step], n % self.heads
+        return n, refs[0][step], 0
+
+    def k_block(self, i, step, *refs):
+        """Index map of a key-side operand's block."""
+        _, m = self._heads_of(i, step, refs)
+        if self.rows:
+            return m // self.kv_heads, refs[1][step], m % self.kv_heads
+        return m, refs[1][step], 0
+
+    def q_lanes(self, i, step, *refs):
+        """Index map of the query block's ``[b·h, seq, LANE]`` rows (the
+        forward's logsumexp, head-major in either layout)."""
+        return self._heads_of(i, step, refs)[0], refs[0][step], 0
+
+    def q_stats(self, i, step, *refs):
+        """Index map of the query block's ``[b·h, 1, seq]`` statistics."""
+        return self._heads_of(i, step, refs)[0], 0, refs[0][step]
+
+
+def _operands(q, k) -> _Operands:
+    """The layout and grouping of a call over ``q`` [b, sq, h, d] and
+    ``k`` [b, sk, kv_heads, d]."""
+    h, kv_heads = q.shape[2], k.shape[2]
+    if h % kv_heads:
+        raise ValueError(f"{h} query heads are no multiple of {kv_heads} "
+                         "key/value heads")
+    return _Operands(h, h // kv_heads, q.shape[3] % LANE == 0)
 
 
 def _flash_fwd(q, k, v, causal: bool, block_q: int, block_k: int,
@@ -567,36 +694,36 @@ def _flash_fwd(q, k, v, causal: bool, block_q: int, block_k: int,
     # shift it
     mask = static_mask(causal, mask, sq, sk)
     sm_scale = 1.0 / math.sqrt(d)
+    ops = _operands(q, k)
     q, k, v, block_q, block_k, sq_p, sk_p, d_p = _pad_blocks(
         q, k, v, block_q, block_k)
-    # fold (batch, heads) into the leading grid dim; the second is the
+    # the leading grid dim is (batch, head) folded; the second is the
     # head's live tiles in the table's order: k/v stream through VMEM one
     # block per step (pallas double-buffers the HBM loads, and a block the
     # next step names again is not loaded again), accumulators persist in
-    # VMEM scratch across a query block's steps.
-    qt = q.transpose(0, 2, 1, 3).reshape(b * h, sq_p, d_p)
-    kt = k.transpose(0, 2, 1, 3).reshape(b * h, sk_p, d_p)
-    vt = v.transpose(0, 2, 1, 3).reshape(b * h, sk_p, d_p)
+    # VMEM scratch across a query block's steps. A head's block is found
+    # by the index maps (``_Operands``): in the projections' own layout at
+    # a head of whole lanes, in head-major copies otherwise; a key/value
+    # head by its group in both.
+    qt, kt, vt = ops.fold(q), ops.fold(k), ops.fold(v)
     kv_len = sk if sk_p != sk else None
     table = tile_table(sq_p // block_q, sk_p // block_k, block_q, block_k,
                        mask, kv_len)
-    out_shape = [jax.ShapeDtypeStruct((b * h, sq_p, d_p), q.dtype)]
-    out_specs = [pl.BlockSpec((1, block_q, d_p), _q_block)]
+    q_spec = pl.BlockSpec((1, block_q, d_p), ops.q_block)
+    k_spec = pl.BlockSpec((1, block_k, d_p), ops.k_block)
+    out_shape = [jax.ShapeDtypeStruct(qt.shape, q.dtype)]
+    out_specs = [q_spec]
     if return_lse:
         out_shape.append(
             jax.ShapeDtypeStruct((b * h, sq_p, LANE), jnp.float32))
-        out_specs.append(pl.BlockSpec((1, block_q, LANE), _q_block))
+        out_specs.append(pl.BlockSpec((1, block_q, LANE), ops.q_lanes))
     res = _tile_call(
         functools.partial(_flash_fwd_kernel, block_k=block_k,
                           block_q=block_q, mask=mask, sm_scale=sm_scale,
                           kv_len=kv_len),
-        table, b * h,
+        table, b * h, ops,
         out_shape=tuple(out_shape),
-        in_specs=[
-            pl.BlockSpec((1, block_q, d_p), _q_block),
-            pl.BlockSpec((1, block_k, d_p), _k_block),
-            pl.BlockSpec((1, block_k, d_p), _k_block),
-        ],
+        in_specs=[q_spec, k_spec, k_spec],
         out_specs=tuple(out_specs),
         scratch_shapes=[
             pltpu.VMEM((block_q, d_p), jnp.float32),
@@ -605,8 +732,7 @@ def _flash_fwd(q, k, v, causal: bool, block_q: int, block_k: int,
         ],
     )(qt, kt, vt)
     out, lse = res if return_lse else (res[0], None)
-    out = out.reshape(b, h, sq_p, d_p).transpose(0, 2, 1, 3)
-    out = out[:, :sq, :, :d]                    # drop padded rows/lanes
+    out = ops.unfold(out, b)[:, :sq, :, :d]     # drop padded rows/lanes
     if return_lse:
         return out, lse[:, :sq, 0]
     return out
@@ -667,11 +793,15 @@ def _flash_bwd_dq_kernel(qi_ref, ki_ref, kind_ref, q_ref, k_ref, v_ref,
         dq_ref[0] = dq_scr[...].astype(dq_ref.dtype)
 
 
-def _flash_bwd_dkv_kernel(qi_ref, ki_ref, kind_ref, q_ref, k_ref, v_ref,
-                          do_ref, lse_ref, delta_ref, dk_ref, dv_ref,
-                          dk_scr, dv_scr, *, kinds, **tile):
+def _flash_bwd_dkv_kernel(qi_ref, ki_ref, kind_ref, *refs, kinds, groups,
+                          **tile):
     import jax.experimental.pallas as pl
 
+    # a key/value head's table names the head of its group in a fourth
+    # column, for the index maps alone: the key block's run, over which
+    # dk and dv accumulate, is all of the group's heads
+    (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref, dv_ref,
+     dk_scr, dv_scr) = refs[1:] if groups > 1 else refs
     qi, ki, kind, first, last = _step(qi_ref, ki_ref, kind_ref,
                                       key_major=True)
 
@@ -699,6 +829,23 @@ def _flash_bwd_dkv_kernel(qi_ref, ki_ref, kind_ref, q_ref, k_ref, v_ref,
         dv_ref[0] = dv_scr[...].astype(dv_ref.dtype)
 
 
+def _row_sums(x, heads: int):
+    """Each head's sum over its ``d`` lanes of ``x`` [b, seq, heads·d]
+    float32, as ``[b·heads, seq]``: a product with the heads' indicator
+    columns. A ``reshape`` to ``[b, seq, heads, d]`` and a sum over ``d``
+    say the same, and XLA's TPU compiler then copies ``x`` from (seq,
+    lanes) tiles into (heads, d) tiles to take it; the product reads
+    ``x`` where it lies. The indicator is exact in any precision and ``x``
+    goes at the highest, so the sums are float32 sums."""
+    b, s, width = x.shape
+    d = width // heads
+    of_head = (jnp.arange(width)[:, None] // d
+               == jnp.arange(heads)[None, :]).astype(jnp.float32)
+    return jnp.einsum("bsk,kh->bhs", x, of_head,
+                      precision=jax.lax.Precision.HIGHEST).reshape(
+                          b * heads, s)
+
+
 def _flash_bwd(q, k, v, o, lse, g, causal: bool, block_q: int,
                block_k: int, g_lse=None, mask=None):
     import jax.experimental.pallas as pl
@@ -708,19 +855,21 @@ def _flash_bwd(q, k, v, o, lse, g, causal: bool, block_q: int,
     sk = k.shape[1]
     mask = static_mask(causal, mask, sq, sk)
     sm_scale = 1.0 / math.sqrt(d)
+    ops = _operands(q, k)
     q, k, v, block_q, block_k, sq_p, sk_p, d_p = _pad_blocks(
         q, k, v, block_q, block_k)
     o = _pad_axis(_pad_axis(o, 1, sq_p), 3, d_p)
     g = _pad_axis(_pad_axis(g, 1, sq_p), 3, d_p)
-    qt = q.transpose(0, 2, 1, 3).reshape(b * h, sq_p, d_p)
-    kt = k.transpose(0, 2, 1, 3).reshape(b * h, sk_p, d_p)
-    vt = v.transpose(0, 2, 1, 3).reshape(b * h, sk_p, d_p)
-    dot = g.transpose(0, 2, 1, 3).reshape(b * h, sq_p, d_p)
-    # Δ = rowsum(dO ⊙ O): cheap elementwise, stays outside the kernels.
-    # Padded query rows have dO = 0, so Δ = 0 there.
-    delta = jnp.sum(dot.astype(jnp.float32)
-                    * o.transpose(0, 2, 1, 3).reshape(
-                        b * h, sq_p, d_p).astype(jnp.float32), axis=-1)
+    qt, kt, vt, dot = ops.fold(q), ops.fold(k), ops.fold(v), ops.fold(g)
+    # Δ = rowsum(dO ⊙ O): cheap elementwise, stays outside the kernels,
+    # taken in the operands' layout; its [b·h, seq] rows alone are
+    # head-major in both. Padded query rows have dO = 0, so Δ = 0 there.
+    if ops.rows:
+        delta = _row_sums(dot.astype(jnp.float32)
+                          * ops.fold(o).astype(jnp.float32), h)
+    else:
+        delta = jnp.sum(dot.astype(jnp.float32)
+                        * ops.fold(o).astype(jnp.float32), axis=-1)
     if g_lse is not None:
         # the cotangent of the row logsumexp folds into the same
         # softmax-Jacobian term as Δ, since ∂lse_i/∂s_ij = p_ij
@@ -739,36 +888,39 @@ def _flash_bwd(q, k, v, o, lse, g, causal: bool, block_q: int,
                 sm_scale=sm_scale, kv_len=kv_len)
     tiles = functools.partial(tile_table, sq_p // block_q, sk_p // block_k,
                               block_q, block_k, mask, kv_len)
-    q_spec = pl.BlockSpec((1, block_q, d_p), _q_block)
-    k_spec = pl.BlockSpec((1, block_k, d_p), _k_block)
-    r_spec = pl.BlockSpec(
-        (1, 1, block_q), lambda i, step, qi_ref, *_: (i, 0, qi_ref[step]))
-    in_specs = [q_spec, k_spec, k_spec, q_spec, r_spec, r_spec]
+
+    def in_specs(maps):
+        q_spec = pl.BlockSpec((1, block_q, d_p), maps.q_block)
+        k_spec = pl.BlockSpec((1, block_k, d_p), maps.k_block)
+        r_spec = pl.BlockSpec((1, 1, block_q), maps.q_stats)
+        return [q_spec, k_spec, k_spec, q_spec, r_spec, r_spec]
+
     dq = _tile_call(
-        functools.partial(_flash_bwd_dq_kernel, **tile), tiles(), b * h,
-        out_shape=jax.ShapeDtypeStruct((b * h, sq_p, d_p), q.dtype),
-        in_specs=in_specs,
-        out_specs=q_spec,
+        functools.partial(_flash_bwd_dq_kernel, **tile), tiles(), b * h, ops,
+        out_shape=jax.ShapeDtypeStruct(qt.shape, q.dtype),
+        in_specs=in_specs(ops),
+        out_specs=pl.BlockSpec((1, block_q, d_p), ops.q_block),
         scratch_shapes=[pltpu.VMEM((block_q, d_p), jnp.float32)],
     )(qt, kt, vt, dot, lse, delta)
-    # dk/dv: key blocks resident, their live query blocks stream
+    # dk/dv: a key/value head's key blocks resident, each over its group's
+    # query heads in turn and their live query blocks, which stream: dk
+    # and dv come out at the key/value heads, summed over the group in
+    # the float32 accumulators
+    by_group = dataclasses.replace(ops, by_group=True)
+    kv_spec = pl.BlockSpec((1, block_k, d_p), by_group.k_block)
     dk, dv = _tile_call(
-        functools.partial(_flash_bwd_dkv_kernel, **tile),
-        tiles(key_major=True), b * h,
-        out_shape=(jax.ShapeDtypeStruct((b * h, sk_p, d_p), k.dtype),
-                   jax.ShapeDtypeStruct((b * h, sk_p, d_p), v.dtype)),
-        in_specs=in_specs,
-        out_specs=(k_spec, k_spec),
+        functools.partial(_flash_bwd_dkv_kernel, groups=ops.groups, **tile),
+        tiles(key_major=True, groups=ops.groups), b * ops.kv_heads, by_group,
+        out_shape=(jax.ShapeDtypeStruct(kt.shape, k.dtype),
+                   jax.ShapeDtypeStruct(vt.shape, v.dtype)),
+        in_specs=in_specs(by_group),
+        out_specs=(kv_spec, kv_spec),
         scratch_shapes=[pltpu.VMEM((block_k, d_p), jnp.float32),
                         pltpu.VMEM((block_k, d_p), jnp.float32)],
     )(qt, kt, vt, dot, lse, delta)
-
-    def unfold(a, s):
-        return a.reshape(b, h, s, d_p).transpose(0, 2, 1, 3)
-
-    return (unfold(dq, sq_p)[:, :sq, :, :d],
-            unfold(dk, sk_p)[:, :sk, :, :d],
-            unfold(dv, sk_p)[:, :sk, :, :d])
+    return (ops.unfold(dq, b)[:, :sq, :, :d],
+            ops.unfold(dk, b, kv=True)[:, :sk, :, :d],
+            ops.unfold(dv, b, kv=True)[:, :sk, :, :d])
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
@@ -780,7 +932,11 @@ def flash_attention(q, k, v, causal: bool = False, block_q: int = 128,
     the measured-fastest block config should go through
     ``ops.autotune.auto_flash_attention`` instead of picking blocks.
     ``mask``: a static ``TileMask`` in place of ``causal``; its dead
-    tiles are no grid step in any of the three kernels."""
+    tiles are no grid step in any of the three kernels. ``q``
+    [b, sq, h, d]; ``k``, ``v`` [b, sk, kv_heads, d] with ``kv_heads``
+    dividing ``h``: each serves ``h // kv_heads`` consecutive query heads,
+    is read by them and not repeated, and gets its gradient at
+    ``kv_heads``."""
     return _flash_fwd(q, k, v, causal, block_q, block_k, mask=mask)
 
 

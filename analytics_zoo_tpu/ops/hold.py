@@ -12,6 +12,7 @@ the compiler must materialise: its consumers, forward and backward, read
 it from memory.
 
 - :func:`hold` — the barrier, in a differentiated program only.
+- :func:`hold_both_ways` — the same, and on the cotangent as well.
 - :func:`gelu_exact` — ``jax.nn.gelu(approximate=False)`` with its
   ``erfc`` held.
 - :class:`Dropout` — ``flax.linen.Dropout`` with its keep-mask held.
@@ -51,6 +52,27 @@ def _hold_bwd(_, g):
 
 
 hold.defvjp(_hold_fwd, _hold_bwd)
+
+
+@jax.custom_vjp
+def hold_both_ways(x):
+    """:func:`hold` whose cotangent is held too: under differentiation
+    ``x`` is materialised as it stands before anything reads it, and so is
+    what the backward pass hands back for it. For a value at the door of
+    a consumer that wants it in another LAYOUT than its producers work
+    in: left alone, XLA's TPU compiler hoists the reshape between the two
+    above the producer's conversions and elementwise chain (and sinks the
+    cotangent's below the consumer's), and makes the copy on their
+    float32 intermediates, several times, where one copy of the rounded
+    value does."""
+    return x
+
+
+def _hold_both_ways_bwd(_, g):
+    return (lax.optimization_barrier(g),)
+
+
+hold_both_ways.defvjp(_hold_fwd, _hold_both_ways_bwd)
 
 
 def gelu_exact(x):

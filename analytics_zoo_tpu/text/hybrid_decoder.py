@@ -81,7 +81,10 @@ def _products_saveable(prim, *_, **__) -> bool:
 
 #: What a layer keeps for the backward pass beside its input: its
 #: products' results, and the attention kernel's output and logsumexp —
-#: 69 MB a layer at 2 x 8,192 x 32 x 64 (67 bf16 + 2 float32), against a
+#: 69 MB a layer at 2 x 8,192 x 32 x 64 (67 bf16 + 2 float32) and 136 MB
+#: at 1 x 16,384 x 32 x 128 (134 + 2; the output is kept as
+#: [batch, seq, heads, d] whichever layout the kernels read, the
+#: logsumexp as [batch·heads, seq]), against a
 #: second launch of the forward kernel in the backward pass. The rest is
 #: recomputed (norms, gates, rotary positions, the sort and gathers of
 #: the expert layer): a third of the activations' memory, which at 16k

@@ -379,14 +379,19 @@ def _check(table: list, name: str, got, want, rtol: float):
     print(f"  {name}: compiled, max |err| {err:.3g}", flush=True)
 
 
-def _flash_checks(table, b, s, h, d, causal):
+def _flash_checks(table, b, s, h, d, causal, kv_heads=None):
+    """``kv_heads``: k and v at fewer heads than q, read by their groups;
+    the reference takes them repeated."""
     import jax
     import jax.numpy as jnp
     from analytics_zoo_tpu.ops import flash_attention as fa
 
-    q, k, v = (jax.random.normal(key, (b, s, h, d), jnp.bfloat16)
-               for key in jax.random.split(jax.random.PRNGKey(0), 3))
-    tag = f"b{b}s{s}h{h}d{d}{' causal' if causal else ''}"
+    q, k, v = (jax.random.normal(key, (b, s, heads, d), jnp.bfloat16)
+               for key, heads in zip(
+                   jax.random.split(jax.random.PRNGKey(0), 3),
+                   (h, kv_heads or h, kv_heads or h)))
+    tag = (f"b{b}s{s}h{h}{f'/{kv_heads}' if kv_heads else ''}d{d}"
+           f"{' causal' if causal else ''}")
 
     def sq(a):
         return (a.astype(jnp.float32) ** 2).sum()
@@ -395,15 +400,16 @@ def _flash_checks(table, b, s, h, d, causal):
         return fa.flash_attention(q, k, v, causal)
 
     def reference(q, k, v):
-        return fa.blockwise_attention(q, k, v, causal=causal)
+        return fa.blockwise_attention(q, *fa.repeat_kv_heads(q, k, v),
+                                      causal=causal)
 
     def kernel_lse(q, k, v):
         out, lse = fa.flash_attention_with_lse(q, k, v, causal)
         return sq(out) + (0.1 * lse).sum()
 
     def reference_lse(q, k, v):
-        out, lse = fa.blockwise_attention(q, k, v, causal=causal,
-                                          return_lse=True)
+        out, lse = fa.blockwise_attention(
+            q, *fa.repeat_kv_heads(q, k, v), causal=causal, return_lse=True)
         return sq(out) + (0.1 * lse).sum()
 
     def both(f, g):
@@ -430,6 +436,9 @@ def phase_kernels() -> list:
     table: list = []
     _flash_checks(table, BERT_BATCH, BERT_SEQ, 12, 64, causal=False)
     _flash_checks(table, 2, 2048, 8, 128, causal=True)
+    # both operand layouts with a key/value head read by its group
+    _flash_checks(table, 2, 2048, 8, 128, causal=True, kv_heads=2)
+    _flash_checks(table, 2, 2048, 8, 64, causal=True, kv_heads=2)
 
     def both_paths(fn, *args):
         """``fn(*args, use_kernel)`` jitted with the kernel pinned on, then

@@ -713,3 +713,174 @@ def test_flash_schedule_matches_blockwise_and_its_vjp(orca_ctx, monkeypatch,
         # zeros, as the rectangular grid left them
         assert not np.asarray(got[0][:, :bq_p]).any()
         assert not np.asarray(got_grads[0][:, :bq_p]).any()
+
+
+# ------------------------- key/value heads by group, operands by layout
+
+def _launches(fn, *args):
+    """The metadata of every ``pallas_call`` in ``fn``'s jaxpr, in order,
+    and the shapes its ``transpose`` equations are given."""
+    import jax
+
+    def eqns(jaxpr):
+        for eqn in jaxpr.eqns:
+            yield eqn
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                yield from eqns(sub)
+
+    found = list(eqns(jax.make_jaxpr(fn)(*args).jaxpr))
+    return ([dict(e.params["metadata"]) for e in found
+             if e.primitive.name == "pallas_call"],
+            [e.invars[0].aval.shape for e in found
+             if e.primitive.name == "transpose"])
+
+
+def _dense_with_lse(q, k, v, allowed):
+    """Dense attention of ``q`` over k, v REPEATED to its heads, under the
+    boolean ``allowed`` [sq, sk]: output and the row logsumexp as
+    ``[b·h, sq]``."""
+    import jax
+    import jax.numpy as jnp
+    from analytics_zoo_tpu.ops import attention as attention_lib
+    b, sq, h, d = q.shape
+    scores = jnp.einsum("bqhd,bkhd->bhqk", q, k) / np.sqrt(d)
+    scores = jnp.where(allowed[None, None], scores, -1e30)
+    out = attention_lib._reference_attention(q, k, v, mask=allowed)
+    return out, jax.nn.logsumexp(scores, axis=-1).reshape(b * h, sq)
+
+
+#: the masks a grouped launch is held to: id -> (sq, sk, causal, the
+#: block-diffusion mask's (L, B) or None)
+GROUPED_MASKS = {
+    "causal": (256, 256, True, None),
+    "block_diffusion": (256, 256, False, (128, 4)),
+    "unmasked_ragged_keys": (128, 200, False, None),
+}
+
+
+@pytest.mark.parametrize("masking", list(GROUPED_MASKS))
+@pytest.mark.parametrize("head_dim", [128, 64])
+@pytest.mark.parametrize("groups", [1, 4, 8])
+def test_grouped_kv_through_the_three_kernels(orca_ctx, monkeypatch, groups,
+                                              head_dim, masking):
+    """k and v at ``kv_heads = heads // groups`` through the real kernel
+    bodies, interpreted: output, logsumexp and ``dq`` against dense
+    attention on REPEATED k, v, and ``dk``, ``dv`` — which come out at
+    ``kv_heads`` — against that reference's gradients summed over each
+    group (the logsumexp's cotangent included). Each launch says how it
+    found a head's blocks: ``rows`` at a head of whole lanes (no
+    head-major transpose of any operand then), ``heads`` otherwise;
+    ``grouped`` when a key/value head serves more than one query head."""
+    import jax
+    import jax.numpy as jnp
+    from analytics_zoo_tpu.ops import flash_attention as fa
+
+    monkeypatch.setenv("ZOO_PALLAS_INTERPRET", "1")
+    sq, sk, causal, bd = GROUPED_MASKS[masking]
+    mask = fa.BlockDiffusionMask(*bd) if bd else None
+    b, h, d = 1, 8, head_dim
+    kv_heads = h // groups
+    rng = np.random.default_rng(groups + head_dim + sq)
+    q = jnp.asarray(rng.normal(size=(b, sq, h, d)), jnp.float32)
+    k, v = (jnp.asarray(rng.normal(size=(b, sk, kv_heads, d)), jnp.float32)
+            for _ in range(2))
+    g = jnp.asarray(rng.normal(size=(b, sq, h, d)), jnp.float32)
+    g_lse = jnp.asarray(rng.normal(size=(b * h, sq)), jnp.float32)
+    if mask is not None:
+        allowed = mask.dense(sq, sk)
+    elif causal:
+        allowed = jnp.tril(jnp.ones((sq, sk), bool), k=sk - sq)
+    else:
+        allowed = jnp.ones((sq, sk), bool)
+
+    def flash(q, k, v):
+        return fa.flash_attention_with_lse(q, k, v, causal, 128, 128, mask)
+
+    got, vjp = jax.vjp(flash, q, k, v)
+    dq, dk, dv = vjp((g, g_lse))
+    want, ref_vjp = jax.vjp(
+        lambda q, k, v: _dense_with_lse(q, k, v, allowed),
+        q, *fa.repeat_kv_heads(q, k, v))
+    want_dq, want_dk, want_dv = ref_vjp((g, g_lse))
+    assert dk.shape == dv.shape == (b, sk, kv_heads, d)
+
+    def group_sums(a):
+        return a.reshape(b, sk, kv_heads, groups, d).sum(3)
+
+    tol = dict(rtol=2e-4, atol=2e-5)
+    np.testing.assert_allclose(got[0], want[0], **tol, err_msg="out")
+    np.testing.assert_allclose(got[1], want[1], **tol, err_msg="lse")
+    np.testing.assert_allclose(dq, want_dq, **tol, err_msg="dq")
+    np.testing.assert_allclose(dk, group_sums(want_dk), **tol, err_msg="dk")
+    np.testing.assert_allclose(dv, group_sums(want_dv), **tol, err_msg="dv")
+
+    launches, transposed = _launches(
+        lambda q, k, v: jax.vjp(flash, q, k, v)[1]((g, g_lse)), q, k, v)
+    assert len(launches) == 3
+    for said in launches:
+        assert said["layout"] == ("rows" if head_dim % 128 == 0 else "heads")
+        assert said["kv"] == ("grouped" if groups > 1 else "own")
+    four_d = [s for s in transposed if len(s) == 4]
+    assert bool(four_d) == (head_dim % 128 != 0)
+
+
+#: id -> (sq, sk, block_q, block_k, the mask's constructor and arguments)
+GROUP_TABLES = {
+    "causal_4x4": (512, 512, 128, 128, ("CausalMask", 0)),
+    "causal_unequal_blocks": (512, 512, 256, 128, ("CausalMask", 0)),
+    "more_keys_than_queries": (256, 512, 128, 128, ("CausalMask", 256)),
+    # bottom-right aligned at fewer keys than queries shifted the other
+    # way: the last two key blocks hold no allowed pair
+    "key_blocks_no_query_sees": (128, 384, 128, 128, ("CausalMask", -256)),
+    "block_diffusion": (256, 256, 64, 64, ("BlockDiffusionMask", 128, 4)),
+    "not_causal_ragged_keys": (256, 300, 128, 128, None),
+}
+
+
+@pytest.mark.parametrize("groups", [2, 4, 8])
+@pytest.mark.parametrize("case", list(GROUP_TABLES))
+def test_key_major_table_keeps_a_key_block_over_its_groups_heads(case,
+                                                                groups):
+    """Pure Python. A key/value head's ``dk/dv`` table: every ``(head of
+    the group, qi, ki)`` live triple once; a key block's run unbroken
+    across the group's heads, each head over the block's live query
+    blocks ascending; the kernels' ``first`` / ``last`` (a change of
+    ``ki``) true exactly at the run's ends; with one head a group the
+    table IS the parent's, bytes and shape."""
+    from analytics_zoo_tpu.ops import flash_attention as fa
+
+    sq, sk, bq, bk, masking = GROUP_TABLES[case]
+    mask = getattr(fa, masking[0])(*masking[1:]) if masking else None
+    nq, nk = -(-sq // bq), -(-sk // bk)
+    kv_len = sk if sk % bk else None
+    one = fa.tile_table(nq, nk, bq, bk, mask, kv_len, key_major=True)
+    same = fa.tile_table(nq, nk, bq, bk, mask, kv_len, key_major=True,
+                         groups=1)
+    assert same.shape == one.shape and same.tobytes() == one.tobytes()
+    table = fa.tile_table(nq, nk, bq, bk, mask, kv_len, key_major=True,
+                          groups=groups)
+    assert table.dtype == np.int32 and table.shape[1] == 4
+    rows = [tuple(r) for r in table.tolist()]
+    live = [r for r in one.tolist() if r[2] != fa.DEAD]
+    # every live triple of every head of the group, once
+    assert sorted(r for r in rows if r[2] != fa.DEAD) == sorted(
+        (qi, ki, kind, head) for qi, ki, kind in live
+        for head in range(groups))
+    # key block -> head of the group -> live query blocks ascending
+    assert rows == sorted(rows, key=lambda r: (r[1], r[3], r[0]))
+    # a key block no query sees keeps ONE dead step, its zeros' flush
+    for qi, ki, kind in one.tolist():
+        if kind == fa.DEAD:
+            assert [r for r in rows if r[1] == ki] == [(qi, ki, kind, 0)]
+    # ``_step``'s first / last: exactly the ends of a key block's run
+    ki = table[:, 1]
+    first = np.r_[True, ki[1:] != ki[:-1]]
+    last = np.r_[ki[1:] != ki[:-1], True]
+    assert first.sum() == last.sum() == nk
+    for block in range(nk):
+        at = np.flatnonzero(ki == block)
+        assert at.tolist() == list(range(at[0], at[-1] + 1))
+        assert first[at[0]] and last[at[-1]]
+        assert not first[at[1:]].any() and not last[at[:-1]].any()
+    with pytest.raises(ValueError, match="key-major"):
+        fa.tile_table(nq, nk, bq, bk, mask, kv_len, groups=groups)

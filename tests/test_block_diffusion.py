@@ -232,6 +232,16 @@ def test_causal_tables_are_the_parents_row_for_row(case):
     assert fa.tile_table(nq, nk, bq, bk, mask, kv_len).tolist() == by_query
     assert fa.tile_table(nq, nk, bq, bk, mask, kv_len,
                          key_major=True).tolist() == by_key
+    # dk/dv at one head a group: the parent's table, not a fourth column
+    assert fa.tile_table(nq, nk, bq, bk, mask, kv_len, key_major=True,
+                         groups=1).tolist() == by_key
+    # and with a group, each head's rows of a key block are the parent's
+    by_head = fa.tile_table(nq, nk, bq, bk, mask, kv_len, key_major=True,
+                            groups=2).tolist()
+    live = [r for r in by_key if r[2] != fa.DEAD]
+    for head in range(2):
+        assert [r[:3] for r in by_head
+                if r[3] == head and r[2] != fa.DEAD] == live
 
 
 def test_the_decoder_cells_causal_tables_are_the_parents_bytes():
@@ -241,6 +251,18 @@ def test_the_decoder_cells_causal_tables_are_the_parents_bytes():
                                   key_major=key_major)
             assert table.dtype == np.int32
             assert hashlib.sha256(table.tobytes()).hexdigest()[:16] == want
+        # the dk/dv launch at one head a group prefetches these bytes
+        one = fa.tile_table(nq, nk, bq, bk, fa.CausalMask(0), None,
+                            key_major=True, groups=1)
+        assert hashlib.sha256(one.tobytes()).hexdigest()[:16] == digests[1]
+        # lfm2's 4 and sdar's 8 heads a group visit the same tiles, each
+        # key block over the group's heads in turn
+        for groups in (4, 8):
+            grouped = fa.tile_table(nq, nk, bq, bk, fa.CausalMask(0), None,
+                                    key_major=True, groups=groups)
+            assert grouped.shape == (len(one) * groups, 4)
+            assert grouped[grouped[:, 3] == groups - 1][:, :3].tobytes() \
+                == one.tobytes()
 
 
 def test_a_mask_is_given_in_place_of_causal_and_over_its_own_rows():

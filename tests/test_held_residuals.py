@@ -473,6 +473,54 @@ def test_v5e_compiles_the_three_kernels_under_the_block_diffusion_mask(
         for kind, n in (("interior", 2), ("diagonal", 6), ("dead", 0))}
 
 
+@pytest.mark.parametrize("shape,kv_heads,masking,layout,tiles", [
+    ((1, 16384, 32, 128), 4, "block_diffusion", "rows", (56, 24, 0)),
+    ((2, 8192, 32, 64), 8, "causal", "heads", (28, 8, 0))],
+    ids=["the_block_diffusion_cells_launches", "the_causal_cells_launches"])
+def test_v5e_compiles_the_decoder_cells_grouped_launches(
+        one_chip, shape, kv_heads, masking, layout, tiles):
+    """The three launches at each decoder cell's own size — q at 32 heads,
+    k and v at the cell's 4 or 8 — compiled for the chip at the untuned
+    1,024 x 1,024 blocks: they fit the kernels' VMEM, each call says the
+    layout the head's width picks and that its key/value heads are read
+    by their groups, and each lists the cell's tiles (a head's count
+    times batch x 32 heads: the ``dk/dv`` launch runs over the key/value
+    heads and takes a group's heads inside a key block's run, the same
+    tiles in another order)."""
+    from analytics_zoo_tpu.common import profiling
+    from analytics_zoo_tpu.ops import autotune
+    from analytics_zoo_tpu.ops.flash_attention import (BlockDiffusionMask,
+                                                       flash_attention)
+
+    b, s, h, d = shape
+    blocks = autotune.untuned_blocks(d, jnp.bfloat16)
+    assert blocks == (1024, 1024)
+    mask = BlockDiffusionMask(s // 2, 4) if masking == "block_diffusion" \
+        else None
+    q = jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
+    k = jax.ShapeDtypeStruct((b, s, kv_heads, d), jnp.bfloat16,
+                             sharding=one_chip)
+    grad = jax.jit(jax.grad(
+        lambda q, k, v: (flash_attention(q, k, v, mask is None, *blocks,
+                                         mask)
+                         .astype(jnp.float32) ** 2).sum(),
+        argnums=(0, 1, 2)))
+    with compiled_outside_the_cache():
+        compiled = grad.lower(q, k, k).compile()
+    text = compiled.as_text()
+    dq, dk, dv = jax.eval_shape(grad, q, k, k)
+    assert dq.shape == shape and dk.shape == dv.shape == k.shape
+    assert profiling.count_kernel_calls(text) == dict.fromkeys(
+        profiling.KERNEL_FUNCTIONS, 1)
+    assert profiling.count_flash_layouts(text) == {
+        f"{kernel}@{layout},grouped": 1
+        for kernel in profiling.KERNEL_FUNCTIONS}
+    assert profiling.count_flash_grid_steps(text) == {
+        f"{kernel}/{kind}": n * b * h
+        for kernel in profiling.KERNEL_FUNCTIONS
+        for kind, n in zip(profiling.TILE_KINDS, tiles)}
+
+
 @pytest.mark.parametrize("head_dim,dtype", [
     (64, jnp.bfloat16), (256, jnp.bfloat16), (512, jnp.bfloat16),
     (128, jnp.float32), (512, jnp.float32)],

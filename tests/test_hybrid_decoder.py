@@ -528,6 +528,52 @@ def test_outside_differentiation_nothing_is_named(monkeypatch):
                   x, primitive="name") == 2
 
 
+# ------------------ the layer on the kernel path: no copy of a head's data
+
+@pytest.mark.parametrize("head_dim,layout", [(128, "rows"), (64, "heads")])
+def test_the_kernel_path_copies_no_heads_data(monkeypatch, head_dim, layout):
+    """The jaxpr of ``GroupedQueryAttention``'s gradient where the kernels
+    run (a TPU, no verdict, scores beyond the switch): three launches that
+    say ``grouped`` and the layout the head's width picks; k and v are
+    never repeated to the query heads (no ``broadcast_in_dim`` to
+    ``[b, s, kv_heads, groups, d]``), and at a head of whole lanes no
+    ``[b, s, h, d]`` operand is transposed head-major on either side of a
+    launch. Off the kernel path the same layer repeats them, once."""
+    from analytics_zoo_tpu.ops import autotune, flash_attention
+    b, s, h, kv, hidden = 1, 256, 4, 2, 32
+    layer = attention_lib.GroupedQueryAttention(h, kv, head_dim)
+    x = jnp.asarray(np.random.default_rng(0).normal(size=(b, s, hidden)),
+                    jnp.float32)
+    params = layer.init(jax.random.PRNGKey(0), x)
+
+    def seen():
+        def grad(params, x):          # traced anew: jax keeps a jaxpr by
+            return jax.grad(          # the function it was made from
+                lambda p, x: layer.apply(p, x).sum(),
+                argnums=(0, 1))(params, x)
+        found = list(_eqns(jax.make_jaxpr(grad)(params, x).jaxpr))
+        return ([dict(e.params["metadata"]) for e in found
+                 if e.primitive.name == "pallas_call"],
+                [e for e in found if e.primitive.name == "broadcast_in_dim"
+                 and e.outvars[0].aval.shape
+                 == (b, s, kv, h // kv, head_dim)],
+                [e for e in found if e.primitive.name == "transpose"
+                 and len(e.invars[0].aval.shape) == 4])
+
+    launches, repeats, transposes = seen()
+    assert not launches and len(repeats) == 2          # the dense path
+    monkeypatch.setattr(flash_attention, "on_tpu", lambda: True)
+    monkeypatch.setattr(autotune, "on_tpu", lambda: True)
+    monkeypatch.setattr(autotune, "attention_decision", lambda *a: None)
+    monkeypatch.setattr(autotune, "SCORES_SWITCH", 0)
+    launches, repeats, transposes = seen()
+    assert len(launches) == 3
+    assert all(said["layout"] == layout and said["kv"] == "grouped"
+               for said in launches)
+    assert not repeats
+    assert bool(transposes) == (layout == "heads")
+
+
 # ------------------------------------------------ tracing and counters
 
 def test_scopes_and_counters_of_the_step(orca_ctx):

@@ -316,6 +316,58 @@ def test_flash_grid_steps_counted_and_published(fixture, want):
         for k, n in want.items()} or None)
 
 
+def _with_layouts(text, *said):
+    """``text`` with ``layout`` and ``kv`` keys added to its launches'
+    ``kernel_metadata`` as the compiler prints them (sorted, one a line),
+    the n-th custom call of a kernel saying ``said[n]``."""
+    out, n = [], 0
+    for chunk in text.split('"interior":"2"\n'):
+        out.append(chunk)
+        if 'custom_call_target="tpu_custom_call"' in chunk:
+            layout, kv = said[n]
+            n += 1
+            out.append(f'"interior":"2",\n"kv":"{kv}",\n"layout":"{layout}"\n')
+        else:
+            out.append('"interior":"2"\n')
+    return "".join(out[:-1])
+
+
+@pytest.mark.parametrize("said,want", [
+    ([("rows", "grouped")] * 3,
+     {"flash_fwd@rows,grouped": 1, "flash_bwd_dq@rows,grouped": 1,
+      "flash_bwd_dkv@rows,grouped": 1}),
+    ([("heads", "grouped"), ("heads", "own"), ("rows", "own")],
+     {"flash_fwd@heads,grouped": 1, "flash_bwd_dq@heads,own": 1,
+      "flash_bwd_dkv@rows,own": 1}),
+    (None, {})],
+    ids=["the_block_diffusion_cells", "each_launch_its_own",
+         "launches_that_say_neither"])
+def test_flash_layouts_counted_and_published(said, want):
+    """The launches of the live-tiles layer with the two keys a launch
+    writes since it finds a head's blocks by its index maps: counted by
+    kernel, layout and grouping beside the grid steps, which read what
+    they read without the keys. A launch from before the keys gives no
+    series."""
+    with open(LIVE_TILES_LAYER) as fh:
+        text = fh.read()
+    steps = profiling.count_flash_grid_steps(text)
+    if said is not None:
+        text = _with_layouts(text, *said)
+        assert text.count('"layout":"') >= 3
+    assert profiling.count_flash_layouts(text) == want
+    assert profiling.count_flash_grid_steps(text) == steps
+    assert profiling.count_kernel_calls(text) == {
+        "flash_fwd": 1, "flash_bwd_dq": 1, "flash_bwd_dkv": 1}
+    profiling.note_executable("canned", _CannedExe(text))
+    counts = profiling.step_counts("canned")
+    assert {k: n for k, n in counts.items() if "@" in k} == want
+    assert {k: n for k, n in counts.items() if "/" in k} == steps
+    assert telemetry.snapshot().get("zoo_flash_launches") == ({
+        "executable=canned,kernel={},layout={},kv={}".format(
+            k.split("@")[0], *k.split("@")[1].split(",")): n
+        for k, n in want.items()} or None)
+
+
 def test_an_instruction_printed_over_several_lines_keeps_its_scope():
     """XLA prints a call's non-empty ``kernel_metadata`` one key a line;
     the three kernels are still counted once each and still belong to the

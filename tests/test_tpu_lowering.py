@@ -33,42 +33,47 @@ def _sq(a):
 
 
 FLASH_SHAPES = [
-    pytest.param(32, 128, 12, 64, False, id="bert-base-b32s128h12d64"),
-    pytest.param(2, 2048, 8, 128, True, id="b2s2048h8d128-causal"),
-    # the hybrid decoder's attention layer at its cell's size (the key and
-    # value heads repeated to the 32 query heads)
-    pytest.param(2, 8192, 32, 64, True, id="gqa-b2s8192h32d64-causal"),
+    pytest.param(32, 128, 12, 64, False, 12, id="bert-base-b32s128h12d64"),
+    pytest.param(2, 2048, 8, 128, True, 8, id="b2s2048h8d128-causal"),
+    # a key/value head read by its group, in both operand layouts
+    pytest.param(2, 2048, 8, 128, True, 2, id="b2s2048h8kv2d128-causal"),
+    pytest.param(2, 2048, 8, 64, True, 2, id="b2s2048h8kv2d64-causal"),
+    # the hybrid decoder's attention layer at its cell's size: 32 query
+    # heads over 8 key/value heads
+    pytest.param(2, 8192, 32, 64, True, 8, id="gqa-b2s8192h32kv8d64-causal"),
     # ragged sequence, unaligned head dim, block_q clamped below the lane
-    pytest.param(1, 200, 2, 64, True, id="ragged-s200"),
-    pytest.param(1, 40, 2, 64, False, id="short-s40"),
+    pytest.param(1, 200, 2, 64, True, 2, id="ragged-s200"),
+    pytest.param(1, 40, 2, 64, False, 2, id="short-s40"),
 ]
 
 
-@pytest.mark.parametrize("b,s,h,d,causal", FLASH_SHAPES)
-def test_flash_forward_lowers(b, s, h, d, causal):
-    q = S((b, s, h, d), jnp.bfloat16)
+def _qkv(b, s, h, d, kv):
+    return (S((b, s, h, d), jnp.bfloat16),) \
+        + (S((b, s, kv, d), jnp.bfloat16),) * 2
+
+
+@pytest.mark.parametrize("b,s,h,d,causal,kv", FLASH_SHAPES)
+def test_flash_forward_lowers(b, s, h, d, causal, kv):
     lowers_for_tpu(lambda q, k, v: fa.flash_attention(q, k, v, causal),
-                   q, q, q)
+                   *_qkv(b, s, h, d, kv))
 
 
-@pytest.mark.parametrize("b,s,h,d,causal", FLASH_SHAPES)
-def test_flash_grad_lowers(b, s, h, d, causal):
-    q = S((b, s, h, d), jnp.bfloat16)
+@pytest.mark.parametrize("b,s,h,d,causal,kv", FLASH_SHAPES)
+def test_flash_grad_lowers(b, s, h, d, causal, kv):
     lowers_for_tpu(
         jax.grad(lambda q, k, v: _sq(fa.flash_attention(q, k, v, causal)),
-                 argnums=(0, 1, 2)), q, q, q)
+                 argnums=(0, 1, 2)), *_qkv(b, s, h, d, kv))
 
 
-@pytest.mark.parametrize("b,s,h,d,causal", FLASH_SHAPES[:2])
-def test_flash_with_lse_grad_lowers(b, s, h, d, causal):
+@pytest.mark.parametrize("b,s,h,d,causal,kv", FLASH_SHAPES[:4])
+def test_flash_with_lse_grad_lowers(b, s, h, d, causal, kv):
     """Both outputs carry a cotangent — what ring attention differentiates."""
-    q = S((b, s, h, d), jnp.bfloat16)
 
     def loss(q, k, v):
         out, lse = fa.flash_attention_with_lse(q, k, v, causal)
         return _sq(out) + lse.sum()
 
-    lowers_for_tpu(jax.grad(loss, argnums=(0, 1, 2)), q, q, q)
+    lowers_for_tpu(jax.grad(loss, argnums=(0, 1, 2)), *_qkv(b, s, h, d, kv))
 
 
 def test_held_experts_grouped_products_lower_at_the_published_widths():
