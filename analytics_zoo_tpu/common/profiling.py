@@ -420,11 +420,15 @@ _TPU_KERNEL_BODY = re.compile(
 #: a line: the one place where an instruction of the optimized text does
 #: not end on the line it began
 _KERNEL_METADATA = re.compile(r"kernel_metadata=\{[^{}]*\}")
-#: label of ``zoo_step_kernel_calls`` → the kernel function
-#: (``ops/flash_attention.py``)
-KERNEL_FUNCTIONS = {"flash_fwd": b"_flash_fwd_kernel",
-                    "flash_bwd_dq": b"_flash_bwd_dq_kernel",
-                    "flash_bwd_dkv": b"_flash_bwd_dkv_kernel"}
+#: label of ``zoo_step_kernel_calls`` → the kernel function: the flash
+#: attention kernels (``ops/flash_attention.py``) ...
+FLASH_KERNELS = {"flash_fwd": b"_flash_fwd_kernel",
+                 "flash_bwd_dq": b"_flash_bwd_dq_kernel",
+                 "flash_bwd_dkv": b"_flash_bwd_dkv_kernel"}
+#: ... and the q/k norm-and-rotary kernels (``ops/norm_rotary.py``)
+KERNEL_FUNCTIONS = {**FLASH_KERNELS,
+                    "norm_rotary_fwd": b"_norm_rotary_fwd_kernel",
+                    "norm_rotary_bwd": b"_norm_rotary_bwd_kernel"}
 #: label ``kind`` of ``zoo_flash_grid_steps``: the kinds of tile a flash
 #: kernel's launch lists (``flash_attention.TILE_KINDS``)
 TILE_KINDS = ("interior", "diagonal", "dead")
@@ -436,11 +440,11 @@ def _one_line_each(hlo_text: str) -> str:
         lambda m: m.group(0).replace("\n", ""), hlo_text)
 
 
-def _flash_launches(hlo_text: str):
-    """``(label, metadata)`` of every custom call of a flash attention
-    kernel in the optimized HLO of a TPU executable: the kernel's label
-    in ``KERNEL_FUNCTIONS`` and the launch's ``kernel_metadata`` as a
-    dict of strings."""
+def _kernel_launches(hlo_text: str):
+    """``(label, metadata)`` of every custom call of a kernel in
+    ``KERNEL_FUNCTIONS`` in the optimized HLO of a TPU executable: the
+    kernel's label and the launch's ``kernel_metadata`` as a dict of
+    strings (empty for a launch that gave none)."""
     for metadata, body in _TPU_KERNEL_BODY.findall(_one_line_each(hlo_text)):
         try:
             module = base64.b64decode(body)
@@ -452,15 +456,18 @@ def _flash_launches(hlo_text: str):
 
 
 def count_kernel_calls(hlo_text: str) -> Dict[str, int]:
-    """``{"flash_fwd": n, "flash_bwd_dq": n, "flash_bwd_dkv": n}``: the
-    custom calls of each flash attention kernel in the optimized HLO of a
-    TPU executable. A training step reads 1, 1, 1 per attention layer
-    that takes the kernels; 2, 1, 1 where a ``jax.checkpoint`` around the
-    layer does not keep ``flash_attention.RESIDUAL_NAMES`` and the
-    backward pass launches the forward kernel again. All 0 off the TPU
-    (the interpreter inlines a kernel) and where attention is XLA's."""
+    """``{"flash_fwd": n, "flash_bwd_dq": n, "flash_bwd_dkv": n,
+    "norm_rotary_fwd": n, "norm_rotary_bwd": n}``: the custom calls of
+    each kernel in the optimized HLO of a TPU executable. A training step
+    reads flash 1, 1, 1 per attention layer that takes the kernels; 2, 1,
+    1 where a ``jax.checkpoint`` around the layer does not keep
+    ``flash_attention.RESIDUAL_NAMES`` and the backward pass launches the
+    forward kernel again. Norm-and-rotary 4, 2 per rematerialised layer
+    that runs it (q and k, forward and recomputed; their backward). All 0
+    off the TPU (the interpreter inlines a kernel) and where the work is
+    XLA's."""
     counts = dict.fromkeys(KERNEL_FUNCTIONS, 0)
-    for kernel, _ in _flash_launches(hlo_text):
+    for kernel, _ in _kernel_launches(hlo_text):
         counts[kernel] += 1
     return counts
 
@@ -475,7 +482,7 @@ def count_flash_grid_steps(hlo_text: str) -> Dict[str, int]:
     all. No key for a kernel the program does not launch: an executable
     without the kernels gives ``{}``."""
     counts: Dict[str, int] = {}
-    for kernel, metadata in _flash_launches(hlo_text):
+    for kernel, metadata in _kernel_launches(hlo_text):
         for kind in TILE_KINDS:
             if metadata.get(kind, "").isdigit():
                 key = f"{kernel}/{kind}"
@@ -496,7 +503,7 @@ def count_flash_layouts(hlo_text: str) -> Dict[str, int]:
     program compiled before the keys were written) is not counted; an
     executable without the kernels gives ``{}``."""
     counts: Dict[str, int] = {}
-    for kernel, metadata in _flash_launches(hlo_text):
+    for kernel, metadata in _kernel_launches(hlo_text):
         layout, kv = metadata.get("layout"), metadata.get("kv")
         if layout and kv:
             key = f"{kernel}@{layout},{kv}"
@@ -557,7 +564,8 @@ def _publish_counts(name: str, counts: Dict[str, int]) -> None:
 
 def step_counts(name: str) -> Optional[Dict[str, int]]:
     """``{"held_values", "erfc", "mask", "flash_fwd", "flash_bwd_dq",
-    "flash_bwd_dkv"}`` of the executable last compiled ahead of time under
+    "flash_bwd_dkv", "norm_rotary_fwd", "norm_rotary_bwd"}`` of the
+    executable last compiled ahead of time under
     ``name``, and ``"<kernel>/<kind>"`` and ``"<kernel>@<layout>,<kv>"``
     for each flash kernel it launches: the optimization barriers of the
     program as lowered (left out where the lowered program was not at

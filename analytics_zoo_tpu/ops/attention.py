@@ -22,6 +22,7 @@ import jax
 import jax.numpy as jnp
 from flax.linen.dtypes import promote_dtype
 
+from analytics_zoo_tpu.ops import norm_rotary
 from analytics_zoo_tpu.ops.hold import Dropout, hold_both_ways
 
 
@@ -205,13 +206,9 @@ def rotary_embedding(x, theta: float, positions=None):
     angles in float32: ``x * cos + rotate_half(x) * sin``. ``positions``
     [seq]: each row's position (default 0..seq-1; they may repeat)."""
     d = x.shape[-1]
-    inv_freq = 1.0 / (theta ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d))
-    if positions is None:
-        positions = jnp.arange(x.shape[1], dtype=jnp.float32)
-    angles = jnp.asarray(positions, jnp.float32)[:, None] \
-        * inv_freq[None, :]
-    cos = jnp.concatenate([jnp.cos(angles)] * 2, -1)[None, :, None, :]
-    sin = jnp.concatenate([jnp.sin(angles)] * 2, -1)[None, :, None, :]
+    table = norm_rotary.rotary_table(x.shape[1], d, theta, positions)
+    cos = jnp.concatenate([table[:, :d // 2]] * 2, -1)[None, :, None, :]
+    sin = jnp.concatenate([table[:, d // 2:]] * 2, -1)[None, :, None, :]
     xf = x.astype(jnp.float32)
     rotated = jnp.concatenate([-xf[..., d // 2:], xf[..., :d // 2]], -1)
     return (xf * cos + rotated * sin).astype(x.dtype)
@@ -226,12 +223,31 @@ def grouped_query_attention(q, k, v, mask=None):
     return dot_product_attention(q, k, v, mask=mask, causal=mask is None)
 
 
+class _NormScale(nn.Module):
+    """An RMSNorm's ``scale`` [features] as ``nn.RMSNorm`` makes it (ones,
+    float32), computing nothing: the parameter of a norm that
+    ``ops/norm_rotary.py`` applies, under the norm's own name."""
+
+    features: int
+
+    @nn.compact
+    def __call__(self):
+        return self.param("scale", nn.initializers.ones, (self.features,),
+                          jnp.float32)
+
+
 class GroupedQueryAttention(nn.Module):
     """Self-attention with grouped-query heads, an RMSNorm over each
     head of q and of k, rotary positions and no bias: projections ``q``,
     ``k``, ``v``, ``out``; norms ``q_norm``, ``k_norm``. Causal over
     positions 0..seq-1, or under a static ``mask``
-    (``flash_attention.TileMask``) at the ``positions`` [seq] given."""
+    (``flash_attention.TileMask``) at the ``positions`` [seq] given.
+
+    Where ``norm_rotary.engages`` (a TPU, a head of whole lanes: the
+    flash kernels' ``rows`` layout) each norm and its rotation are one
+    kernel each way on the projection's ``[batch, seq, heads·d]`` rows;
+    elsewhere they are XLA's ``nn.RMSNorm`` and ``rotary_embedding``. The
+    parameters are the same either way."""
 
     num_heads: int
     num_kv_heads: int
@@ -246,22 +262,36 @@ class GroupedQueryAttention(nn.Module):
         b, s, hidden = x.shape
         h, g, d = self.num_heads, self.num_kv_heads, self.head_dim
 
+        rows = norm_rotary.engages(d)
+
         def proj(name, heads):
             y = nn.Dense(heads * d, use_bias=False, dtype=self.dtype,
                          kernel_init=self.kernel_init, name=name)(x)
-            return y.reshape(b, s, heads, d)
+            return y if rows else y.reshape(b, s, heads, d)
 
         q, k, v = proj("q", h), proj("k", g), proj("v", g)
-        q = nn.RMSNorm(epsilon=self.norm_eps, dtype=self.dtype,
-                       name="q_norm")(q)
-        k = nn.RMSNorm(epsilon=self.norm_eps, dtype=self.dtype,
-                       name="k_norm")(k)
-        q = rotary_embedding(q, self.rope_theta, positions)
-        k = rotary_embedding(k, self.rope_theta, positions)
-        # q and k come out of the norms and rotations in (heads, d) tiles
-        # and the kernels read [batch, seq, heads·d] rows; v and the
-        # kernels' output meet products on both sides and cross nothing
-        q, k = hold_both_ways(q), hold_both_ways(k)
+        if rows:
+            # on the projections' own [batch, seq, heads·d] rows, which
+            # the kernels read as they are: no float32 copy of q or k and
+            # no relayout between (heads, d) tiles and rows
+            table = norm_rotary.rotary_table(s, d, self.rope_theta,
+                                             positions)
+            q = norm_rotary.norm_rotary(
+                q, _NormScale(d, name="q_norm")(), table, h, self.norm_eps)
+            k = norm_rotary.norm_rotary(
+                k, _NormScale(d, name="k_norm")(), table, g, self.norm_eps)
+            q, k, v = (a.reshape(b, s, -1, d) for a in (q, k, v))
+        else:
+            q = nn.RMSNorm(epsilon=self.norm_eps, dtype=self.dtype,
+                           name="q_norm")(q)
+            k = nn.RMSNorm(epsilon=self.norm_eps, dtype=self.dtype,
+                           name="k_norm")(k)
+            q = rotary_embedding(q, self.rope_theta, positions)
+            k = rotary_embedding(k, self.rope_theta, positions)
+            # held at the kernels' door, value and cotangent: a relayout
+            # the kernels need is made once, on the rounded value, not
+            # hoisted onto the chain's float32 intermediates (ops/hold.py)
+            q, k = hold_both_ways(q), hold_both_ways(k)
         out = grouped_query_attention(q, k, v, mask)
         return nn.Dense(hidden, use_bias=False, dtype=self.dtype,
                         kernel_init=self.kernel_init,

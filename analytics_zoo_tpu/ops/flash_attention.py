@@ -599,7 +599,7 @@ def _div(i, n: int):
 class _Operands:
     """How the launches of one call find a head's blocks: the layout of
     their operands and the index maps over them. One rule picks the
-    layout, from the head's width alone:
+    layout, from the head's width alone (``rows_layout``):
 
     - ``rows`` (``head_dim % 128 == 0``): q, dO, out, dq stay ``[batch,
       seq, heads·d]`` as the projections write them, k, v, dk, dv
@@ -672,6 +672,15 @@ class _Operands:
         return self._heads_of(i, step, refs)[0], 0, refs[0][step]
 
 
+def rows_layout(head_dim: int) -> bool:
+    """The one rule for whether a head's data stays in ``[batch, seq,
+    heads·head_dim]`` rows: a head of whole lanes. The kernels' operands
+    take the ``rows`` layout by it (``_Operands``), and the q/k norm and
+    rotary positions run as one kernel on such rows by it
+    (``ops/norm_rotary.py``)."""
+    return head_dim % LANE == 0
+
+
 def _operands(q, k) -> _Operands:
     """The layout and grouping of a call over ``q`` [b, sq, h, d] and
     ``k`` [b, sk, kv_heads, d]."""
@@ -679,7 +688,7 @@ def _operands(q, k) -> _Operands:
     if h % kv_heads:
         raise ValueError(f"{h} query heads are no multiple of {kv_heads} "
                          "key/value heads")
-    return _Operands(h, h // kv_heads, q.shape[3] % LANE == 0)
+    return _Operands(h, h // kv_heads, rows_layout(q.shape[3]))
 
 
 def _flash_fwd(q, k, v, causal: bool, block_q: int, block_k: int,

@@ -426,6 +426,42 @@ def _flash_checks(table, b, s, h, d, causal, kv_heads=None):
         grad(kernel_lse), grad(reference_lse)), BF16_RTOL)
 
 
+def _norm_rotary_checks(table, b, s, heads, d=128):
+    """The q/k norm and rotary positions as kernels against the XLA chain
+    they replace, in float32 on the same bfloat16 rows, at positions that
+    repeat as block diffusion's do: value and gradient."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from analytics_zoo_tpu.ops import attention, norm_rotary
+
+    keys = jax.random.split(jax.random.PRNGKey(2), 3)
+    x = jax.random.normal(keys[0], (b, s, heads * d), jnp.bfloat16)
+    scale = 1 + 0.1 * jax.random.normal(keys[1], (d,))
+    cotangent = jax.random.normal(keys[2], x.shape, jnp.bfloat16)
+    positions = np.tile(np.arange(s // 2), 2)
+    rows = norm_rotary.rotary_table(s, d, 1e6, positions)
+
+    def kernel(x, scale):
+        return norm_rotary.norm_rotary(x, scale, rows, heads, 1e-6)
+
+    def reference(x, scale):
+        u = x.reshape(b, s, heads, d).astype(jnp.float32)
+        u = u * jax.lax.rsqrt(jnp.mean(u * u, -1, keepdims=True) + 1e-6)
+        return attention.rotary_embedding(u * scale, 1e6, positions) \
+            .reshape(x.shape)
+
+    def pulled(f):
+        return jax.jit(lambda x, scale: jax.vjp(f, x, scale)[1](
+            cotangent.astype(f(x, scale).dtype)))
+
+    tag = f"b{b}s{s}h{heads}d{d}"
+    _check(table, f"norm_rotary fwd {tag}", jax.jit(kernel)(x, scale),
+           jax.jit(reference)(x, scale), BF16_RTOL)
+    _check(table, f"norm_rotary grad {tag}", pulled(kernel)(x, scale),
+           pulled(reference)(x, scale), BF16_RTOL)
+
+
 def phase_kernels() -> list:
     import jax
     import jax.numpy as jnp
@@ -439,6 +475,7 @@ def phase_kernels() -> list:
     # both operand layouts with a key/value head read by its group
     _flash_checks(table, 2, 2048, 8, 128, causal=True, kv_heads=2)
     _flash_checks(table, 2, 2048, 8, 64, causal=True, kv_heads=2)
+    _norm_rotary_checks(table, 2, 2048, 8)
 
     def both_paths(fn, *args):
         """``fn(*args, use_kernel)`` jitted with the kernel pinned on, then

@@ -388,14 +388,14 @@ def test_v5e_launches_the_forward_kernel_once_where_its_residuals_are_kept(
         text = grad.lower(x, w).compile().as_text()
     assert profiling.count_kernel_calls(text) == {
         "flash_fwd": forward_launches, "flash_bwd_dq": 1,
-        "flash_bwd_dkv": 1}
+        "flash_bwd_dkv": 1, "norm_rotary_fwd": 0, "norm_rotary_bwd": 0}
     # each launch lists its live tiles alone: two heads of one interior
     # and two diagonal tiles at 2,048 positions and 1,024 x 1,024 blocks
     steps = profiling.count_flash_grid_steps(text)
     assert steps == {
         f"{kernel}/{kind}": n * (forward_launches
                                  if kernel == "flash_fwd" else 1)
-        for kernel in profiling.KERNEL_FUNCTIONS
+        for kernel in profiling.FLASH_KERNELS
         for kind, n in (("interior", 2), ("diagonal", 4), ("dead", 0))}
 
 
@@ -431,13 +431,14 @@ def test_v5e_flash_launches_take_no_step_for_a_dead_tile(one_chip, sq, sk,
     with compiled_outside_the_cache():
         text = grad.lower(q, k).compile().as_text()
     launched = want is not None
-    assert profiling.count_kernel_calls(text) == dict.fromkeys(
-        profiling.KERNEL_FUNCTIONS, int(launched))
+    assert profiling.count_kernel_calls(text) == {
+        **dict.fromkeys(profiling.KERNEL_FUNCTIONS, 0),
+        **dict.fromkeys(profiling.FLASH_KERNELS, int(launched))}
     # a kept dead step belongs to a query block: the key blocks' kernel,
     # every one of whose blocks some query sees, has none
     assert profiling.count_flash_grid_steps(text) == ({
         f"{kernel}/{kind}": n * (kernel != "flash_bwd_dkv" or kind != "dead")
-        for kernel in profiling.KERNEL_FUNCTIONS
+        for kernel in profiling.FLASH_KERNELS
         for kind, n in zip(profiling.TILE_KINDS, want)} if launched else {})
 
 
@@ -465,11 +466,12 @@ def test_v5e_compiles_the_three_kernels_under_the_block_diffusion_mask(
         argnums=(0, 1, 2)))
     with compiled_outside_the_cache():
         text = grad.lower(q, q, q).compile().as_text()
-    assert profiling.count_kernel_calls(text) == dict.fromkeys(
-        profiling.KERNEL_FUNCTIONS, 1)
+    assert profiling.count_kernel_calls(text) == {
+        **dict.fromkeys(profiling.KERNEL_FUNCTIONS, 0),
+        **dict.fromkeys(profiling.FLASH_KERNELS, 1)}
     assert profiling.count_flash_grid_steps(text) == {
         f"{kernel}/{kind}": n * 4
-        for kernel in profiling.KERNEL_FUNCTIONS
+        for kernel in profiling.FLASH_KERNELS
         for kind, n in (("interior", 2), ("diagonal", 6), ("dead", 0))}
 
 
@@ -510,15 +512,96 @@ def test_v5e_compiles_the_decoder_cells_grouped_launches(
     text = compiled.as_text()
     dq, dk, dv = jax.eval_shape(grad, q, k, k)
     assert dq.shape == shape and dk.shape == dv.shape == k.shape
-    assert profiling.count_kernel_calls(text) == dict.fromkeys(
-        profiling.KERNEL_FUNCTIONS, 1)
+    assert profiling.count_kernel_calls(text) == {
+        **dict.fromkeys(profiling.KERNEL_FUNCTIONS, 0),
+        **dict.fromkeys(profiling.FLASH_KERNELS, 1)}
     assert profiling.count_flash_layouts(text) == {
         f"{kernel}@{layout},grouped": 1
-        for kernel in profiling.KERNEL_FUNCTIONS}
+        for kernel in profiling.FLASH_KERNELS}
     assert profiling.count_flash_grid_steps(text) == {
         f"{kernel}/{kind}": n * b * h
-        for kernel in profiling.KERNEL_FUNCTIONS
+        for kernel in profiling.FLASH_KERNELS
         for kind, n in zip(profiling.TILE_KINDS, tiles)}
+
+
+@pytest.mark.parametrize("heads", [32, 4, 1], ids=["q", "k", "one_head"])
+def test_v5e_compiles_the_norm_rotary_kernels_at_the_cells_size(one_chip,
+                                                                heads):
+    """The q/k norm and rotary positions of the block-diffusion cell, q at
+    32 heads of 128 and k at 4, over 16,384 rows, forward and backward
+    compiled for the chip at the blocks the rows are given by default:
+    they fit the kernels' VMEM, and each is one launch. One head a row is
+    the narrowest: its 2 MiB would be 8,192 rows, which ``MAX_ROWS``
+    keeps to 512 (at 8,192 the compile runs out of VMEM)."""
+    from analytics_zoo_tpu.common import profiling
+    from analytics_zoo_tpu.ops import norm_rotary
+
+    x = jax.ShapeDtypeStruct((1, 16384, heads * 128), jnp.bfloat16,
+                             sharding=one_chip)
+    scale = jax.ShapeDtypeStruct((128,), jnp.float32, sharding=one_chip)
+    table = jax.ShapeDtypeStruct((16384, 128), jnp.float32,
+                                 sharding=one_chip)
+    grad = jax.jit(jax.grad(
+        lambda x, scale, table: (norm_rotary.norm_rotary(
+            x, scale, table, heads, 1e-6).astype(jnp.float32) ** 2).sum(),
+        argnums=(0, 1)))
+    with compiled_outside_the_cache():
+        text = grad.lower(x, scale, table).compile().as_text()
+    assert profiling.count_kernel_calls(text) == {
+        **dict.fromkeys(profiling.KERNEL_FUNCTIONS, 0),
+        "norm_rotary_fwd": 1, "norm_rotary_bwd": 1}
+
+
+def test_v5e_the_layer_hands_q_and_k_to_the_kernels_as_rows(one_chip,
+                                                            monkeypatch):
+    """One ``GroupedQueryAttention`` at the block-diffusion cell's widths
+    (hidden 2,048, 32 / 4 heads of 128, repeated positions) over 4,096
+    rows, its gradient under the decoder's policy compiled for the chip:
+    q and k go from the projections through the norm-rotary kernels to
+    the flash kernels with no copy, reshape or transpose of q, k, dq or
+    dk on either side, and no float32 copy of them at all — the
+    ``[b, s, h, d]`` view is no instruction. Four forward launches (q and
+    k, forward and recomputed) and two backward."""
+    from analytics_zoo_tpu.common import profiling
+    from analytics_zoo_tpu.ops import autotune
+    from analytics_zoo_tpu.ops import flash_attention as fa
+    from analytics_zoo_tpu.text import hybrid_decoder
+
+    s, h, g, d, hidden = 4096, 32, 4, 128, 2048
+    mask = fa.BlockDiffusionMask(s // 2, 4)
+    positions = np.tile(np.arange(s // 2, dtype=np.int32), 2)
+
+    class Block(nn.Module):
+        @nn.compact
+        def __call__(self, x):
+            return attention_lib.GroupedQueryAttention(
+                h, g, d, 1e6, 1e-6, dtype=jnp.bfloat16,
+                name="attention")(x, positions, mask)
+
+    layer = nn.remat(Block, policy=hybrid_decoder._BLOCK_POLICY)()
+    x = jax.ShapeDtypeStruct((1, s, hidden), jnp.bfloat16, sharding=one_chip)
+    params = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        jax.eval_shape(lambda: layer.init(
+            jax.random.PRNGKey(0), jnp.zeros((1, s, hidden), jnp.bfloat16))))
+    monkeypatch.setattr(fa, "on_tpu", lambda: True)
+    monkeypatch.setattr(autotune, "on_tpu", lambda: True)
+    monkeypatch.setattr(autotune, "attention_decision", lambda *a: None)
+    monkeypatch.setattr(autotune, "SCORES_SWITCH", 0)
+    grad = jax.jit(jax.grad(
+        lambda p, x: (layer.apply(p, x).astype(jnp.float32) ** 2).sum(),
+        argnums=(0, 1)))
+    with compiled_outside_the_cache():
+        text = grad.lower(params, x).compile().as_text()
+    assert profiling.count_kernel_calls(text) == {
+        "flash_fwd": 1, "flash_bwd_dq": 1, "flash_bwd_dkv": 1,
+        "norm_rotary_fwd": 4, "norm_rotary_bwd": 2}
+    shapes = "|".join(re.escape(f"[1,{s},{w}]") for w in (h * d, g * d)) \
+        + "|" + "|".join(re.escape(f"[1,{s},{n},{d}]") for n in (h, g))
+    moved = re.findall(rf"= bf16(?:{shapes})\S* (copy|reshape|transpose)\(",
+                       text)
+    assert not moved
+    assert not re.findall(rf"= f32(?:{shapes})", text)
 
 
 @pytest.mark.parametrize("head_dim,dtype", [

@@ -533,18 +533,24 @@ def test_outside_differentiation_nothing_is_named(monkeypatch):
 @pytest.mark.parametrize("head_dim,layout", [(128, "rows"), (64, "heads")])
 def test_the_kernel_path_copies_no_heads_data(monkeypatch, head_dim, layout):
     """The jaxpr of ``GroupedQueryAttention``'s gradient where the kernels
-    run (a TPU, no verdict, scores beyond the switch): three launches that
-    say ``grouped`` and the layout the head's width picks; k and v are
-    never repeated to the query heads (no ``broadcast_in_dim`` to
-    ``[b, s, kv_heads, groups, d]``), and at a head of whole lanes no
+    run (a TPU, no verdict, scores beyond the switch): three flash
+    launches that say ``grouped`` and the layout the head's width picks;
+    k and v are never repeated to the query heads (no ``broadcast_in_dim``
+    to ``[b, s, kv_heads, groups, d]``), and at a head of whole lanes no
     ``[b, s, h, d]`` operand is transposed head-major on either side of a
-    launch. Off the kernel path the same layer repeats them, once."""
+    launch. There the q/k norm and rotary positions are the norm-rotary
+    kernels, a forward launch for q and one for k and a backward each, and
+    nothing computes on q or k as ``[b, s, heads, d]``: the four-dimensional
+    view is reshapes alone. At 64 lanes the XLA chain stays and no
+    norm-rotary kernel is launched. Off the kernel path the same layer
+    repeats k and v, once."""
     from analytics_zoo_tpu.ops import autotune, flash_attention
     b, s, h, kv, hidden = 1, 256, 4, 2, 32
     layer = attention_lib.GroupedQueryAttention(h, kv, head_dim)
     x = jnp.asarray(np.random.default_rng(0).normal(size=(b, s, hidden)),
                     jnp.float32)
     params = layer.init(jax.random.PRNGKey(0), x)
+    heads_of_q_or_k = {(b, s, h, head_dim), (b, s, kv, head_dim)}
 
     def seen():
         def grad(params, x):          # traced anew: jax keeps a jaxpr by
@@ -552,26 +558,43 @@ def test_the_kernel_path_copies_no_heads_data(monkeypatch, head_dim, layout):
                 lambda p, x: layer.apply(p, x).sum(),
                 argnums=(0, 1))(params, x)
         found = list(_eqns(jax.make_jaxpr(grad)(params, x).jaxpr))
-        return ([dict(e.params["metadata"]) for e in found
-                 if e.primitive.name == "pallas_call"],
+        kernels = [(e.params["jaxpr"].debug_info.func_name,
+                    e.params["metadata"]) for e in found
+                   if e.primitive.name == "pallas_call"]
+        return ([dict(said) for name, said in kernels
+                 if name.startswith("_flash_")],
+                sorted(name for name, _ in kernels
+                       if name.startswith("_norm_rotary_")),
                 [e for e in found if e.primitive.name == "broadcast_in_dim"
                  and e.outvars[0].aval.shape
                  == (b, s, kv, h // kv, head_dim)],
                 [e for e in found if e.primitive.name == "transpose"
-                 and len(e.invars[0].aval.shape) == 4])
+                 and len(e.invars[0].aval.shape) == 4],
+                {e.primitive.name for e in found
+                 if e.primitive.name not in ("reshape", "name")
+                 and any(getattr(o.aval, "shape", None) in heads_of_q_or_k
+                         for o in e.outvars)})
 
-    launches, repeats, transposes = seen()
-    assert not launches and len(repeats) == 2          # the dense path
+    launches, norm_rotary, repeats, transposes, on_heads = seen()
+    assert not launches and not norm_rotary and len(repeats) == 2
+    assert {"mul", "concatenate"} <= on_heads            # the chain
     monkeypatch.setattr(flash_attention, "on_tpu", lambda: True)
     monkeypatch.setattr(autotune, "on_tpu", lambda: True)
     monkeypatch.setattr(autotune, "attention_decision", lambda *a: None)
     monkeypatch.setattr(autotune, "SCORES_SWITCH", 0)
-    launches, repeats, transposes = seen()
+    launches, norm_rotary, repeats, transposes, on_heads = seen()
     assert len(launches) == 3
     assert all(said["layout"] == layout and said["kv"] == "grouped"
                for said in launches)
     assert not repeats
     assert bool(transposes) == (layout == "heads")
+    if layout == "rows":
+        assert norm_rotary == ["_norm_rotary_bwd_kernel"] * 2 \
+            + ["_norm_rotary_fwd_kernel"] * 2
+        assert not on_heads
+    else:
+        assert not norm_rotary
+        assert {"mul", "concatenate", "optimization_barrier"} <= on_heads
 
 
 # ------------------------------------------------ tracing and counters

@@ -261,9 +261,11 @@ REMAT_LAYER = os.path.join(os.path.dirname(__file__), "fixtures",
 
 
 @pytest.mark.parametrize("fixture,want", [
-    (REMAT_LAYER, {"flash_fwd": 2, "flash_bwd_dq": 1, "flash_bwd_dkv": 1}),
+    (REMAT_LAYER, {"flash_fwd": 2, "flash_bwd_dq": 1, "flash_bwd_dkv": 1,
+                   "norm_rotary_fwd": 0, "norm_rotary_bwd": 0}),
     (PARENT_FUSIONS, {"flash_fwd": 0, "flash_bwd_dq": 0,
-                      "flash_bwd_dkv": 0})],
+                      "flash_bwd_dkv": 0, "norm_rotary_fwd": 0,
+                      "norm_rotary_bwd": 0})],
     ids=["a_layer_that_keeps_products_alone", "no_kernel"])
 def test_kernel_calls_counted_and_published(fixture, want):
     """The entry computation of ``tanh(x @ w) -> flash_attention -> @ w``
@@ -292,7 +294,7 @@ LIVE_TILES_LAYER = os.path.join(
 
 @pytest.mark.parametrize("fixture,want", [
     (LIVE_TILES_LAYER, {f"{kernel}/{kind}": n
-                        for kernel in profiling.KERNEL_FUNCTIONS
+                        for kernel in profiling.FLASH_KERNELS
                         for kind, n in (("interior", 2), ("diagonal", 4),
                                         ("dead", 0))}),
     (REMAT_LAYER, {}), (PARENT_FUSIONS, {})],
@@ -357,7 +359,8 @@ def test_flash_layouts_counted_and_published(said, want):
     assert profiling.count_flash_layouts(text) == want
     assert profiling.count_flash_grid_steps(text) == steps
     assert profiling.count_kernel_calls(text) == {
-        "flash_fwd": 1, "flash_bwd_dq": 1, "flash_bwd_dkv": 1}
+        "flash_fwd": 1, "flash_bwd_dq": 1, "flash_bwd_dkv": 1,
+        "norm_rotary_fwd": 0, "norm_rotary_bwd": 0}
     profiling.note_executable("canned", _CannedExe(text))
     counts = profiling.step_counts("canned")
     assert {k: n for k, n in counts.items() if "@" in k} == want
@@ -376,7 +379,8 @@ def test_an_instruction_printed_over_several_lines_keeps_its_scope():
         text = fh.read()
     assert 'kernel_metadata={\n"dead":"0"' in text
     assert profiling.count_kernel_calls(text) == {
-        "flash_fwd": 1, "flash_bwd_dq": 1, "flash_bwd_dkv": 1}
+        "flash_fwd": 1, "flash_bwd_dq": 1, "flash_bwd_dkv": 1,
+        "norm_rotary_fwd": 0, "norm_rotary_bwd": 0}
     kernels = {name: (e["scope"], e["phase"])
                for name, e in profiling.parse_scope_index(text).items()
                if e["opcode"] == "custom-call"}
@@ -386,11 +390,67 @@ def test_an_instruction_printed_over_several_lines_keeps_its_scope():
         ("checkpoint/block_1/attention", "backward")]
 
 
-def test_the_counted_kernel_functions_are_flash_attentions():
-    from analytics_zoo_tpu.ops import flash_attention
-    for function in profiling.KERNEL_FUNCTIONS.values():
+def test_the_counted_kernel_functions_are_the_packages_kernels():
+    from analytics_zoo_tpu.ops import flash_attention, norm_rotary
+    for function in profiling.FLASH_KERNELS.values():
         assert callable(getattr(flash_attention, function.decode()))
+    others = set(profiling.KERNEL_FUNCTIONS) - set(profiling.FLASH_KERNELS)
+    assert others == {"norm_rotary_fwd", "norm_rotary_bwd"}
+    for kernel in others:
+        assert callable(getattr(
+            norm_rotary, profiling.KERNEL_FUNCTIONS[kernel].decode()))
     assert profiling.TILE_KINDS == flash_attention.TILE_KINDS
+
+
+def _kernel_call(name, body: bytes, metadata=""):
+    """One custom call of the TPU compiler's optimized HLO whose kernel
+    body is ``body`` (base64 as the compiler writes it)."""
+    import base64
+    said = f", frontend_attributes={{kernel_metadata={{{metadata}}}}}" \
+        if metadata else ""
+    return (f"  %{name} = bf16[1,256,512]{{2,1,0}} custom-call(bf16[1,256,"
+            f"512]{{2,1,0}} %p), custom_call_target=\"tpu_custom_call\"{said},"
+            ' metadata={op_name="jit(step)/block_1/attention/pallas_call"}, '
+            'backend_config={"custom_call_config":{"body":"'
+            + base64.b64encode(body).decode() + '"}}\n')
+
+
+def _norm_rotary_step(fwd: bytes, bwd: bytes) -> str:
+    """A step's entry computation with four launches of a kernel whose
+    body names ``fwd``, two of ``bwd`` and one flash forward launch."""
+    return ("ENTRY %main (p: bf16[1,256,512]) -> bf16[1,256,512] {\n"
+            "  %p = bf16[1,256,512]{2,1,0} parameter(0)\n"
+            + "".join(_kernel_call(f"attention.{i}", b"MLIR\x00" + fwd)
+                      for i in range(4))
+            + "".join(_kernel_call(f"attention.{i}", b"MLIR\x00" + bwd)
+                      for i in (4, 5))
+            + _kernel_call("attention.6", b"MLIR\x00_flash_fwd_kernel\x00",
+                           '\n"dead":"0",\n"diagonal":"4",\n"interior":"2",'
+                           '\n"kv":"grouped",\n"layout":"rows"\n')
+            + "}\n")
+
+
+def test_norm_rotary_launches_are_counted_beside_the_flash_kernels():
+    """A step whose attention layer runs the q/k norm-and-rotary kernels:
+    each launch is told by its kernel function's name inside the body and
+    counted under its own label; it lists no tiles and says no layout, so
+    the flash counters read the flash launches alone."""
+    text = _norm_rotary_step(b"_norm_rotary_fwd_kernel\x00",
+                             b"_norm_rotary_bwd_kernel\x00")
+    want = {"flash_fwd": 1, "flash_bwd_dq": 0, "flash_bwd_dkv": 0,
+            "norm_rotary_fwd": 4, "norm_rotary_bwd": 2}
+    assert profiling.count_kernel_calls(text) == want
+    assert profiling.count_flash_grid_steps(text) == {
+        "flash_fwd/interior": 2, "flash_fwd/diagonal": 4, "flash_fwd/dead": 0}
+    assert profiling.count_flash_layouts(text) == {
+        "flash_fwd@rows,grouped": 1}
+    profiling.note_executable("canned", _CannedExe(text))
+    assert telemetry.snapshot()["zoo_step_kernel_calls"] == {
+        f"executable=canned,kernel={k}": n for k, n in want.items()}
+    # the function's name, not the launch's place or scope, tells them
+    assert profiling.count_kernel_calls(_norm_rotary_step(
+        b"_norm_rotary_fwd\x00", b"_rotary_bwd_kernel\x00")) == dict(
+            want, norm_rotary_fwd=0, norm_rotary_bwd=0)
 
 
 def test_fit_publishes_the_counts_where_metrics_are_scraped(orca_ctx):

@@ -17,6 +17,7 @@ from jax import export
 
 from analytics_zoo_tpu.ops import embedding_bag as eb
 from analytics_zoo_tpu.ops import flash_attention as fa
+from analytics_zoo_tpu.ops import norm_rotary as nr
 from analytics_zoo_tpu.ops import paged_attention as pa
 
 S = jax.ShapeDtypeStruct
@@ -112,6 +113,31 @@ def test_flash_small_block_q_is_widened_to_the_lane():
             q, k, v, False, 64, 64)), argnums=(0, 1, 2)), q, q, q)
 
 
+NORM_ROTARY_SHAPES = [
+    pytest.param(2, 2048, 8, id="b2s2048h8d128"),
+    # the block-diffusion cell's q and k over its 16,384 rows
+    pytest.param(1, 16384, 32, id="q-b1s16384h32d128"),
+    pytest.param(1, 16384, 4, id="k-b1s16384h4d128"),
+    # a last block that runs past the sequence
+    pytest.param(1, 200, 2, id="ragged-s200"),
+]
+
+
+@pytest.mark.parametrize("b,s,h", NORM_ROTARY_SHAPES)
+def test_norm_rotary_forward_and_grad_lower(monkeypatch, b, s, h):
+    if s == 200:                                 # blocks of 128 rows
+        monkeypatch.setattr(nr, "BLOCK_BYTES", 128 * h * 128 * 2)
+
+    def loss(x, scale, table):
+        return _sq(nr.norm_rotary(x, scale, table, h, 1e-6))
+
+    avals = (S((b, s, h * 128), jnp.bfloat16), S((128,), jnp.float32),
+             S((s, 128), jnp.float32))
+    lowers_for_tpu(lambda x, scale, table: nr.norm_rotary(
+        x, scale, table, h, 1e-6), *avals)
+    lowers_for_tpu(jax.grad(loss, argnums=(0, 1)), *avals)
+
+
 NCF_TABLES = (S((6041, 20), jnp.float32), S((3707, 20), jnp.float32))
 
 
@@ -182,7 +208,7 @@ def test_every_pallas_call_in_ops_is_covered():
             if n:
                 sites[name] = n
     assert sites == {"embedding_bag.py": 2, "flash_attention.py": 1,
-                     "paged_attention.py": 2}, sites
+                     "norm_rotary.py": 2, "paged_attention.py": 2}, sites
     # flash attention's one site is ``_tile_call``, which the forward,
     # ``dq`` and ``dk/dv`` launches go through
     with open(fa.__file__) as fh:
