@@ -67,7 +67,8 @@ __all__ = [
     "backend_state", "DUMP_DIR", "reset_for_tests",
     "note_executable", "scope_index", "parse_scope_index",
     "step_counts", "count_elementwise_evals", "count_kernel_calls",
-    "count_flash_grid_steps", "count_flash_layouts",
+    "count_flash_grid_steps", "count_flash_score_pairs",
+    "count_flash_layouts",
 ]
 
 logger = logging.getLogger(__name__)
@@ -324,11 +325,13 @@ class _Executable:
     estimator that built it are gone, and nothing that holds device
     memory."""
 
-    __slots__ = ("fn", "sig", "hlo_text", "flops", "counts", "index")
+    __slots__ = ("fn", "sig", "hlo_text", "launches", "flops", "counts",
+                 "index")
 
-    def __init__(self, fn, sig, hlo_text, flops, counts):
+    def __init__(self, fn, sig, hlo_text, launches, flops, counts):
         self.fn, self.sig = fn, sig
-        self.hlo_text, self.flops, self.counts = hlo_text, flops, counts
+        self.hlo_text, self.launches = hlo_text, launches
+        self.flops, self.counts = flops, counts
         self.index: Optional[Dict[str, dict]] = None
 
 
@@ -342,8 +345,10 @@ def note_executable(name: str, exe, fn=None, sig=None,
     compiled under ``name`` (the newest executable of a name wins), count
     what :func:`step_counts` answers — the held values in the text of
     ``lowered``, the ``jax.stages.Lowered`` that ``exe`` was compiled
-    from, the evaluations in the optimized text — and publish the counts
-    as gauges; returns the FLOP count. ``ExecutableCache`` calls this
+    from, the evaluations in the optimized text, the kernel launches found
+    in it once (:func:`_kernel_launches`, kept for :func:`scope_index`) —
+    and publish the counts as gauges; returns the FLOP count.
+    ``ExecutableCache`` calls this
     after every build. Reading the text of a whole train step takes most
     of a second, so a rebuild of the same jitted ``fn`` for the same
     signature — every ``fit`` call warms its step again — keeps what is
@@ -354,7 +359,7 @@ def note_executable(name: str, exe, fn=None, sig=None,
             and held.fn() is fn and held.sig == sig:
         _publish_counts(name, held.counts)
         return held.flops
-    text = flops = None
+    text = flops = launches = None
     counts: Dict[str, int] = {}
     try:
         text = exe.as_text()
@@ -362,10 +367,12 @@ def note_executable(name: str, exe, fn=None, sig=None,
         if isinstance(cost, (list, tuple)):
             cost = cost[0]
         flops = float(cost.get("flops", 0.0)) or None
+        launches = _kernel_launches(text)
         counts = {**count_elementwise_evals(text),
-                  **count_kernel_calls(text),
-                  **count_flash_grid_steps(text),
-                  **count_flash_layouts(text)}
+                  **count_kernel_calls(launches),
+                  **count_flash_grid_steps(launches),
+                  **count_flash_score_pairs(launches),
+                  **count_flash_layouts(launches)}
         if lowered is not None:
             counts["held_values"] = lowered.as_text().count(_BARRIER)
     except Exception:
@@ -375,7 +382,7 @@ def note_executable(name: str, exe, fn=None, sig=None,
         ref = weakref.ref(fn) if fn is not None else None
     except TypeError:        # a callable that takes no weak reference
         ref = None
-    rec = _Executable(ref, sig, text, flops, counts)
+    rec = _Executable(ref, sig, text, launches, flops, counts)
     with _executables_lock:
         _executables[name] = rec
     _publish_counts(name, counts)
@@ -416,6 +423,13 @@ def count_elementwise_evals(hlo_text: str) -> Dict[str, int]:
 _TPU_KERNEL_BODY = re.compile(
     r'custom_call_target="tpu_custom_call"'
     r'(?:.*?kernel_metadata=\{([^{}]*)\})?.*?"body":"([A-Za-z0-9+/=]*)"')
+#: the TPU compiler's grouped matrix product (``jax.lax.ragged_dot``): a
+#: kernel of the compiler's own, whose ``op_name`` is its bare name,
+#: ``ragged-dot-none``; ``ragged-dot-metadata`` beside it only finds where
+#: the groups start
+_RAGGED_DOT = re.compile(r'op_name="ragged-dot-(?!metadata")')
+#: the label the scope index gives the grouped matrix product's launches
+RAGGED_DOT = "ragged_dot"
 #: XLA prints a call's ``kernel_metadata``, where it is not empty, one key
 #: a line: the one place where an instruction of the optimized text does
 #: not end on the line it began
@@ -432,6 +446,9 @@ KERNEL_FUNCTIONS = {**FLASH_KERNELS,
 #: label ``kind`` of ``zoo_flash_grid_steps``: the kinds of tile a flash
 #: kernel's launch lists (``flash_attention.TILE_KINDS``)
 TILE_KINDS = ("interior", "diagonal", "dead")
+#: a flash launch's two counts of score pairs (``flash_attention.tile_pairs``)
+#: → label ``kind`` of ``zoo_flash_score_pairs``
+SCORE_PAIRS = {"pairs": "computed", "allowed": "allowed"}
 
 
 def _one_line_each(hlo_text: str) -> str:
@@ -440,59 +457,108 @@ def _one_line_each(hlo_text: str) -> str:
         lambda m: m.group(0).replace("\n", ""), hlo_text)
 
 
-def _kernel_launches(hlo_text: str):
-    """``(label, metadata)`` of every custom call of a kernel in
-    ``KERNEL_FUNCTIONS`` in the optimized HLO of a TPU executable: the
-    kernel's label and the launch's ``kernel_metadata`` as a dict of
-    strings (empty for a launch that gave none)."""
-    for metadata, body in _TPU_KERNEL_BODY.findall(_one_line_each(hlo_text)):
+#: one launch of a known kernel in an optimized HLO text: its instruction's
+#: name, its kernel's label and what it wrote into its call's
+#: ``kernel_metadata`` (strings; empty where it wrote nothing)
+Launch = Tuple[str, str, Dict[str, str]]
+
+
+def _kernel_launches(hlo_text: str) -> List[Launch]:
+    """Every custom call of a known kernel in the optimized HLO of a TPU
+    executable, in the text's order: a pallas kernel of
+    ``KERNEL_FUNCTIONS``, told by its function's name inside the call's
+    body, and the compiler's grouped matrix product (``RAGGED_DOT``), told
+    by its bare name."""
+    launches = []
+    for line in _one_line_each(hlo_text).splitlines():
+        if 'custom_call_target="tpu_custom_call"' not in line:
+            continue
+        instruction = _INSTRUCTION.match(line)
+        if instruction is None:
+            continue
+        name = instruction.group(2)
+        if _RAGGED_DOT.search(line):
+            launches.append((name, RAGGED_DOT, {}))
+            continue
+        found = _TPU_KERNEL_BODY.search(line)
         try:
-            module = base64.b64decode(body)
+            module = base64.b64decode(found.group(2)) if found else b""
         except binascii.Error:
             continue
         for kernel, function in KERNEL_FUNCTIONS.items():
             if function in module:
-                yield kernel, dict(re.findall(r'"(\w+)":"(\w*)"', metadata))
+                launches.append((name, kernel, dict(re.findall(
+                    r'"(\w+)":"(\w*)"', found.group(1) or ""))))
+                break
+    return launches
 
 
-def count_kernel_calls(hlo_text: str) -> Dict[str, int]:
+def _launches_of(hlo) -> List[Launch]:
+    """``hlo``, the launches :func:`_kernel_launches` found in an
+    optimized HLO text, or that text, whose launches are then found."""
+    return _kernel_launches(hlo) if isinstance(hlo, str) else hlo
+
+
+def count_kernel_calls(launches) -> Dict[str, int]:
     """``{"flash_fwd": n, "flash_bwd_dq": n, "flash_bwd_dkv": n,
     "norm_rotary_fwd": n, "norm_rotary_bwd": n}``: the custom calls of
-    each kernel in the optimized HLO of a TPU executable. A training step
-    reads flash 1, 1, 1 per attention layer that takes the kernels; 2, 1,
-    1 where a ``jax.checkpoint`` around the layer does not keep
+    each kernel in the optimized HLO of a TPU executable (``launches``:
+    what :func:`_kernel_launches` found in it, or the text). A training
+    step reads flash 1, 1, 1 per attention layer that takes the kernels;
+    2, 1, 1 where a ``jax.checkpoint`` around the layer does not keep
     ``flash_attention.RESIDUAL_NAMES`` and the backward pass launches the
     forward kernel again. Norm-and-rotary 4, 2 per rematerialised layer
     that runs it (q and k, forward and recomputed; their backward). All 0
     off the TPU (the interpreter inlines a kernel) and where the work is
     XLA's."""
     counts = dict.fromkeys(KERNEL_FUNCTIONS, 0)
-    for kernel, _ in _kernel_launches(hlo_text):
-        counts[kernel] += 1
+    for _, kernel, _ in _launches_of(launches):
+        if kernel in counts:
+            counts[kernel] += 1
     return counts
 
 
-def count_flash_grid_steps(hlo_text: str) -> Dict[str, int]:
+def _summed(launches, keys) -> Dict[str, int]:
+    """``{"<kernel>/<key>": n}``: each of ``keys`` that a launch wrote as
+    a number into its call's metadata, summed over a kernel's launches."""
+    counts: Dict[str, int] = {}
+    for _, kernel, metadata in _launches_of(launches):
+        for key in keys:
+            if metadata.get(key, "").isdigit():
+                label = f"{kernel}/{key}"
+                counts[label] = counts.get(label, 0) + int(metadata[key])
+    return counts
+
+
+def count_flash_grid_steps(launches) -> Dict[str, int]:
     """``{"<kernel>/<kind>": n}``: the grid steps the launches of each
-    flash attention kernel in the optimized HLO of a TPU executable take,
-    by kind of tile (``TILE_KINDS``), as the launch listed them ahead of
-    time (``flash_attention.tile_table``) and wrote them into its call's
+    flash attention kernel in the optimized HLO of a TPU executable take
+    (``launches`` as :func:`count_kernel_calls` takes them), by kind of
+    tile (``TILE_KINDS``), as the launch listed them ahead of time
+    (``flash_attention.tile_table``) and wrote them into its call's
     metadata; summed over the kernel's launches, all heads. A causal
     launch reads ``dead`` 0 unless it has query blocks that see no key at
     all. No key for a kernel the program does not launch: an executable
     without the kernels gives ``{}``."""
-    counts: Dict[str, int] = {}
-    for kernel, metadata in _kernel_launches(hlo_text):
-        for kind in TILE_KINDS:
-            if metadata.get(kind, "").isdigit():
-                key = f"{kernel}/{kind}"
-                counts[key] = counts.get(key, 0) + int(metadata[kind])
-    return counts
+    return _summed(launches, TILE_KINDS)
 
 
-def count_flash_layouts(hlo_text: str) -> Dict[str, int]:
+def count_flash_score_pairs(launches) -> Dict[str, int]:
+    """``{"<kernel>/pairs": n, "<kernel>/allowed": n}``: the score pairs
+    the live tiles of each flash attention kernel's launches compute, and
+    those of them that the mask and the true key length let through, as
+    each launch counted them ahead of time (``flash_attention.tile_pairs``)
+    and wrote them into its call's metadata; summed over the kernel's
+    launches, all heads (``launches`` as :func:`count_kernel_calls` takes
+    them). A launch that says neither (a program compiled before the keys
+    were written) and an executable without the kernels give ``{}``."""
+    return _summed(launches, SCORE_PAIRS)
+
+
+def count_flash_layouts(launches) -> Dict[str, int]:
     """``{"<kernel>@<layout>,<kv>": n}``: the launches of each flash
-    attention kernel in the optimized HLO of a TPU executable by how they
+    attention kernel in the optimized HLO of a TPU executable
+    (``launches`` as :func:`count_kernel_calls` takes them) by how they
     find a head's blocks, as each launch wrote it into its call's
     metadata — ``layout`` ``rows`` (operands ``[batch, seq, heads·d]``
     as the projections write them, the head named by the index maps) or
@@ -503,7 +569,7 @@ def count_flash_layouts(hlo_text: str) -> Dict[str, int]:
     program compiled before the keys were written) is not counted; an
     executable without the kernels gives ``{}``."""
     counts: Dict[str, int] = {}
-    for kernel, metadata in _kernel_launches(hlo_text):
+    for _, kernel, metadata in _launches_of(launches):
         layout, kv = metadata.get("layout"), metadata.get("kv")
         if layout and kv:
             key = f"{kernel}@{layout},{kv}"
@@ -552,7 +618,16 @@ def _publish_counts(name: str, counts: Dict[str, int]) -> None:
                     name, kernel, layout, kv).set(counts[key])
             continue
         kernel, _, kind = key.partition("/")
-        if kind:
+        if kind in SCORE_PAIRS:
+            reg.gauge(
+                "zoo_flash_score_pairs", "Score pairs the live tiles of a "
+                "flash attention kernel's launches in the compiled program "
+                "compute, and those of them the mask and the true key "
+                "length allow: allowed over computed is the share of the "
+                "kernel's score work that is wanted",
+                ("executable", "kernel", "kind")).labels(
+                    name, kernel, SCORE_PAIRS[kind]).set(counts[key])
+        elif kind:
             reg.gauge(
                 "zoo_flash_grid_steps", "Grid steps the launches of a "
                 "flash attention kernel in the compiled program take, by "
@@ -566,16 +641,19 @@ def step_counts(name: str) -> Optional[Dict[str, int]]:
     """``{"held_values", "erfc", "mask", "flash_fwd", "flash_bwd_dq",
     "flash_bwd_dkv", "norm_rotary_fwd", "norm_rotary_bwd"}`` of the
     executable last compiled ahead of time under
-    ``name``, and ``"<kernel>/<kind>"`` and ``"<kernel>@<layout>,<kv>"``
-    for each flash kernel it launches: the optimization barriers of the
-    program as lowered (left out where the lowered program was not at
-    hand), :func:`count_elementwise_evals`, :func:`count_kernel_calls`,
-    :func:`count_flash_grid_steps` and :func:`count_flash_layouts` of its
-    optimized HLO; ``None`` when nothing was compiled under that name. The
-    same numbers are the gauges ``zoo_step_held_values{executable}``,
+    ``name``, and ``"<kernel>/<kind>"``, ``"<kernel>/pairs"``,
+    ``"<kernel>/allowed"`` and ``"<kernel>@<layout>,<kv>"`` for each flash
+    kernel it launches: the optimization barriers of the program as
+    lowered (left out where the lowered program was not at hand),
+    :func:`count_elementwise_evals`, :func:`count_kernel_calls`,
+    :func:`count_flash_grid_steps`, :func:`count_flash_score_pairs` and
+    :func:`count_flash_layouts` of its optimized HLO; ``None`` when
+    nothing was compiled under that name. The same numbers are the gauges
+    ``zoo_step_held_values{executable}``,
     ``zoo_step_elementwise_evals{executable,kind}``,
     ``zoo_step_kernel_calls{executable,kernel}``,
-    ``zoo_flash_grid_steps{executable,kernel,kind}`` and
+    ``zoo_flash_grid_steps{executable,kernel,kind}``,
+    ``zoo_flash_score_pairs{executable,kernel,kind=computed|allowed}`` and
     ``zoo_flash_launches{executable,kernel,layout,kv}``."""
     with _executables_lock:
         rec = _executables.get(name)
@@ -583,7 +661,8 @@ def step_counts(name: str) -> Optional[Dict[str, int]]:
 
 
 def scope_index(name: str) -> Optional[Dict[str, dict]]:
-    """``{instruction name: {"scope", "phase", "scopes", "opcode"}}`` for
+    """``{instruction name: {"scope", "phase", "scopes", "opcode"}}``, and
+    ``"kernel"`` (and ``"tiles"``) on a kernel launch's entry, for
     the executable last compiled ahead of time under ``name``
     (``"estimator_train_step"``, ``"estimator_train_scan"``, an inference
     model's cache name); ``None`` when nothing was. See
@@ -593,7 +672,7 @@ def scope_index(name: str) -> Optional[Dict[str, dict]]:
     if rec is None or rec.hlo_text is None:
         return None
     if rec.index is None:
-        rec.index = parse_scope_index(rec.hlo_text)
+        rec.index = parse_scope_index(rec.hlo_text, rec.launches)
     return rec.index
 
 
@@ -642,11 +721,19 @@ def _scope_of(op_name: str) -> Tuple[str, str]:
     return "/".join(scope), phase
 
 
-def parse_scope_index(hlo_text: str) -> Dict[str, dict]:
+def parse_scope_index(hlo_text: str,
+                      launches: Optional[List[Launch]] = None
+                      ) -> Dict[str, dict]:
     """The scope index of one optimized HLO module's text: for every
     instruction the device runs as an op of its own — those of the entry
     computation and of the bodies of its ``while``/``call``/``conditional``
-    instructions — ``{"scope", "phase", "scopes", "opcode"}``.
+    instructions — ``{"scope", "phase", "scopes", "opcode"}``, and on the
+    entry of a kernel's launch ``kernel``, its label (``launches``: what
+    :func:`_kernel_launches` found in the text, found here where not
+    given): one of ``KERNEL_FUNCTIONS``, or ``RAGGED_DOT`` for the
+    compiler's grouped matrix product. A flash launch's entry carries
+    ``tiles`` too: the grid steps its metadata lists, all kinds and all
+    heads. Every other entry has neither key.
 
     ``scope`` and ``phase`` (``forward`` | ``backward`` | ``optimizer`` |
     ``other``) come from the instruction's ``metadata.op_name``
@@ -695,6 +782,9 @@ def parse_scope_index(hlo_text: str) -> Dict[str, dict]:
                         named.group(1) if named else None,
                         bool(m.group(1)), callees, _OPERAND.findall(rest)))
 
+    kernels = {name: (kernel, metadata)
+               for name, kernel, metadata in _launches_of(
+                   hlo_text if launches is None else launches)}
     index: Dict[str, dict] = {}
     seen = set()
 
@@ -736,6 +826,13 @@ def parse_scope_index(hlo_text: str) -> Dict[str, dict]:
             index[name] = {
                 "scope": scope, "phase": phase, "opcode": opcode,
                 "scopes": sorted({_scope_of(n)[0] for n in names})}
+            if name in kernels:
+                kernel, metadata = kernels[name]
+                index[name]["kernel"] = kernel
+                tiles = [int(metadata[kind]) for kind in TILE_KINDS
+                         if metadata.get(kind, "").isdigit()]
+                if tiles:
+                    index[name]["tiles"] = sum(tiles)
         _inherit_from_neighbours(body, index)
 
     if entry is not None:

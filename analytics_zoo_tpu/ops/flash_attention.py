@@ -250,9 +250,10 @@ class TileMask:
         raise NotImplementedError
 
     def representatives(self, start: int, stop: int):
-        """Positions of ``[start, stop)`` among which every other one
-        has a twin that the mask treats alike (all of them, unless a
-        subclass knows better)."""
+        """Positions of ``[start, stop)``, ascending and ``start`` first,
+        such that the mask treats every position alike with the last
+        representative at or before it (all of them, unless a subclass
+        knows better)."""
         return np.arange(start, stop)
 
     def kind(self, q0: int, q1: int, k0: int, k1: int) -> int:
@@ -422,6 +423,44 @@ def tile_table(nq: int, nk: int, block_q: int, block_k: int, mask, kv_len,
     return table
 
 
+@functools.lru_cache(maxsize=None)
+def tile_pairs(nq: int, nk: int, block_q: int, block_k: int, mask, kv_len,
+               key_major: bool = False, groups: int = 1):
+    """``(computed, allowed)``: the score pairs the live tiles of ONE
+    head's ``tile_table`` (the same arguments) compute, ``block_q x
+    block_k`` a step, and those of them that the mask and the true key
+    length ``kv_len`` let through — an interior tile whole, a diagonal one
+    counted here in numpy. Padded query rows count as the mask treats
+    their positions."""
+    table = tile_table(nq, nk, block_q, block_k, mask, kv_len, key_major,
+                       groups)
+    live = table[table[:, 2] != DEAD]
+    allowed = sum(
+        block_q * block_k if kind == INTERIOR else _allowed_in_tile(
+            int(qi), int(ki), block_q, block_k, mask, kv_len)
+        for qi, ki, kind in live[:, :3])
+    return len(live) * block_q * block_k, allowed
+
+
+@functools.lru_cache(maxsize=None)
+def _allowed_in_tile(qi: int, ki: int, block_q: int, block_k: int, mask,
+                     kv_len) -> int:
+    """The pairs of tile ``(qi, ki)`` that neither the mask nor the padded
+    key tail excludes: the mask read at its representatives, each weighed
+    by the positions it stands for."""
+    q0, k0 = qi * block_q, ki * block_k
+    k1 = k0 + block_k if kv_len is None else min(k0 + block_k, kv_len)
+    if k1 <= k0:
+        return 0
+    if mask is None:
+        return block_q * (k1 - k0)
+    qs = mask.representatives(q0, q0 + block_q)
+    ks = mask.representatives(k0, k1)
+    seen = ~mask.excluded(qs[:, None], ks[None, :])
+    return int(np.diff(qs, append=q0 + block_q)
+               @ seen.astype(np.int64) @ np.diff(ks, append=k1))
+
+
 def _step(qi_ref, ki_ref, kind_ref, key_major: bool = False):
     """This grid step's row of the table, and whether it is the first and
     the last of its resident block's run (``qi``'s, or with ``key_major``
@@ -472,7 +511,7 @@ def _masked_scores(s, qi, ki, *, block_q, block_k, mask, kv_len):
     return s if masked is None else jnp.where(masked, NEG_INF, s)
 
 
-def _tile_call(kernel, table, heads: int, operands: "_Operands", *,
+def _tile_call(kernel, table, pairs, heads: int, operands: "_Operands", *,
                out_shape, in_specs, out_specs, scratch_shapes):
     """``pl.pallas_call`` of ``kernel`` over ``(heads, the table's
     steps)``: the table's columns go ahead of the operands by scalar
@@ -481,15 +520,18 @@ def _tile_call(kernel, table, heads: int, operands: "_Operands", *,
     lists its group's heads, a fourth ``head_ref``. One-dimensional
     columns: SMEM pads an array's last dim to 128 words, so ``[steps, 3]``
     as it stands would take 512 bytes a step. The steps the launch takes,
-    by kind, ride in the custom call as its kernel metadata beside how it
-    finds a head's blocks — ``layout`` and ``kv``, ``_Operands`` —
-    (``profiling.count_flash_grid_steps`` and ``count_flash_layouts``
-    read them from the compiled program)."""
+    by kind, ride in the custom call as its kernel metadata beside the
+    score pairs they compute and allow (``pairs``, one head's
+    ``tile_pairs``) and how it finds a head's blocks — ``layout`` and
+    ``kv``, ``_Operands`` — (``profiling.count_flash_grid_steps``,
+    ``count_flash_score_pairs`` and ``count_flash_layouts`` read them from
+    the compiled program)."""
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     kinds = table[:, 2]
     counts = np.bincount(kinds, minlength=len(TILE_KINDS)) * heads
+    computed, allowed = pairs
     call = pl.pallas_call(
         functools.partial(kernel, kinds=frozenset(kinds.tolist())),
         out_shape=out_shape,
@@ -498,6 +540,8 @@ def _tile_call(kernel, table, heads: int, operands: "_Operands", *,
             in_specs=in_specs, out_specs=out_specs,
             scratch_shapes=scratch_shapes),
         metadata={**{name: str(n) for name, n in zip(TILE_KINDS, counts)},
+                  "pairs": str(computed * heads),
+                  "allowed": str(allowed * heads),
                   "layout": "rows" if operands.rows else "heads",
                   "kv": "grouped" if operands.groups > 1 else "own"},
         **_interp_kw())
@@ -716,8 +760,8 @@ def _flash_fwd(q, k, v, causal: bool, block_q: int, block_k: int,
     # head by its group in both.
     qt, kt, vt = ops.fold(q), ops.fold(k), ops.fold(v)
     kv_len = sk if sk_p != sk else None
-    table = tile_table(sq_p // block_q, sk_p // block_k, block_q, block_k,
-                       mask, kv_len)
+    tiles = (sq_p // block_q, sk_p // block_k, block_q, block_k, mask,
+             kv_len)
     q_spec = pl.BlockSpec((1, block_q, d_p), ops.q_block)
     k_spec = pl.BlockSpec((1, block_k, d_p), ops.k_block)
     out_shape = [jax.ShapeDtypeStruct(qt.shape, q.dtype)]
@@ -730,7 +774,7 @@ def _flash_fwd(q, k, v, causal: bool, block_q: int, block_k: int,
         functools.partial(_flash_fwd_kernel, block_k=block_k,
                           block_q=block_q, mask=mask, sm_scale=sm_scale,
                           kv_len=kv_len),
-        table, b * h, ops,
+        tile_table(*tiles), tile_pairs(*tiles), b * h, ops,
         out_shape=tuple(out_shape),
         in_specs=[q_spec, k_spec, k_spec],
         out_specs=tuple(out_specs),
@@ -895,8 +939,8 @@ def _flash_bwd(q, k, v, o, lse, g, causal: bool, block_q: int,
     kv_len = sk if sk_p != sk else None
     tile = dict(block_q=block_q, block_k=block_k, mask=mask,
                 sm_scale=sm_scale, kv_len=kv_len)
-    tiles = functools.partial(tile_table, sq_p // block_q, sk_p // block_k,
-                              block_q, block_k, mask, kv_len)
+    tiles = (sq_p // block_q, sk_p // block_k, block_q, block_k, mask,
+             kv_len)
 
     def in_specs(maps):
         q_spec = pl.BlockSpec((1, block_q, d_p), maps.q_block)
@@ -905,7 +949,8 @@ def _flash_bwd(q, k, v, o, lse, g, causal: bool, block_q: int,
         return [q_spec, k_spec, k_spec, q_spec, r_spec, r_spec]
 
     dq = _tile_call(
-        functools.partial(_flash_bwd_dq_kernel, **tile), tiles(), b * h, ops,
+        functools.partial(_flash_bwd_dq_kernel, **tile), tile_table(*tiles),
+        tile_pairs(*tiles), b * h, ops,
         out_shape=jax.ShapeDtypeStruct(qt.shape, q.dtype),
         in_specs=in_specs(ops),
         out_specs=pl.BlockSpec((1, block_q, d_p), ops.q_block),
@@ -919,7 +964,8 @@ def _flash_bwd(q, k, v, o, lse, g, causal: bool, block_q: int,
     kv_spec = pl.BlockSpec((1, block_k, d_p), by_group.k_block)
     dk, dv = _tile_call(
         functools.partial(_flash_bwd_dkv_kernel, groups=ops.groups, **tile),
-        tiles(key_major=True, groups=ops.groups), b * ops.kv_heads, by_group,
+        tile_table(*tiles, True, ops.groups),
+        tile_pairs(*tiles, True, ops.groups), b * ops.kv_heads, by_group,
         out_shape=(jax.ShapeDtypeStruct(kt.shape, k.dtype),
                    jax.ShapeDtypeStruct(vt.shape, v.dtype)),
         in_specs=in_specs(by_group),

@@ -682,5 +682,5 @@ def test_a_kernel_the_compiler_named_takes_the_scope_of_what_feeds_it():
     assert index["fusion.4"]["phase"] == "forward"
     assert index["ragged-dot-none.2"] == {
         "scope": "M/block_1/moe/experts/products", "phase": "backward",
-        "scopes": [], "opcode": "custom-call"}
+        "scopes": [], "opcode": "custom-call", "kernel": "ragged_dot"}
     assert index["fusion.3"]["scope"] == "optimizer"

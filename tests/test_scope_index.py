@@ -77,7 +77,9 @@ def test_scope_index_names_the_parts_of_the_step(step_index):
     assert {"forward", "backward"} <= _phases(step_index, r"^loss(/|$)")
     assert _phases(step_index, r"^clip(/|$)") == {"other"}
     assert _phases(step_index, r"^metrics$") == {"other"}
-    # no wrapper of a transform is left in a scope, no primitive's name
+    # no wrapper of a transform is left in a scope, no primitive's name;
+    # no entry names a kernel (``kernel``, ``tiles``): BERT's step on the
+    # CPU launches none
     for entry in step_index.values():
         assert set(entry) == {"scope", "phase", "scopes", "opcode"}
         for scope in entry["scopes"]:
@@ -451,6 +453,190 @@ def test_norm_rotary_launches_are_counted_beside_the_flash_kernels():
     assert profiling.count_kernel_calls(_norm_rotary_step(
         b"_norm_rotary_fwd\x00", b"_rotary_bwd_kernel\x00")) == dict(
             want, norm_rotary_fwd=0, norm_rotary_bwd=0)
+
+
+# ------------------------------------------- each launch in the index
+
+EXPERT_LAYER = os.path.join(os.path.dirname(__file__), "fixtures",
+                            "hlo_v5e_expert_layer_entry.txt")
+
+
+@pytest.mark.parametrize("fixture,tiles", [
+    (LIVE_TILES_LAYER, 6), (REMAT_LAYER, None)],
+    ids=["launches_that_list_their_tiles", "launches_that_list_nothing"])
+def test_each_flash_launch_names_its_kernel_in_the_index(fixture, tiles):
+    """The attention layer's entry computation as the v5e compiler left
+    it: the entry of each flash custom call says which kernel it launches
+    and, where the launch listed its grid steps, how many (two heads of
+    one interior and two diagonal tiles); no other entry names a kernel.
+    What the counters read is the same from the one list of launches as
+    from the text."""
+    with open(fixture) as fh:
+        text = fh.read()
+    launches = profiling._kernel_launches(text)
+    index = profiling.parse_scope_index(text)
+    assert profiling.parse_scope_index(text, launches) == index
+    named = {name: e for name, e in index.items() if "kernel" in e}
+    assert {name: e["kernel"] for name, e in named.items()} \
+        == {name: kernel for name, kernel, _ in launches}
+    assert {e["kernel"] for e in named.values()} == set(
+        profiling.FLASH_KERNELS)
+    for entry in named.values():
+        assert entry["opcode"] == "custom-call"
+        assert entry["kernel"] in profiling.FLASH_KERNELS
+        assert entry.get("tiles") == tiles
+    for entry in index.values():
+        if "kernel" not in entry:
+            assert set(entry) == {"scope", "phase", "scopes", "opcode"}
+    for count in (profiling.count_kernel_calls,
+                  profiling.count_flash_grid_steps,
+                  profiling.count_flash_layouts):
+        assert count(launches) == count(text)
+
+
+def test_the_expert_layers_grouped_products_are_named_ragged_dot():
+    """The entry computation of one expert layer's gradient as the v5e
+    compiler left it: the grouped products are the compiler's own custom
+    calls, ``ragged-dot-none``, each named ``ragged_dot`` in the index
+    under the layer's scope; ``ragged-dot-metadata`` (where the groups
+    start) and every other instruction name no kernel. No pallas kernel of
+    the package's is counted."""
+    with open(EXPERT_LAYER) as fh:
+        text = fh.read()
+    index = profiling.parse_scope_index(text)
+    products = {name for name, e in index.items()
+                if e.get("kernel") == profiling.RAGGED_DOT}
+    assert products and all(name.startswith("ragged-dot-none")
+                            for name in products)
+    assert products == {name for name, e in index.items()
+                        if name.startswith("ragged-dot-none")}
+    assert any(name.startswith("ragged-dot-metadata") and "kernel" not in e
+               for name, e in index.items())
+    import re
+    for name in products:
+        assert index[name]["opcode"] == "custom-call"
+        assert re.search(r"block_\d+/moe(/|$)", index[name]["scope"])
+        assert "tiles" not in index[name]
+    assert set(profiling.count_kernel_calls(text).values()) == {0}
+    assert profiling.count_flash_grid_steps(text) == {}
+    assert profiling.count_flash_score_pairs(text) == {}
+
+
+def test_note_executable_finds_the_launches_once(monkeypatch):
+    """One pass over the launches at compile time: the counters read its
+    list, and the scope index, parsed on first request, reads it again
+    without a pass of its own."""
+    with open(LIVE_TILES_LAYER) as fh:
+        text = fh.read()
+    calls = []
+    found = profiling._kernel_launches
+
+    def counted(hlo_text):
+        calls.append(len(hlo_text))
+        return found(hlo_text)
+
+    monkeypatch.setattr(profiling, "_kernel_launches", counted)
+    profiling.note_executable("canned", _CannedExe(text))
+    assert len(calls) == 1
+    index = profiling.scope_index("canned")
+    assert len(calls) == 1
+    assert index == profiling.parse_scope_index(text, found(text))
+    assert sum("kernel" in e for e in index.values()) == 3
+
+
+def _with_pairs(text, pairs, allowed):
+    """``text`` with ``pairs`` and ``allowed`` among each launch's
+    ``kernel_metadata``, in the compiler's order (sorted, one a line)."""
+    return text.replace('"interior":"2"\n',
+                        f'"interior":"2",\n"pairs":"{pairs}"\n').replace(
+        '"diagonal":"4",\n', f'"allowed":"{allowed}",\n"diagonal":"4",\n')
+
+
+def test_score_pairs_counted_and_published():
+    """Each launch of the live-tiles layer with the two keys a launch
+    writes since it counts its score pairs (two heads of three 512 x 512
+    tiles; the causal mask allows 1,024 x 1,025 / 2 pairs a head): summed
+    by kernel and published as ``zoo_flash_score_pairs``, computed and
+    allowed, beside the grid steps, which read what they read."""
+    with open(LIVE_TILES_LAYER) as fh:
+        text = fh.read()
+    steps = profiling.count_flash_grid_steps(text)
+    text = _with_pairs(text, 2 * 3 * 512 * 512, 1024 * 1025)
+    want = {f"{kernel}/{key}": n for kernel in profiling.FLASH_KERNELS
+            for key, n in (("pairs", 1572864), ("allowed", 1049600))}
+    assert profiling.count_flash_score_pairs(text) == want
+    assert profiling.count_flash_grid_steps(text) == steps
+    profiling.note_executable("canned", _CannedExe(text))
+    counts = profiling.step_counts("canned")
+    assert {k: counts[k] for k in want} == want
+    assert {k: n for k, n in counts.items()
+            if k.endswith(("/interior", "/diagonal", "/dead"))} == steps
+    snap = telemetry.snapshot()
+    assert snap["zoo_flash_score_pairs"] == {
+        f"executable=canned,kernel={kernel},kind={kind}": n
+        for kernel in profiling.FLASH_KERNELS
+        for kind, n in (("computed", 1572864), ("allowed", 1049600))}
+    assert set(snap["zoo_flash_grid_steps"]) == {
+        f"executable=canned,kernel={k.split('/')[0]},kind={k.split('/')[1]}"
+        for k in steps}
+    # a launch from before the keys: no series
+    telemetry.reset_for_tests()
+    with open(LIVE_TILES_LAYER) as fh:
+        profiling.note_executable("canned", _CannedExe(fh.read()))
+    assert "zoo_flash_score_pairs" not in telemetry.snapshot()
+
+
+@pytest.mark.parametrize("sq,heads,mask,want", [
+    (8192, 64, "causal", (37748736, 33558528)),
+    (16384, 32, "block_diffusion", (83886080, 67141632))],
+    ids=["the_causal_cells_head", "the_block_diffusion_cells_head"])
+def test_score_pairs_at_the_decoder_cells_shapes(sq, heads, mask, want):
+    """What a launch writes, counted in numpy with no compile, at the two
+    decoder cells' 1,024 x 1,024 blocks: a causal head of 8,192 positions
+    computes 36 tiles and wants 8,192 x 8,193 / 2 of their pairs (88.9%);
+    a head of the block-diffusion cell's 16,384 rows computes 80 tiles and
+    wants 2 x 33,570,816 (80.0%). Every kind of launch lists the same
+    tiles: the key-major table of a group of 8 or 4 heads counts the
+    group's heads."""
+    from analytics_zoo_tpu.ops import flash_attention as fa
+    m = fa.CausalMask(0) if mask == "causal" \
+        else fa.BlockDiffusionMask(sq // 2, 4)
+    n = sq // 1024
+    assert fa.tile_pairs(n, n, 1024, 1024, m, None) == want
+    for groups in (1, 4, 8):
+        assert fa.tile_pairs(n, n, 1024, 1024, m, None, True, groups) \
+            == (want[0] * groups, want[1] * groups)
+    assert 100 * want[1] / want[0] == pytest.approx(
+        88.9 if mask == "causal" else 80.04, abs=0.01)
+
+
+@pytest.mark.parametrize("sq,sk,bq,bk,mask", [
+    (200, 200, 128, 128, "causal"), (256, 300, 128, 128, "causal"),
+    (1024, 1500, 512, 512, None), (1024, 1500, 512, 512, "causal"),
+    (256, 256, 64, 128, "block_diffusion"),
+    (384, 384, 128, 128, "block_diffusion_clean")],
+    ids=["ragged_causal", "more_keys_than_queries", "padded_key_tail",
+         "padded_key_tail_causal", "block_diffusion", "clean_half_alone"])
+def test_allowed_pairs_match_a_brute_force_count(sq, sk, bq, bk, mask):
+    """The allowed pairs of every live tile, summed over the table, equal
+    the pairs of the padded score matrix that neither the mask nor the
+    padded key tail excludes, counted whole in numpy; the computed pairs
+    are the table's steps times a tile."""
+    from analytics_zoo_tpu.ops import flash_attention as fa
+    m = {"causal": fa.CausalMask(sk - sq), None: None,
+         "block_diffusion": fa.BlockDiffusionMask(sq // 2, 4),
+         "block_diffusion_clean": fa.BlockDiffusionMask(sq, 4, noisy=False),
+         }[mask]
+    nq, nk = -(-sq // bq), -(-sk // bk)
+    kv_len = sk if nk * bk != sk else None
+    q = np.arange(nq * bq)[:, None]
+    k = np.arange(nk * bk)[None, :]
+    allowed = (k < sk) & (np.ones_like(q, bool) if m is None
+                          else ~m.excluded(q, k))
+    table = fa.tile_table(nq, nk, bq, bk, m, kv_len)
+    live = int((table[:, 2] != fa.DEAD).sum())
+    assert fa.tile_pairs(nq, nk, bq, bk, m, kv_len) == (
+        live * bq * bk, int(allowed.sum()))
 
 
 def test_fit_publishes_the_counts_where_metrics_are_scraped(orca_ctx):

@@ -77,6 +77,44 @@ def test_flash_with_lse_grad_lowers(b, s, h, d, causal, kv):
     lowers_for_tpu(jax.grad(loss, argnums=(0, 1, 2)), *_qkv(b, s, h, d, kv))
 
 
+@pytest.mark.parametrize("shape,kv,masking", [
+    ((2, 8192, 32, 64), 8, "causal"),
+    ((1, 16384, 32, 128), 4, "block_diffusion")],
+    ids=["the_causal_cells_layer", "the_block_diffusion_cells_layer"])
+def test_each_flash_call_carries_its_score_pairs(shape, kv, masking):
+    """The three launches of each decoder cell's attention at the cell's
+    size and 1,024 x 1,024 blocks, lowered for the TPU: every call's
+    ``kernel_metadata`` says the score pairs its live tiles compute and
+    those the mask allows — one head's ``tile_pairs`` times the batch's
+    query heads, whichever heads the launch's grid runs over — beside its
+    grid steps, one head's table times the heads the grid runs over."""
+    import re
+
+    b, s, h, d = shape
+    mask = fa.BlockDiffusionMask(s // 2, 4) if masking != "causal" else None
+    grad = jax.grad(lambda q, k, v: _sq(fa.flash_attention(
+        q, k, v, mask is None, 1024, 1024, mask)), argnums=(0, 1, 2))
+    text = export.export(jax.jit(grad), platforms=("tpu",))(
+        *_qkv(b, s, h, d, kv)).mlir_module()
+    said = {name: dict(re.findall(r"\\22(\w+)\\22:\\22(\w*)\\22", meta))
+            for name, meta in re.findall(
+                r'kernel_name = "(_flash_\w+_kernel)".*?kernel_metadata = '
+                r'"([^"]*)"', text)}
+    assert set(said) == {"_flash_fwd_kernel", "_flash_bwd_dq_kernel",
+                         "_flash_bwd_dkv_kernel"}
+    n = s // 1024
+    computed, allowed = fa.tile_pairs(n, n, 1024, 1024,
+                                      mask or fa.CausalMask(0), None)
+    for name, metadata in said.items():
+        assert metadata["pairs"] == str(computed * b * h), name
+        assert metadata["allowed"] == str(allowed * b * h), name
+        heads = b * kv if "dkv" in name else b * h
+        steps = sum(int(metadata[kind]) for kind in fa.TILE_KINDS)
+        assert steps == heads * len(fa.tile_table(
+            n, n, 1024, 1024, mask or fa.CausalMask(0), None,
+            "dkv" in name, h // kv if "dkv" in name else 1))
+
+
 def test_held_experts_grouped_products_lower_at_the_published_widths():
     """The dropless expert layer's first window and its second under the
     ``cond``, forward and backward, at 16,384 tokens, 8 of 32 experts
