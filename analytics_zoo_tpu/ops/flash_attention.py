@@ -44,8 +44,10 @@ What a launch leaves out is decided by a STATIC mask (``TileMask``: the
 causal one behind ``causal=True``, ``BlockDiffusionMask`` for
 block-diffusion training's noisy and clean copy of a sequence): each
 launch lists the tiles the mask leaves work in ahead of time
-(``tile_table``) and takes no grid step for the others; the scan takes
-the same object.
+(``tile_table``) and takes no grid step for the others, and a tile the
+mask's edge crosses computes only its live strips where the mask leaves
+it dead ones (``strips``, ``launch_table``); the scan takes the same
+object.
 
 ``ZOO_PALLAS_INTERPRET=1`` runs every kernel through the pallas
 interpreter, which works on CPU — the parity tests in
@@ -230,6 +232,15 @@ DEAD = 2
 #: the kinds by name, in the order of their codes (the labels of
 #: ``zoo_flash_grid_steps``, common/profiling.py)
 TILE_KINDS = ("interior", "diagonal", "dead")
+#: the first code of a strip pattern: a launch codes a diagonal tile whose
+#: mask leaves dead strips ``STRIPS + i``, ``i`` its pattern's index
+#: (``launch_table``); such a tile counts as diagonal wherever kinds are
+#: counted
+STRIPS = 3
+#: the side of a strip: a diagonal tile is classified in ``STRIP`` rows of
+#: the resident block against ``STRIP`` columns of the streamed one, and
+#: computes its live strips alone (docs/kernels.md: why 256)
+STRIP = 256
 
 
 class TileMask:
@@ -374,6 +385,18 @@ def static_mask(causal: bool, mask, sq: int, sk: int):
     return mask
 
 
+def _kind(mask, kv_len, q0: int, q1: int, k0: int, k1: int) -> int:
+    """The kind of the queries ``[q0, q1)`` against the keys ``[k0, k1)``:
+    the mask's (every pair allowed for ``None``), dead past the true key
+    length ``kv_len``, diagonal where an interior region crosses it."""
+    if kv_len is not None and k0 >= kv_len:
+        return DEAD
+    kind = INTERIOR if mask is None else mask.kind(q0, q1, k0, k1)
+    if kind == INTERIOR and kv_len is not None and k1 > kv_len:
+        return DIAGONAL
+    return kind
+
+
 @functools.lru_cache(maxsize=None)
 def tile_table(nq: int, nk: int, block_q: int, block_k: int, mask, kv_len,
                key_major: bool = False, groups: int = 1):
@@ -399,13 +422,8 @@ def tile_table(nq: int, nk: int, block_q: int, block_k: int, mask, kv_len,
                          "table only")
 
     def kind(qi, ki):
-        k = INTERIOR if mask is None else mask.kind(
-            qi * block_q, (qi + 1) * block_q, ki * block_k,
-            (ki + 1) * block_k)
-        if k == INTERIOR and kv_len is not None \
-                and ki * block_k + block_k > kv_len:
-            return DIAGONAL
-        return k
+        return _kind(mask, kv_len, qi * block_q, (qi + 1) * block_q,
+                     ki * block_k, (ki + 1) * block_k)
 
     rows = []
     for outer in range(nk if key_major else nq):
@@ -424,22 +442,99 @@ def tile_table(nq: int, nk: int, block_q: int, block_k: int, mask, kv_len,
 
 
 @functools.lru_cache(maxsize=None)
+def strips(qi: int, ki: int, block_q: int, block_k: int, mask, kv_len,
+           key_major: bool = False):
+    """The strip pattern of the diagonal tile ``(qi, ki)``: for each
+    ``STRIP`` rows of the resident block — the query block's, or with
+    ``key_major`` the key block's — ``(lo, hi, masked)``: the streamed
+    block's live strips are ``[lo, hi)``, and ``masked`` says whether one
+    of them crosses the mask's edge or the padded key tail. ``None``, and
+    the tile is computed whole, where it has no dead strip, where a row's
+    live strips are not contiguous, or where a block is no multiple of
+    ``STRIP``."""
+    if block_q % STRIP or block_k % STRIP:
+        return None
+    q0, k0 = qi * block_q, ki * block_k
+    kinds = np.array([[_kind(mask, kv_len, q0 + r * STRIP,
+                             q0 + (r + 1) * STRIP, k0 + c * STRIP,
+                             k0 + (c + 1) * STRIP)
+                       for c in range(block_k // STRIP)]
+                      for r in range(block_q // STRIP)])
+    if key_major:
+        kinds = kinds.T
+    if not (kinds == DEAD).any():
+        return None
+    pattern = []
+    for row in kinds:
+        live = np.flatnonzero(row != DEAD)
+        lo, hi = (int(live[0]), int(live[-1]) + 1) if len(live) else (0, 0)
+        if hi - lo != len(live):
+            return None
+        pattern.append((lo, hi, bool((row[lo:hi] == DIAGONAL).any())))
+    return tuple(pattern)
+
+
+def _segments(pattern) -> tuple:
+    """A strip pattern as the products its body computes, ``(r0, r1, c0,
+    c1, masked)`` in rows of the resident and the streamed block: one a
+    strip, none for a strip that sees nothing."""
+    return tuple((r * STRIP, (r + 1) * STRIP, lo * STRIP, hi * STRIP, masked)
+                 for r, (lo, hi, masked) in enumerate(pattern) if hi > lo)
+
+
+@functools.lru_cache(maxsize=None)
+def launch_table(nq: int, nk: int, block_q: int, block_k: int, mask,
+                 kv_len, key_major: bool = False, groups: int = 1):
+    """What ONE head of a launch runs: ``tile_table``'s rows (the same
+    arguments) in its order, with each diagonal tile whose mask leaves it
+    dead strips coded ``STRIPS + i`` for its pattern (``strips``), and
+    ``((code, segments), ...)`` for each code the table holds — the
+    products of a step of that kind, ``(r0, r1, c0, c1, masked)`` in rows
+    of the resident and the streamed block: an interior tile whole and
+    bare, a diagonal one whole and masked, a pattern's tile its live
+    strips, a dead one nothing."""
+    table = tile_table(nq, nk, block_q, block_k, mask, kv_len, key_major,
+                       groups).copy()
+    resident, streamed = (block_k, block_q) if key_major \
+        else (block_q, block_k)
+    bodies = {INTERIOR: ((0, resident, 0, streamed, False),),
+              DIAGONAL: ((0, resident, 0, streamed, True),), DEAD: ()}
+    patterns = []
+    for row in table:
+        if row[2] != DIAGONAL:
+            continue
+        pattern = strips(int(row[0]), int(row[1]), block_q, block_k, mask,
+                         kv_len, key_major)
+        if pattern is None:
+            continue
+        if pattern not in patterns:
+            patterns.append(pattern)
+            bodies[STRIPS + len(patterns) - 1] = _segments(pattern)
+        row[2] = STRIPS + patterns.index(pattern)
+    table.setflags(write=False)
+    return table, tuple((code, bodies[code])
+                        for code in sorted(set(table[:, 2].tolist())))
+
+
+@functools.lru_cache(maxsize=None)
 def tile_pairs(nq: int, nk: int, block_q: int, block_k: int, mask, kv_len,
                key_major: bool = False, groups: int = 1):
-    """``(computed, allowed)``: the score pairs the live tiles of ONE
-    head's ``tile_table`` (the same arguments) compute, ``block_q x
-    block_k`` a step, and those of them that the mask and the true key
-    length ``kv_len`` let through — an interior tile whole, a diagonal one
-    counted here in numpy. Padded query rows count as the mask treats
-    their positions."""
-    table = tile_table(nq, nk, block_q, block_k, mask, kv_len, key_major,
-                       groups)
+    """``(computed, allowed)``: the score pairs the live steps of ONE
+    head's launch compute (``launch_table``, the same arguments: a whole
+    tile, or a diagonal tile's live strips), and those of them that the
+    mask and the true key length ``kv_len`` let through — an interior tile
+    whole, a diagonal one counted here in numpy. Padded query rows count
+    as the mask treats their positions."""
+    table, bodies = launch_table(nq, nk, block_q, block_k, mask, kv_len,
+                                 key_major, groups)
+    area = {code: sum((r1 - r0) * (c1 - c0) for r0, r1, c0, c1, _ in segs)
+            for code, segs in bodies}
     live = table[table[:, 2] != DEAD]
     allowed = sum(
         block_q * block_k if kind == INTERIOR else _allowed_in_tile(
             int(qi), int(ki), block_q, block_k, mask, kv_len)
         for qi, ki, kind in live[:, :3])
-    return len(live) * block_q * block_k, allowed
+    return sum(area[code] for code in live[:, 2].tolist()), allowed
 
 
 @functools.lru_cache(maxsize=None)
@@ -476,51 +571,56 @@ def _step(qi_ref, ki_ref, kind_ref, key_major: bool = False):
     return qi_ref[step], ki_ref[step], kind_ref[step], first, last
 
 
-def _by_kind(kind, kinds, compute) -> None:
-    """Run ``compute(masked)`` as the step's kind says: bare on an
-    interior tile, masked on a diagonal one, not at all on a dead one.
-    ``kinds`` are the kinds the launch's table holds; one that holds a
-    single kind computes unconditionally."""
+def _by_kind(kind, bodies, compute) -> None:
+    """Run ``compute(segments)`` with the step's kind's segments
+    (``launch_table``): a whole tile bare, a whole tile masked, a
+    pattern's live strips, nothing on a dead step. ``bodies`` are the
+    launch's ``(code, segments)``; one that holds a single kind computes
+    unconditionally."""
     import jax.experimental.pallas as pl
 
-    if len(kinds) == 1:
-        if DEAD not in kinds:
-            compute(DIAGONAL in kinds)
+    if len(bodies) == 1:
+        if bodies[0][1]:
+            compute(bodies[0][1])
         return
-    for k in sorted(kinds - {DEAD}):
-        pl.when(kind == k)(functools.partial(compute, k == DIAGONAL))
+    for code, segments in bodies:
+        if segments:
+            pl.when(kind == code)(functools.partial(compute, segments))
 
 
-def _masked_scores(s, qi, ki, *, block_q, block_k, mask, kv_len):
-    """A diagonal tile's scores with what the mask and the padded key
-    tail exclude set to ``NEG_INF`` — the kernel-side mirror of
-    ``blockwise_attention``'s ``mask.excluded`` and ``k_pos < sk``."""
+def _masked_scores(s, q0, k0, *, mask, kv_len):
+    """Scores of the queries from ``q0`` against the keys from ``k0``
+    with what the mask and the padded key tail exclude set to ``NEG_INF``
+    — the kernel-side mirror of ``blockwise_attention``'s
+    ``mask.excluded`` and ``k_pos < sk``."""
     iota = functools.partial(jax.lax.broadcasted_iota, jnp.int32)
-    k_pos = ki * block_k + iota(s.shape, 1)
+    k_pos = k0 + iota(s.shape, 1)
     masked = None
     if mask is not None and mask.tile_iotas:
-        q_pos = qi * block_q + iota(s.shape, 0)
+        q_pos = q0 + iota(s.shape, 0)
         masked = mask.excluded(q_pos, k_pos)
     elif mask is not None:
-        masked = mask.excluded(
-            qi * block_q + iota((s.shape[0], 1), 0),
-            ki * block_k + iota((1, s.shape[1]), 1))
+        masked = mask.excluded(q0 + iota((s.shape[0], 1), 0),
+                               k0 + iota((1, s.shape[1]), 1))
     if kv_len is not None:
         over = k_pos >= kv_len
         masked = over if masked is None else (masked | over)
     return s if masked is None else jnp.where(masked, NEG_INF, s)
 
 
-def _tile_call(kernel, table, pairs, heads: int, operands: "_Operands", *,
-               out_shape, in_specs, out_specs, scratch_shapes):
-    """``pl.pallas_call`` of ``kernel`` over ``(heads, the table's
-    steps)``: the table's columns go ahead of the operands by scalar
-    prefetch, to the kernel and to every index map, which take ``(head,
-    step, qi_ref, ki_ref, kind_ref)`` and, where a key/value head's table
-    lists its group's heads, a fourth ``head_ref``. One-dimensional
-    columns: SMEM pads an array's last dim to 128 words, so ``[steps, 3]``
-    as it stands would take 512 bytes a step. The steps the launch takes,
-    by kind, ride in the custom call as its kernel metadata beside the
+def _tile_call(kernel, tiles, heads: int, operands: "_Operands", *,
+               key_major: bool = False, out_shape, in_specs, out_specs,
+               scratch_shapes):
+    """``pl.pallas_call`` of ``kernel`` over ``(heads, the steps of one
+    head's launch_table(*tiles, key_major, groups))``: the table's columns
+    go ahead of the operands by scalar prefetch, to the kernel and to
+    every index map, which take ``(head, step, qi_ref, ki_ref, kind_ref)``
+    and, where a key/value head's table lists its group's heads, a fourth
+    ``head_ref``; the kernel gets each kind's segments as ``bodies``.
+    One-dimensional columns: SMEM pads an array's last dim to 128 words,
+    so ``[steps, 3]`` as it stands would take 512 bytes a step. The steps
+    the launch takes, by kind (``tile_table``'s: a pattern's tile is
+    diagonal), ride in the custom call as its kernel metadata beside the
     score pairs they compute and allow (``pairs``, one head's
     ``tile_pairs``) and how it finds a head's blocks — ``layout`` and
     ``kv``, ``_Operands`` — (``profiling.count_flash_grid_steps``,
@@ -529,11 +629,13 @@ def _tile_call(kernel, table, pairs, heads: int, operands: "_Operands", *,
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    kinds = table[:, 2]
-    counts = np.bincount(kinds, minlength=len(TILE_KINDS)) * heads
-    computed, allowed = pairs
+    groups = operands.groups if key_major else 1
+    table, bodies = launch_table(*tiles, key_major, groups)
+    counts = np.bincount(tile_table(*tiles, key_major, groups)[:, 2],
+                         minlength=len(TILE_KINDS)) * heads
+    computed, allowed = tile_pairs(*tiles, key_major, groups)
     call = pl.pallas_call(
-        functools.partial(kernel, kinds=frozenset(kinds.tolist())),
+        functools.partial(kernel, bodies=bodies),
         out_shape=out_shape,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=table.shape[1], grid=(heads, len(table)),
@@ -552,7 +654,8 @@ def _tile_call(kernel, table, pairs, heads: int, operands: "_Operands", *,
 # ---------------------------------------------------------------- pallas fwd
 
 def _flash_fwd_kernel(qi_ref, ki_ref, kind_ref, q_ref, k_ref, v_ref, o_ref,
-                      *rest, kinds, sm_scale: float, **tile):
+                      *rest, bodies, sm_scale: float, block_q, block_k,
+                      **tile):
     import jax.experimental.pallas as pl
 
     # rest = (lse_ref?, o_scr, m_scr, l_scr): the lse output only exists
@@ -569,36 +672,46 @@ def _flash_fwd_kernel(qi_ref, ki_ref, kind_ref, q_ref, k_ref, v_ref, o_ref,
         m_scr[...] = jnp.full_like(m_scr, NEG_INF)
         l_scr[...] = jnp.zeros_like(l_scr)
 
-    def _compute(masked: bool):
+    def _compute(segments):
         # MXU matmuls stay in the input dtype (bf16 doubles throughput on
         # v5e); softmax state and the output accumulator are fp32 — the
         # standard flash mixed-precision split. preferred_element_type
         # gives fp32 accumulation inside the MXU either way. sm_scale is
         # 1/sqrt(d_orig) from the caller: q may be zero-padded past the
         # model's head_dim, so q.shape[-1] is the wrong denominator here.
-        q = q_ref[0]                             # [block_q, d]
-        k_blk = k_ref[0]                         # [block_k, d] (streamed)
-        v_blk = v_ref[0]
-        s = jax.lax.dot_general(                 # [block_q, block_k] fp32
-            q, k_blk, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * sm_scale
-        if masked:
-            s = _masked_scores(s, qi, ki, **tile)
-        # softmax state stays 2-D ([block_q, 1] columns) end to end:
-        # Mosaic works in sublane × lane tiles, and a column broadcasts
-        # along the lanes as it is
-        m = m_scr[...]
-        m_new = jnp.maximum(m, s.max(-1, keepdims=True))
-        p = jnp.exp(s - m_new)
-        corr = jnp.exp(m - m_new)
-        pv = jax.lax.dot_general(                # p in v's dtype → MXU rate
-            p.astype(v_blk.dtype), v_blk, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        o_scr[...] = o_scr[...] * corr + pv
-        l_scr[...] = l_scr[...] * corr + p.sum(-1, keepdims=True)
-        m_scr[...] = m_new
+        # A segment is the query rows [r0, r1) against the streamed keys
+        # [c0, c1): the whole tile, or one run of a pattern's strips.
+        # Every segment's scores first, then each one's softmax: the
+        # compiler keeps the order it is given, and a strip's softmax
+        # then runs beside the next strips' products
+        scores = []
+        for r0, r1, c0, c1, masked in segments:
+            q = q_ref[0, r0:r1]                  # [rows, d]
+            k_blk = k_ref[0, c0:c1]              # [cols, d] (streamed)
+            s = jax.lax.dot_general(             # [rows, cols] fp32
+                q, k_blk, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * sm_scale
+            if masked:
+                s = _masked_scores(s, qi * block_q + r0, ki * block_k + c0,
+                                   **tile)
+            scores.append(s)
+        for (r0, r1, c0, c1, _), s in zip(segments, scores):
+            v_blk = v_ref[0, c0:c1]
+            # softmax state stays 2-D ([rows, 1] columns) end to end:
+            # Mosaic works in sublane × lane tiles, and a column
+            # broadcasts along the lanes as it is
+            m = m_scr[r0:r1]
+            m_new = jnp.maximum(m, s.max(-1, keepdims=True))
+            p = jnp.exp(s - m_new)
+            corr = jnp.exp(m - m_new)
+            pv = jax.lax.dot_general(            # p in v's dtype → MXU rate
+                p.astype(v_blk.dtype), v_blk, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            o_scr[r0:r1] = o_scr[r0:r1] * corr + pv
+            l_scr[r0:r1] = l_scr[r0:r1] * corr + p.sum(-1, keepdims=True)
+            m_scr[r0:r1] = m_new
 
-    _by_kind(kind, kinds, _compute)
+    _by_kind(kind, bodies, _compute)
 
     @pl.when(last)
     def _flush():
@@ -774,7 +887,7 @@ def _flash_fwd(q, k, v, causal: bool, block_q: int, block_k: int,
         functools.partial(_flash_fwd_kernel, block_k=block_k,
                           block_q=block_q, mask=mask, sm_scale=sm_scale,
                           kv_len=kv_len),
-        tile_table(*tiles), tile_pairs(*tiles), b * h, ops,
+        tiles, b * h, ops,
         out_shape=tuple(out_shape),
         in_specs=[q_spec, k_spec, k_spec],
         out_specs=tuple(out_specs),
@@ -800,10 +913,11 @@ def _flash_fwd(q, k, v, causal: bool, block_q: int, block_k: int,
 # use Δ = rowsum(dO ⊙ O) for the softmax Jacobian. MXU matmuls run in the
 # input dtype with fp32 accumulation; accumulators live in VMEM scratch.
 
-def _bwd_block(q, k_blk, v_blk, do, lse, delta, qi, ki, masked: bool, *,
+def _bwd_block(q, k_blk, v_blk, do, lse, delta, q0, k0, masked: bool, *,
                sm_scale, **tile):
-    """Shared per-tile math: returns (p, ds) as fp32 [block_q, block_k].
-    ``lse`` and ``delta`` are [block_q] rows; ``delta`` already has the
+    """Shared per-segment math: returns (p, ds) as fp32 [rows, cols] for
+    the queries from position ``q0`` against the keys from ``k0``.
+    ``lse`` and ``delta`` are [rows] rows; ``delta`` already has the
     cotangent of the row logsumexp subtracted (see ``_flash_bwd``).
     Padded query rows arrive with lse = +1e30 so p (and everything
     downstream) is exactly zero for them."""
@@ -811,7 +925,7 @@ def _bwd_block(q, k_blk, v_blk, do, lse, delta, qi, ki, masked: bool, *,
         q, k_blk, (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32) * sm_scale
     if masked:
-        s = _masked_scores(s, qi, ki, **tile)
+        s = _masked_scores(s, q0, k0, **tile)
     p = jnp.exp(s - lse[:, None])                     # [bq, bk] fp32
     dp = jax.lax.dot_general(                         # dO · Vᵀ
         do, v_blk, (((1,), (1,)), ((), ())),
@@ -822,7 +936,7 @@ def _bwd_block(q, k_blk, v_blk, do, lse, delta, qi, ki, masked: bool, *,
 
 def _flash_bwd_dq_kernel(qi_ref, ki_ref, kind_ref, q_ref, k_ref, v_ref,
                          do_ref, lse_ref, delta_ref, dq_ref, dq_scr, *,
-                         kinds, **tile):
+                         bodies, block_q, block_k, **tile):
     import jax.experimental.pallas as pl
 
     qi, ki, kind, first, last = _step(qi_ref, ki_ref, kind_ref)
@@ -831,23 +945,33 @@ def _flash_bwd_dq_kernel(qi_ref, ki_ref, kind_ref, q_ref, k_ref, v_ref,
     def _init():
         dq_scr[...] = jnp.zeros_like(dq_scr)
 
-    def _compute(masked: bool):
-        q, k_blk, v_blk = q_ref[0], k_ref[0], v_ref[0]
-        _, ds = _bwd_block(q, k_blk, v_blk, do_ref[0], lse_ref[0, 0],
-                           delta_ref[0, 0], qi, ki, masked, **tile)
-        dq_scr[...] += jax.lax.dot_general(           # dS · K
-            ds.astype(q.dtype), k_blk, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+    def _compute(segments):
+        # a segment: the query rows [r0, r1) against the keys [c0, c1);
+        # every segment's dS first, then the products that accumulate
+        # them (the forward's order, for the same reason)
+        grads = []
+        for r0, r1, c0, c1, masked in segments:
+            q, k_blk, v_blk = q_ref[0, r0:r1], k_ref[0, c0:c1], \
+                v_ref[0, c0:c1]
+            _, ds = _bwd_block(q, k_blk, v_blk, do_ref[0, r0:r1],
+                               lse_ref[0, 0, r0:r1], delta_ref[0, 0, r0:r1],
+                               qi * block_q + r0, ki * block_k + c0, masked,
+                               **tile)
+            grads.append((ds.astype(q.dtype), k_blk))
+        for (r0, r1, *_), (ds, k_blk) in zip(segments, grads):
+            dq_scr[r0:r1] += jax.lax.dot_general(     # dS · K
+                ds, k_blk, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
 
-    _by_kind(kind, kinds, _compute)
+    _by_kind(kind, bodies, _compute)
 
     @pl.when(last)
     def _flush():
         dq_ref[0] = dq_scr[...].astype(dq_ref.dtype)
 
 
-def _flash_bwd_dkv_kernel(qi_ref, ki_ref, kind_ref, *refs, kinds, groups,
-                          **tile):
+def _flash_bwd_dkv_kernel(qi_ref, ki_ref, kind_ref, *refs, bodies, groups,
+                          block_q, block_k, **tile):
     import jax.experimental.pallas as pl
 
     # a key/value head's table names the head of its group in a fourth
@@ -863,18 +987,27 @@ def _flash_bwd_dkv_kernel(qi_ref, ki_ref, kind_ref, *refs, kinds, groups,
         dk_scr[...] = jnp.zeros_like(dk_scr)
         dv_scr[...] = jnp.zeros_like(dv_scr)
 
-    def _compute(masked: bool):
-        q, k_blk, v_blk, do = q_ref[0], k_ref[0], v_ref[0], do_ref[0]
-        p, ds = _bwd_block(q, k_blk, v_blk, do, lse_ref[0, 0],
-                           delta_ref[0, 0], qi, ki, masked, **tile)
-        dv_scr[...] += jax.lax.dot_general(           # Pᵀ · dO
-            p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        dk_scr[...] += jax.lax.dot_general(           # dSᵀ · Q
-            ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+    def _compute(segments):
+        # the key-major table's segment: the resident key rows [r0, r1)
+        # against the streamed query rows [c0, c1); every segment's P and
+        # dS first, then the products that accumulate them
+        grads = []
+        for r0, r1, c0, c1, masked in segments:
+            q, do = q_ref[0, c0:c1], do_ref[0, c0:c1]
+            k_blk, v_blk = k_ref[0, r0:r1], v_ref[0, r0:r1]
+            p, ds = _bwd_block(q, k_blk, v_blk, do, lse_ref[0, 0, c0:c1],
+                               delta_ref[0, 0, c0:c1], qi * block_q + c0,
+                               ki * block_k + r0, masked, **tile)
+            grads.append((p.astype(do.dtype), do, ds.astype(q.dtype), q))
+        for (r0, r1, *_), (p, do, ds, q) in zip(segments, grads):
+            dv_scr[r0:r1] += jax.lax.dot_general(     # Pᵀ · dO
+                p, do, (((0,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            dk_scr[r0:r1] += jax.lax.dot_general(     # dSᵀ · Q
+                ds, q, (((0,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
 
-    _by_kind(kind, kinds, _compute)
+    _by_kind(kind, bodies, _compute)
 
     @pl.when(last)
     def _flush():
@@ -949,8 +1082,7 @@ def _flash_bwd(q, k, v, o, lse, g, causal: bool, block_q: int,
         return [q_spec, k_spec, k_spec, q_spec, r_spec, r_spec]
 
     dq = _tile_call(
-        functools.partial(_flash_bwd_dq_kernel, **tile), tile_table(*tiles),
-        tile_pairs(*tiles), b * h, ops,
+        functools.partial(_flash_bwd_dq_kernel, **tile), tiles, b * h, ops,
         out_shape=jax.ShapeDtypeStruct(qt.shape, q.dtype),
         in_specs=in_specs(ops),
         out_specs=pl.BlockSpec((1, block_q, d_p), ops.q_block),
@@ -964,8 +1096,7 @@ def _flash_bwd(q, k, v, o, lse, g, causal: bool, block_q: int,
     kv_spec = pl.BlockSpec((1, block_k, d_p), by_group.k_block)
     dk, dv = _tile_call(
         functools.partial(_flash_bwd_dkv_kernel, groups=ops.groups, **tile),
-        tile_table(*tiles, True, ops.groups),
-        tile_pairs(*tiles, True, ops.groups), b * ops.kv_heads, by_group,
+        tiles, b * ops.kv_heads, by_group, key_major=True,
         out_shape=(jax.ShapeDtypeStruct(kt.shape, k.dtype),
                    jax.ShapeDtypeStruct(vt.shape, v.dtype)),
         in_specs=in_specs(by_group),

@@ -824,6 +824,117 @@ def test_grouped_kv_through_the_three_kernels(orca_ctx, monkeypatch, groups,
     assert bool(four_d) == (head_dim % 128 != 0)
 
 
+#: the masks a diagonal tile's strips are held to: id -> (sq, sk, causal,
+#: the block-diffusion mask's (L, B, noisy) or None, the blocks in strips,
+#: whether a diagonal tile keeps dead strips), in units of a strip
+STRIP_MASKS = {
+    "causal": (4, 4, True, None, 2, True),
+    "block_diffusion": (4, 4, False, (2, True), 2, True),
+    "block_diffusion_clean_half": (4, 4, False, (4, False), 2, True),
+    "padded_key_tail": (2, 2.25, False, None, 2, True),
+    # one strip a tile: a diagonal tile has no dead strip and keeps the
+    # whole-tile body
+    "no_dead_strip": (4, 4, True, None, 1, False),
+}
+
+
+@pytest.fixture
+def strips_of_128(monkeypatch):
+    """Strips of 128, so that the interpreted kernels run at tiles of a
+    few hundred rows: what a strip is does not depend on its side. The
+    tables are cached by their arguments, which the side is not one of:
+    emptied on both sides of the test."""
+    from analytics_zoo_tpu.ops import flash_attention as fa
+
+    def empty():
+        for table in (fa.strips, fa.launch_table, fa.tile_pairs):
+            table.cache_clear()
+    empty()
+    monkeypatch.setattr(fa, "STRIP", 128)
+    yield
+    monkeypatch.undo()
+    empty()
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("head_dim", [128, 64])
+@pytest.mark.parametrize("masking", list(STRIP_MASKS))
+def test_diagonal_tiles_compute_their_live_strips(orca_ctx, monkeypatch,
+                                                  strips_of_128, masking,
+                                                  head_dim, dtype):
+    """The real kernel bodies, interpreted, where the mask leaves a
+    diagonal tile dead strips: output, logsumexp, ``dq``, ``dk``, ``dv``
+    (the logsumexp's cotangent included) against dense masked attention
+    on the same rounded inputs, with 4 query heads over one key/value
+    head in both operand layouts. The launches take ``tile_table``'s grid
+    and code each such tile by its strip pattern, and say they compute
+    fewer pairs than whole tiles; where no tile has a dead strip the
+    launches are the whole-tile launches."""
+    import jax
+    import jax.numpy as jnp
+    from analytics_zoo_tpu.ops import flash_attention as fa
+
+    monkeypatch.setenv("ZOO_PALLAS_INTERPRET", "1")
+    sq, sk, causal, bd, blocks, dead_strips = STRIP_MASKS[masking]
+    sq, sk, block = (int(n * fa.STRIP) for n in (sq, sk, blocks))
+    mask = fa.BlockDiffusionMask(bd[0] * fa.STRIP, 4, noisy=bd[1]) \
+        if bd else None
+    b, h, kv_heads, d = 1, 4, 1, head_dim
+    rng = np.random.default_rng(sq + sk + head_dim)
+
+    def draw(*shape):
+        return jnp.asarray(rng.normal(size=shape), jnp.float32).astype(dtype)
+
+    q, g = draw(b, sq, h, d), draw(b, sq, h, d)
+    k, v = draw(b, sk, kv_heads, d), draw(b, sk, kv_heads, d)
+    g_lse = jnp.asarray(rng.normal(size=(b * h, sq)), jnp.float32)
+    static = fa.static_mask(causal, mask, sq, sk)
+    allowed = jnp.ones((sq, sk), bool) if static is None \
+        else static.dense(sq, sk)
+
+    def flash(q, k, v):
+        return fa.flash_attention_with_lse(q, k, v, causal, block, block,
+                                           mask)
+
+    got, vjp = jax.vjp(flash, q, k, v)
+    dq, dk, dv = vjp((g, g_lse))
+    wide = [a.astype(jnp.float32) for a in (q, k, v, g)]
+    want, ref_vjp = jax.vjp(
+        lambda q, k, v: _dense_with_lse(q, k, v, allowed),
+        wide[0], *fa.repeat_kv_heads(*wide[:3]))
+    want_dq, want_dk, want_dv = ref_vjp((wide[3], g_lse))
+    want_dk, want_dv = (a.reshape(b, sk, kv_heads, h, d).sum(3)
+                        for a in (want_dk, want_dv))
+    for name, a, w in zip(("out", "lse", "dq", "dk", "dv"),
+                          (*got, dq, dk, dv),
+                          (*want, want_dq, want_dk, want_dv)):
+        a = np.asarray(a, np.float32)
+        if dtype == "float32":
+            np.testing.assert_allclose(a, w, rtol=2e-4, atol=2e-5,
+                                       err_msg=name)
+        else:
+            assert np.linalg.norm(a - w) < 1e-2 * np.linalg.norm(w), name
+
+    _, _, _, bq_p, bk_p, sq_p, sk_p, _ = fa._pad_blocks(
+        q[..., :1], k[..., :1], v[..., :1], block, block)
+    tiles = (sq_p // bq_p, sk_p // bk_p, bq_p, bk_p, static,
+             sk if sk_p != sk else None)
+    launches, _ = _launches(
+        lambda q, k, v: jax.vjp(flash, q, k, v)[1]((g, g_lse)), q, k, v)
+    assert len(launches) == 3
+    for key_major, said in zip((False, False, True), launches):
+        table = fa.tile_table(*tiles, key_major, h if key_major else 1)
+        launch, bodies = fa.launch_table(*tiles, key_major,
+                                         h if key_major else 1)
+        assert (np.delete(launch, 2, 1) == np.delete(table, 2, 1)).all()
+        assert bool((launch[:, 2] >= fa.STRIPS).any()) == dead_strips
+        whole = int((table[:, 2] != fa.DEAD).sum()) * bq_p * bk_p
+        computed = fa.tile_pairs(*tiles, key_major, h if key_major else 1)[0]
+        assert (computed < whole) == dead_strips
+        assert said["pairs"] == str(computed * b
+                                    * (kv_heads if key_major else h))
+
+
 #: id -> (sq, sk, block_q, block_k, the mask's constructor and arguments)
 GROUP_TABLES = {
     "causal_4x4": (512, 512, 128, 128, ("CausalMask", 0)),

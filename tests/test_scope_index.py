@@ -586,28 +586,95 @@ def test_score_pairs_counted_and_published():
     assert "zoo_flash_score_pairs" not in telemetry.snapshot()
 
 
-@pytest.mark.parametrize("sq,heads,mask,want", [
-    (8192, 64, "causal", (37748736, 33558528)),
-    (16384, 32, "block_diffusion", (83886080, 67141632))],
+#: a head's computed pairs at the two decoder cells' 1,024 x 1,024 blocks,
+#: by the side of a strip: whole tiles (what the launches computed before
+#: a diagonal tile computed its live strips alone), 128 and 256
+DECODER_CELLS_COMPUTED = {
+    "causal": {None: 37748736, 128: 34078720, 256: 34603008},
+    "block_diffusion": {None: 83886080, 128: 69206016, 256: 71303168}}
+
+
+@pytest.mark.parametrize("sq,heads,mask,allowed,share", [
+    (8192, 64, "causal", 33558528, {128: 98.47, 256: 96.98}),
+    (16384, 32, "block_diffusion", 67141632, {128: 97.02, 256: 94.16})],
     ids=["the_causal_cells_head", "the_block_diffusion_cells_head"])
-def test_score_pairs_at_the_decoder_cells_shapes(sq, heads, mask, want):
+def test_score_pairs_at_the_decoder_cells_shapes(sq, heads, mask, allowed,
+                                                 share):
     """What a launch writes, counted in numpy with no compile, at the two
     decoder cells' 1,024 x 1,024 blocks: a causal head of 8,192 positions
-    computes 36 tiles and wants 8,192 x 8,193 / 2 of their pairs (88.9%);
-    a head of the block-diffusion cell's 16,384 rows computes 80 tiles and
-    wants 2 x 33,570,816 (80.0%). Every kind of launch lists the same
-    tiles: the key-major table of a group of 8 or 4 heads counts the
-    group's heads."""
+    takes 36 tiles and wants 8,192 x 8,193 / 2 of their pairs; a head of
+    the block-diffusion cell's 16,384 rows takes 80 tiles and wants 2 x
+    33,570,816. A diagonal tile computes its live strips alone, so the
+    computed pairs are the whole tiles' (88.9% and 80.04% wanted) less the
+    dead strips: 96.98% and 94.16% wanted at 256-wide strips (98.47% and
+    97.02% at 128). Every kind
+    of launch computes the same pairs: the key-major table of a group of 8
+    or 4 heads counts the group's heads."""
     from analytics_zoo_tpu.ops import flash_attention as fa
     m = fa.CausalMask(0) if mask == "causal" \
         else fa.BlockDiffusionMask(sq // 2, 4)
     n = sq // 1024
+    computed = DECODER_CELLS_COMPUTED[mask]
+    assert 100 * allowed / computed[None] == pytest.approx(
+        88.9 if mask == "causal" else 80.04, abs=0.01)
+    want = (computed[fa.STRIP], allowed)
     assert fa.tile_pairs(n, n, 1024, 1024, m, None) == want
     for groups in (1, 4, 8):
         assert fa.tile_pairs(n, n, 1024, 1024, m, None, True, groups) \
             == (want[0] * groups, want[1] * groups)
-    assert 100 * want[1] / want[0] == pytest.approx(
-        88.9 if mask == "causal" else 80.04, abs=0.01)
+    assert 100 * want[1] / want[0] == pytest.approx(share[fa.STRIP],
+                                                    abs=0.01)
+
+
+@pytest.mark.parametrize("tile,mask", [
+    ("noisy_diagonal", "block_diffusion"),
+    ("clean_diagonal", "block_diffusion"),
+    ("noisy_over_clean", "block_diffusion"),
+    ("causal_diagonal", "causal")])
+def test_strip_patterns_of_the_decoder_cells_diagonal_tiles(tile, mask):
+    """Pure numpy, at the decoder cells' 1,024 x 1,024 blocks. The three
+    kinds of diagonal tile of the block-diffusion cell and the causal
+    cell's: per query strip the live key strips, and per key strip (the
+    ``dk/dv`` launch's key-major table) the live query strips — a noisy
+    strip sees its own strip alone; a clean strip, and a noisy one over
+    the clean copy, the strips up to its own — each range crossing the
+    mask's edge. Every diagonal tile of the cell is such a tile; the
+    launch's table is ``tile_table``'s row for row, in its order, with
+    the diagonal tiles coded by their pattern."""
+    from analytics_zoo_tpu.ops import flash_attention as fa
+    n = 1024 // fa.STRIP
+    if mask == "causal":
+        m, tiles, qi, ki = fa.CausalMask(0), 8, 3, 3
+    else:
+        m, tiles = fa.BlockDiffusionMask(8192, 4), 16
+        qi, ki = {"noisy_diagonal": (2, 2), "clean_diagonal": (10, 10),
+                  "noisy_over_clean": (2, 10)}[tile]
+    if tile == "noisy_diagonal":
+        by_query = by_key = [(r, r + 1, True) for r in range(n)]
+    else:
+        by_query = [(0, r + 1, True) for r in range(n)]
+        by_key = [(c, n, True) for c in range(n)]
+    assert fa.strips(qi, ki, 1024, 1024, m, None) == tuple(by_query)
+    assert fa.strips(qi, ki, 1024, 1024, m, None, True) == tuple(by_key)
+    assert fa._segments(tuple(by_query)) == tuple(
+        (r * fa.STRIP, (r + 1) * fa.STRIP, lo * fa.STRIP, hi * fa.STRIP,
+         True) for r, (lo, hi, _) in enumerate(by_query))
+    for key_major, groups in ((False, 1), (True, 1), (True, 4)):
+        table = fa.tile_table(tiles, tiles, 1024, 1024, m, None, key_major,
+                              groups)
+        launch, bodies = fa.launch_table(tiles, tiles, 1024, 1024, m, None,
+                                         key_major, groups)
+        assert launch.shape == table.shape
+        assert (np.delete(launch, 2, 1) == np.delete(table, 2, 1)).all()
+        diagonal = table[:, 2] == fa.DIAGONAL
+        assert (launch[~diagonal, 2] == table[~diagonal, 2]).all()
+        assert (launch[diagonal, 2] >= fa.STRIPS).all()
+        want = by_key if key_major else by_query
+        codes = dict(bodies)
+        code = launch[(launch[:, 0] == qi) & (launch[:, 1] == ki), 2][0]
+        assert codes[int(code)] == fa._segments(tuple(want))
+        assert sorted(codes) == [fa.INTERIOR] + list(range(
+            fa.STRIPS, fa.STRIPS + (1 if mask == "causal" else 2)))
 
 
 @pytest.mark.parametrize("sq,sk,bq,bk,mask", [
@@ -621,7 +688,9 @@ def test_allowed_pairs_match_a_brute_force_count(sq, sk, bq, bk, mask):
     """The allowed pairs of every live tile, summed over the table, equal
     the pairs of the padded score matrix that neither the mask nor the
     padded key tail excludes, counted whole in numpy; the computed pairs
-    are the table's steps times a tile."""
+    are, for each live tile, its live strips where some of its strips
+    allow no pair and the live ones of each row lie side by side, and the
+    whole tile otherwise — told from the same brute-force matrix."""
     from analytics_zoo_tpu.ops import flash_attention as fa
     m = {"causal": fa.CausalMask(sk - sq), None: None,
          "block_diffusion": fa.BlockDiffusionMask(sq // 2, 4),
@@ -634,9 +703,29 @@ def test_allowed_pairs_match_a_brute_force_count(sq, sk, bq, bk, mask):
     allowed = (k < sk) & (np.ones_like(q, bool) if m is None
                           else ~m.excluded(q, k))
     table = fa.tile_table(nq, nk, bq, bk, m, kv_len)
+    strip = fa.STRIP
+    for key_major in (False, True):
+        computed = 0
+        for qi, ki, kind in fa.tile_table(nq, nk, bq, bk, m, kv_len,
+                                          key_major).tolist():
+            if kind == fa.DEAD:
+                continue
+            tile = allowed[qi * bq:(qi + 1) * bq, ki * bk:(ki + 1) * bk]
+            whole = bq * bk
+            if kind == fa.DIAGONAL and not bq % strip and not bk % strip:
+                live = tile.reshape(bq // strip, strip, bk // strip,
+                                    strip).any(axis=(1, 3))
+                rows = live.T if key_major else live
+                side_by_side = all(
+                    np.ptp(np.flatnonzero(r)) + 1 == r.sum() for r in rows
+                    if r.any())
+                if not live.all() and side_by_side:
+                    whole = int(live.sum()) * strip * strip
+            computed += whole
+        assert fa.tile_pairs(nq, nk, bq, bk, m, kv_len, key_major) == (
+            computed, int(allowed.sum()))
     live = int((table[:, 2] != fa.DEAD).sum())
-    assert fa.tile_pairs(nq, nk, bq, bk, m, kv_len) == (
-        live * bq * bk, int(allowed.sum()))
+    assert computed <= live * bq * bk
 
 
 def test_fit_publishes_the_counts_where_metrics_are_scraped(orca_ctx):

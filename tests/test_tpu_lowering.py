@@ -77,14 +77,17 @@ def test_flash_with_lse_grad_lowers(b, s, h, d, causal, kv):
     lowers_for_tpu(jax.grad(loss, argnums=(0, 1, 2)), *_qkv(b, s, h, d, kv))
 
 
-@pytest.mark.parametrize("shape,kv,masking", [
-    ((2, 8192, 32, 64), 8, "causal"),
-    ((1, 16384, 32, 128), 4, "block_diffusion")],
+@pytest.mark.parametrize("shape,kv,masking,strips_pairs", [
+    ((2, 8192, 32, 64), 8, "causal", {128: 34078720, 256: 34603008}),
+    ((1, 16384, 32, 128), 4, "block_diffusion",
+     {128: 69206016, 256: 71303168})],
     ids=["the_causal_cells_layer", "the_block_diffusion_cells_layer"])
-def test_each_flash_call_carries_its_score_pairs(shape, kv, masking):
+def test_each_flash_call_carries_its_score_pairs(shape, kv, masking,
+                                                 strips_pairs):
     """The three launches of each decoder cell's attention at the cell's
     size and 1,024 x 1,024 blocks, lowered for the TPU: every call's
-    ``kernel_metadata`` says the score pairs its live tiles compute and
+    ``kernel_metadata`` says the score pairs its live strips compute (a
+    head's interior tiles whole and its diagonal tiles' live strips) and
     those the mask allows — one head's ``tile_pairs`` times the batch's
     query heads, whichever heads the launch's grid runs over — beside its
     grid steps, one head's table times the heads the grid runs over."""
@@ -105,6 +108,7 @@ def test_each_flash_call_carries_its_score_pairs(shape, kv, masking):
     n = s // 1024
     computed, allowed = fa.tile_pairs(n, n, 1024, 1024,
                                       mask or fa.CausalMask(0), None)
+    assert computed == strips_pairs[fa.STRIP]
     for name, metadata in said.items():
         assert metadata["pairs"] == str(computed * b * h), name
         assert metadata["allowed"] == str(allowed * b * h), name
