@@ -439,10 +439,14 @@ _KERNEL_METADATA = re.compile(r"kernel_metadata=\{[^{}]*\}")
 FLASH_KERNELS = {"flash_fwd": b"_flash_fwd_kernel",
                  "flash_bwd_dq": b"_flash_bwd_dq_kernel",
                  "flash_bwd_dkv": b"_flash_bwd_dkv_kernel"}
-#: ... and the q/k norm-and-rotary kernels (``ops/norm_rotary.py``)
+#: ... the q/k norm-and-rotary kernels (``ops/norm_rotary.py``) and the
+#: expert layer's combine (``ops/moe_combine.py``: the window's rows
+#: packed one to a tile, then each token's rows summed)
 KERNEL_FUNCTIONS = {**FLASH_KERNELS,
                     "norm_rotary_fwd": b"_norm_rotary_fwd_kernel",
-                    "norm_rotary_bwd": b"_norm_rotary_bwd_kernel"}
+                    "norm_rotary_bwd": b"_norm_rotary_bwd_kernel",
+                    "moe_pack_rows": b"_pack_rows_kernel",
+                    "moe_sum_rows": b"_sum_rows_kernel"}
 #: label ``kind`` of ``zoo_flash_grid_steps``: the kinds of tile a flash
 #: kernel's launch lists (``flash_attention.TILE_KINDS``)
 TILE_KINDS = ("interior", "diagonal", "dead")
@@ -501,16 +505,19 @@ def _launches_of(hlo) -> List[Launch]:
 
 def count_kernel_calls(launches) -> Dict[str, int]:
     """``{"flash_fwd": n, "flash_bwd_dq": n, "flash_bwd_dkv": n,
-    "norm_rotary_fwd": n, "norm_rotary_bwd": n}``: the custom calls of
-    each kernel in the optimized HLO of a TPU executable (``launches``:
-    what :func:`_kernel_launches` found in it, or the text). A training
-    step reads flash 1, 1, 1 per attention layer that takes the kernels;
-    2, 1, 1 where a ``jax.checkpoint`` around the layer does not keep
+    "norm_rotary_fwd": n, "norm_rotary_bwd": n, "moe_pack_rows": n,
+    "moe_sum_rows": n}``: the custom calls of each kernel in the optimized
+    HLO of a TPU executable (``launches``: what :func:`_kernel_launches`
+    found in it, or the text). A training step reads flash 1, 1, 1 per
+    attention layer that takes the kernels; 2, 1, 1 where a
+    ``jax.checkpoint`` around the layer does not keep
     ``flash_attention.RESIDUAL_NAMES`` and the backward pass launches the
     forward kernel again. Norm-and-rotary 4, 2 per rematerialised layer
-    that runs it (q and k, forward and recomputed; their backward). All 0
-    off the TPU (the interpreter inlines a kernel) and where the work is
-    XLA's."""
+    that runs it (q and k, forward and recomputed; their backward). The
+    expert layer's combine 4, 4 per dropless layer that runs it: the
+    first window's forward and backward, and the same two inside the
+    second window's ``cond``, which a step seldom takes. All 0 off the
+    TPU (the interpreter inlines a kernel) and where the work is XLA's."""
     counts = dict.fromkeys(KERNEL_FUNCTIONS, 0)
     for _, kernel, _ in _launches_of(launches):
         if kernel in counts:
@@ -639,7 +646,8 @@ def _publish_counts(name: str, counts: Dict[str, int]) -> None:
 
 def step_counts(name: str) -> Optional[Dict[str, int]]:
     """``{"held_values", "erfc", "mask", "flash_fwd", "flash_bwd_dq",
-    "flash_bwd_dkv", "norm_rotary_fwd", "norm_rotary_bwd"}`` of the
+    "flash_bwd_dkv", "norm_rotary_fwd", "norm_rotary_bwd",
+    "moe_pack_rows", "moe_sum_rows"}`` of the
     executable last compiled ahead of time under
     ``name``, and ``"<kernel>/<kind>"``, ``"<kernel>/pairs"``,
     ``"<kernel>/allowed"`` and ``"<kernel>@<layout>,<kv>"`` for each flash

@@ -24,6 +24,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from analytics_zoo_tpu.ops import moe_combine
 from analytics_zoo_tpu.parallel import mesh as mesh_lib
 
 
@@ -163,29 +164,34 @@ ROUTER_SCORINGS = ("sigmoid_bias", "softmax")
 
 
 @jax.custom_vjp
-def _take_rows(x, tok, rows, inside):
+def _take_rows(x, tok, rows, inside, ends):
     """``x[tok]``: the window's rows gathered from the tokens. ``rows``
     [N, k] says where in the window each assignment of a token sits and
     ``inside`` [N, k] whether it sits there at all, so that the gradient
-    is a gather too (a scatter-add over 2048-wide rows otherwise)."""
+    is a gather too (a scatter-add over 2048-wide rows otherwise);
+    ``ends`` [G] where each held expert's rows of the window end."""
     return x[tok]
 
 
-def _take_rows_fwd(x, tok, rows, inside):
-    return x[tok], (rows, inside)
+def _take_rows_fwd(x, tok, rows, inside, ends):
+    return x[tok], (rows, inside, ends)
 
 
 def _take_rows_bwd(res, g):
-    rows, inside = res
-    return _sum_rows(g, rows, inside), None, None, None
+    rows, inside, ends = res
+    return _sum_rows(g, rows, inside, ends), None, None, None, None
 
 
 _take_rows.defvjp(_take_rows_fwd, _take_rows_bwd)
 
 
-def _sum_rows(y, rows, inside):
+def _sum_rows(y, rows, inside, ends):
     """[N, H]: for every token the sum of the window's rows its
-    assignments sit at."""
+    assignments sit at (the rows sorted by held expert, ``ends``, then by
+    token): pallas kernels that read those rows alone where
+    ``moe_combine.engages``, else ``k`` gathers."""
+    if moe_combine.engages(y):
+        return moe_combine.sum_rows(y, jnp.where(inside, rows, -1), ends)
     out = 0
     for j in range(rows.shape[1]):
         out = out + jnp.where(inside[:, j, None], y[rows[:, j]], 0)
@@ -193,19 +199,19 @@ def _sum_rows(y, rows, inside):
 
 
 @jax.custom_vjp
-def _put_rows(y, tok, rows, inside):
+def _put_rows(y, tok, rows, inside, ends):
     """The inverse of :func:`_take_rows`: ``y`` [M, H], one row per
     assignment of the window, summed into its token."""
-    return _sum_rows(y, rows, inside)
+    return _sum_rows(y, rows, inside, ends)
 
 
-def _put_rows_fwd(y, tok, rows, inside):
-    return _sum_rows(y, rows, inside), (tok,)
+def _put_rows_fwd(y, tok, rows, inside, ends):
+    return _sum_rows(y, rows, inside, ends), (tok,)
 
 
 def _put_rows_bwd(res, g):
     (tok,) = res
-    return g[tok], None, None, None
+    return g[tok], None, None, None, None
 
 
 _put_rows.defvjp(_put_rows_fwd, _put_rows_bwd)
@@ -257,14 +263,16 @@ def held_expert_ffn(x, ids, weights, w1, w3, w2, held, n_experts: int):
         inside = is_held & (position >= start) & (position < start + size)
         rows = jnp.clip(position - start, 0, size - 1)
         valid = (jnp.arange(start, start + size) < n_held)[:, None]
-        sizes = jnp.clip(ends, start, start + size) \
-            - jnp.clip(starts, start, start + size)
+        upto = jnp.clip(ends, start, start + size)
+        sizes = upto - jnp.clip(starts, start, start + size)
         # the window's rows that hold no held assignment go to the last
         # group (their results are cleared below): the grouped products
         # skip rows outside every group, and their time would follow the
         # routing from step to step and from seed to seed
         sizes = sizes.at[-1].add(size - jnp.sum(sizes))
-        xs = _take_rows(x, tok, rows, inside)
+        # where each held expert's rows of the window end
+        in_window = upto - start
+        xs = _take_rows(x, tok, rows, inside, in_window)
         with jax.named_scope("products"):
             # rows past the last group hold whatever a product left there:
             # each result is cleared before anything is computed from it
@@ -275,7 +283,7 @@ def held_expert_ffn(x, ids, weights, w1, w3, w2, held, n_experts: int):
             y = jnp.where(valid, jax.lax.ragged_dot(
                 jax.nn.silu(a) * b, w2.astype(xs.dtype), sizes), 0)
         y = y * flat_weights[at][:, None].astype(y.dtype)
-        return _put_rows(y, tok, rows, inside)
+        return _put_rows(y, tok, rows, inside, in_window)
 
     main = nk if g == n_experts else min(
         nk, -(-int(WINDOW_FACTOR * nk * g / n_experts) // 512) * 512)

@@ -388,7 +388,8 @@ def test_v5e_launches_the_forward_kernel_once_where_its_residuals_are_kept(
         text = grad.lower(x, w).compile().as_text()
     assert profiling.count_kernel_calls(text) == {
         "flash_fwd": forward_launches, "flash_bwd_dq": 1,
-        "flash_bwd_dkv": 1, "norm_rotary_fwd": 0, "norm_rotary_bwd": 0}
+        "flash_bwd_dkv": 1, "norm_rotary_fwd": 0, "norm_rotary_bwd": 0,
+        "moe_pack_rows": 0, "moe_sum_rows": 0}
     # each launch lists its live tiles alone: two heads of one interior
     # and two diagonal tiles at 2,048 positions and 1,024 x 1,024 blocks
     steps = profiling.count_flash_grid_steps(text)
@@ -621,7 +622,8 @@ def test_v5e_the_layer_hands_q_and_k_to_the_kernels_as_rows(one_chip,
         text = grad.lower(params, x).compile().as_text()
     assert profiling.count_kernel_calls(text) == {
         "flash_fwd": 1, "flash_bwd_dq": 1, "flash_bwd_dkv": 1,
-        "norm_rotary_fwd": 4, "norm_rotary_bwd": 2}
+        "norm_rotary_fwd": 4, "norm_rotary_bwd": 2,
+        "moe_pack_rows": 0, "moe_sum_rows": 0}
     shapes = "|".join(re.escape(f"[1,{s},{w}]") for w in (h * d, g * d)) \
         + "|" + "|".join(re.escape(f"[1,{s},{n},{d}]") for n in (h, g))
     moved = re.findall(rf"= bf16(?:{shapes})\S* (copy|reshape|transpose)\(",
@@ -654,3 +656,64 @@ def test_v5e_holds_the_untuned_blocks_at_every_head_row(one_chip, head_dim,
         argnums=(0, 1, 2)))
     with compiled_outside_the_cache():
         grad.lower(q, q, q).compile()
+
+
+@pytest.mark.parametrize("kernels", [True, False],
+                         ids=["the_kernels", "the_gathers"])
+def test_v5e_compiles_the_expert_layers_combine_at_the_cells_widths(
+        one_chip, monkeypatch, kernels):
+    """One dropless expert layer of the block-diffusion cell (16,384
+    tokens, hidden 2,048, 16 of 128 experts of width 768 held, ``k`` 8),
+    its gradient compiled for the chip under the 16 MiB of scoped VMEM the
+    chip gives a kernel: the combine (``_put_rows``' forward,
+    ``_take_rows``' backward) is the pack and sum kernels, a launch of
+    each per window each way (the second window's inside the ``cond``),
+    and the entry computation holds no ``bf16[16384, 2048]`` gather. The
+    gathers it replaces read 16 there: ``k`` a direction."""
+    import dataclasses
+
+    import jax.experimental.pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    from analytics_zoo_tpu.common import profiling
+    from analytics_zoo_tpu.ops import flash_attention as fa
+    from analytics_zoo_tpu.ops import moe, moe_combine
+
+    call = pl.pallas_call
+
+    def stated(*args, compiler_params=None, **kwargs):
+        params = dataclasses.replace(
+            compiler_params or pltpu.CompilerParams(),
+            vmem_limit_bytes=16 << 20)
+        return call(*args, compiler_params=params, **kwargs)
+
+    monkeypatch.setattr(pl, "pallas_call", stated)
+    monkeypatch.setattr(fa, "on_tpu", lambda: True)
+    if not kernels:
+        monkeypatch.setattr(moe_combine, "engages", lambda y: False)
+    n, hidden, width, held, experts, k = 16384, 2048, 768, 16, 128, 8
+
+    def loss(x, w1, w3, w2, logits):
+        ids, weights = moe.sigmoid_top_k_routing(
+            logits, jnp.zeros((experts,)), k)
+        out, _, _ = moe.held_expert_ffn(x, ids, weights, w1, w3, w2,
+                                        tuple(range(held)), experts)
+        return (out.astype(jnp.float32) ** 2).sum()
+
+    def aval(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    grad = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4)))
+    with compiled_outside_the_cache():
+        text = grad.lower(
+            aval((n, hidden), jnp.bfloat16), aval((held, hidden, width)),
+            aval((held, hidden, width)), aval((held, width, hidden)),
+            aval((n, experts))).compile().as_text()
+    launches = 4 if kernels else 0
+    assert profiling.count_kernel_calls(text) == {
+        **dict.fromkeys(profiling.KERNEL_FUNCTIONS, 0),
+        "moe_pack_rows": launches, "moe_sum_rows": launches}
+    entry = text.split("\nENTRY", 1)[1]
+    gathers = re.findall(r'= bf16\[16384,2048\]\S* fusion\(.*op_name="[^"]*'
+                         r'/gather"', entry)
+    assert len(gathers) == (0 if kernels else 2 * k)

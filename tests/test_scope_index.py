@@ -264,10 +264,12 @@ REMAT_LAYER = os.path.join(os.path.dirname(__file__), "fixtures",
 
 @pytest.mark.parametrize("fixture,want", [
     (REMAT_LAYER, {"flash_fwd": 2, "flash_bwd_dq": 1, "flash_bwd_dkv": 1,
-                   "norm_rotary_fwd": 0, "norm_rotary_bwd": 0}),
+                   "norm_rotary_fwd": 0, "norm_rotary_bwd": 0,
+                   "moe_pack_rows": 0, "moe_sum_rows": 0}),
     (PARENT_FUSIONS, {"flash_fwd": 0, "flash_bwd_dq": 0,
                       "flash_bwd_dkv": 0, "norm_rotary_fwd": 0,
-                      "norm_rotary_bwd": 0})],
+                      "norm_rotary_bwd": 0, "moe_pack_rows": 0,
+                      "moe_sum_rows": 0})],
     ids=["a_layer_that_keeps_products_alone", "no_kernel"])
 def test_kernel_calls_counted_and_published(fixture, want):
     """The entry computation of ``tanh(x @ w) -> flash_attention -> @ w``
@@ -362,7 +364,8 @@ def test_flash_layouts_counted_and_published(said, want):
     assert profiling.count_flash_grid_steps(text) == steps
     assert profiling.count_kernel_calls(text) == {
         "flash_fwd": 1, "flash_bwd_dq": 1, "flash_bwd_dkv": 1,
-        "norm_rotary_fwd": 0, "norm_rotary_bwd": 0}
+        "norm_rotary_fwd": 0, "norm_rotary_bwd": 0,
+        "moe_pack_rows": 0, "moe_sum_rows": 0}
     profiling.note_executable("canned", _CannedExe(text))
     counts = profiling.step_counts("canned")
     assert {k: n for k, n in counts.items() if "@" in k} == want
@@ -382,7 +385,8 @@ def test_an_instruction_printed_over_several_lines_keeps_its_scope():
     assert 'kernel_metadata={\n"dead":"0"' in text
     assert profiling.count_kernel_calls(text) == {
         "flash_fwd": 1, "flash_bwd_dq": 1, "flash_bwd_dkv": 1,
-        "norm_rotary_fwd": 0, "norm_rotary_bwd": 0}
+        "norm_rotary_fwd": 0, "norm_rotary_bwd": 0,
+        "moe_pack_rows": 0, "moe_sum_rows": 0}
     kernels = {name: (e["scope"], e["phase"])
                for name, e in profiling.parse_scope_index(text).items()
                if e["opcode"] == "custom-call"}
@@ -393,14 +397,16 @@ def test_an_instruction_printed_over_several_lines_keeps_its_scope():
 
 
 def test_the_counted_kernel_functions_are_the_packages_kernels():
-    from analytics_zoo_tpu.ops import flash_attention, norm_rotary
+    from analytics_zoo_tpu.ops import flash_attention, moe_combine, norm_rotary
     for function in profiling.FLASH_KERNELS.values():
         assert callable(getattr(flash_attention, function.decode()))
-    others = set(profiling.KERNEL_FUNCTIONS) - set(profiling.FLASH_KERNELS)
-    assert others == {"norm_rotary_fwd", "norm_rotary_bwd"}
-    for kernel in others:
+    others = {"norm_rotary_fwd": norm_rotary, "norm_rotary_bwd": norm_rotary,
+              "moe_pack_rows": moe_combine, "moe_sum_rows": moe_combine}
+    assert set(profiling.KERNEL_FUNCTIONS) - set(profiling.FLASH_KERNELS) \
+        == set(others)
+    for kernel, module in others.items():
         assert callable(getattr(
-            norm_rotary, profiling.KERNEL_FUNCTIONS[kernel].decode()))
+            module, profiling.KERNEL_FUNCTIONS[kernel].decode()))
     assert profiling.TILE_KINDS == flash_attention.TILE_KINDS
 
 
@@ -440,7 +446,8 @@ def test_norm_rotary_launches_are_counted_beside_the_flash_kernels():
     text = _norm_rotary_step(b"_norm_rotary_fwd_kernel\x00",
                              b"_norm_rotary_bwd_kernel\x00")
     want = {"flash_fwd": 1, "flash_bwd_dq": 0, "flash_bwd_dkv": 0,
-            "norm_rotary_fwd": 4, "norm_rotary_bwd": 2}
+            "norm_rotary_fwd": 4, "norm_rotary_bwd": 2,
+            "moe_pack_rows": 0, "moe_sum_rows": 0}
     assert profiling.count_kernel_calls(text) == want
     assert profiling.count_flash_grid_steps(text) == {
         "flash_fwd/interior": 2, "flash_fwd/diagonal": 4, "flash_fwd/dead": 0}

@@ -10,6 +10,9 @@ compiles and checks on the device. (Mosaic's own compile still needs the
 chip; this guards the stage before it.)
 """
 
+import base64
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -91,8 +94,6 @@ def test_each_flash_call_carries_its_score_pairs(shape, kv, masking,
     those the mask allows — one head's ``tile_pairs`` times the batch's
     query heads, whichever heads the launch's grid runs over — beside its
     grid steps, one head's table times the heads the grid runs over."""
-    import re
-
     b, s, h, d = shape
     mask = fa.BlockDiffusionMask(s // 2, 4) if masking != "causal" else None
     grad = jax.grad(lambda q, k, v: _sq(fa.flash_attention(
@@ -144,6 +145,44 @@ def test_held_experts_grouped_products_lower_at_the_published_widths():
     text = exported.mlir_module()
     assert "ragged_dot" in text
     assert "stablehlo.case" in text or "stablehlo.if" in text
+
+
+@pytest.mark.parametrize("hidden,width,held,experts,k", [
+    (2048, 768, 16, 128, 8), (2048, 1792, 8, 32, 4)],
+    ids=["sdar_widths", "lfm2_widths"])
+def test_held_expert_ffn_combines_through_the_kernels(monkeypatch, hidden,
+                                                      width, held, experts,
+                                                      k):
+    """The dropless expert layer at each decoder cell's widths over 16,384
+    tokens, forward and gradient, on a TPU: the combine (``_put_rows``'
+    forward, ``_take_rows``' backward) lowers to the pack and sum kernels
+    of ``ops/moe_combine.py``: a launch of each for each window forward,
+    and as many again backward (the second window's under the ``cond``)."""
+    from analytics_zoo_tpu.ops import moe
+
+    monkeypatch.setattr(fa, "on_tpu", lambda: True)
+    n = 16384
+
+    def layer(x, w1, w3, w2, logits):
+        ids, weights = moe.sigmoid_top_k_routing(
+            logits, jnp.zeros((experts,)), k)
+        return moe.held_expert_ffn(x, ids, weights, w1, w3, w2,
+                                   tuple(range(held)), experts)[0]
+
+    avals = (S((n, hidden), jnp.bfloat16), S((held, hidden, width),
+                                             jnp.float32),
+             S((held, hidden, width), jnp.float32),
+             S((held, width, hidden), jnp.float32),
+             S((n, experts), jnp.float32))
+    for fn, launches in ((layer, 2), (jax.grad(
+            lambda *a: _sq(layer(*a)), argnums=(0, 1, 2, 3)), 4)):
+        exported = export.export(jax.jit(fn), platforms=("tpu",))(*avals)
+        bodies = [base64.b64decode(body) for body in re.findall(
+            r'tpu_custom_call.*?\\22body\\22: \\22([A-Za-z0-9+/=]*)',
+            exported.mlir_module())]
+        assert len(bodies) == 2 * launches
+        for kernel in (b"_pack_rows_kernel", b"_sum_rows_kernel"):
+            assert sum(kernel in body for body in bodies) == launches
 
 
 def test_flash_small_block_q_is_widened_to_the_lane():
@@ -239,7 +278,6 @@ def test_paged_attention_lowers(dtype, page_size, dim):
 def test_every_pallas_call_in_ops_is_covered():
     """A new kernel must join this file: count the call sites."""
     import os
-    import re
 
     ops_dir = os.path.dirname(fa.__file__)
     sites = {}
@@ -250,7 +288,8 @@ def test_every_pallas_call_in_ops_is_covered():
             if n:
                 sites[name] = n
     assert sites == {"embedding_bag.py": 2, "flash_attention.py": 1,
-                     "norm_rotary.py": 2, "paged_attention.py": 2}, sites
+                     "moe_combine.py": 2, "norm_rotary.py": 2,
+                     "paged_attention.py": 2}, sites
     # flash attention's one site is ``_tile_call``, which the forward,
     # ``dq`` and ``dk/dv`` launches go through
     with open(fa.__file__) as fh:
