@@ -296,3 +296,94 @@ class GroupedQueryAttention(nn.Module):
         return nn.Dense(hidden, use_bias=False, dtype=self.dtype,
                         kernel_init=self.kernel_init,
                         name="out")(out.reshape(b, s, h * d))
+
+
+# ------------------------------------------------------ latent attention
+
+def deinterleave(x):
+    """The channels of ``x`` [..., d] in pairs ``(2i, 2i + 1)`` laid out
+    as halves: ``[x0, x2, ..., x1, x3, ...]``, so that ``rotary_embedding``
+    (``rotate_half``) rotates each pair, as ``transformers``'
+    ``apply_rotary_pos_emb_interleave`` reorders them."""
+    d = x.shape[-1]
+    return x.reshape(*x.shape[:-1], d // 2, 2).swapaxes(-1, -2).reshape(
+        x.shape)
+
+
+class LatentAttention(nn.Module):
+    """Multi-head latent attention (DeepSeek-V2, no query compression)
+    over the ``num_heads`` heads this layer holds, causal, with a
+    head-wise output gate. Per token ``x``, per head:
+
+        [q_nope | q_rope] = W_q x                       (qk_nope + qk_rope)
+        [c | k_rope] = W_kv_a x;  c = RMSNorm(c)        (kv_lora_rank + qk_rope)
+        [k_nope | v] = W_kv_b c                         (qk_nope + v_head)
+        q, k = RMSNorm over each head's qk_nope + qk_rope, then rotary
+               positions on the qk_rope channels, pairs interleaved
+               (``deinterleave``); ``k_rope`` is one for all heads
+        a = softmax_causal(q kᵀ / sqrt(qk_nope + qk_rope)) v
+        y = W_o [a · σ(w_gᵀ x)]                         (a gate a head)
+
+    The latent ``c`` serves every head, so ``W_kv_a`` is whole on every
+    chip that holds some of the heads; the shares of a partition of the
+    heads add up to the whole layer. Projections ``q``, ``kv_a``,
+    ``kv_b``, ``gate``, ``o``; norms ``kv_a_norm``, ``q_norm``,
+    ``k_norm``. The value head is narrower than the query/key head, and
+    the flash kernels take it at its own width: on a TPU or under the
+    interpreter the kernels at ``autotune.untuned_blocks`` (no verdict is
+    looked up: the tuner times a value width equal to the key's only),
+    the dense einsum chain elsewhere."""
+
+    num_heads: int
+    qk_nope_dim: int
+    qk_rope_dim: int
+    v_head_dim: int
+    kv_lora_rank: int
+    rope_theta: float = 10000.0
+    norm_eps: float = 1e-6
+    dtype: Optional[jnp.dtype] = None
+    kernel_init: nn.initializers.Initializer = nn.initializers.lecun_normal()
+
+    @nn.compact
+    def __call__(self, x):
+        b, s, hidden = x.shape
+        h, dn, dr = self.num_heads, self.qk_nope_dim, self.qk_rope_dim
+        dv, r = self.v_head_dim, self.kv_lora_rank
+        if None in (dn, dr, dv, r):
+            raise ValueError("latent attention needs qk_nope_dim, "
+                             "qk_rope_dim, v_head_dim and kv_lora_rank")
+
+        def dense(name, features, y):
+            return nn.Dense(features, use_bias=False, dtype=self.dtype,
+                            kernel_init=self.kernel_init, name=name)(y)
+
+        def norm(name, y):
+            return nn.RMSNorm(epsilon=self.norm_eps, dtype=self.dtype,
+                              name=name)(y)
+
+        q = dense("q", h * (dn + dr), x).reshape(b, s, h, dn + dr)
+        c, k_rope = jnp.split(dense("kv_a", r + dr, x), [r], axis=-1)
+        kv = dense("kv_b", h * (dn + dv), norm("kv_a_norm", c)).reshape(
+            b, s, h, dn + dv)
+        k = jnp.concatenate(
+            [kv[..., :dn], jnp.broadcast_to(k_rope[:, :, None, :],
+                                            (b, s, h, dr))], -1)
+        q, k = norm("q_norm", q), norm("k_norm", k)
+
+        def rotate(y):
+            return jnp.concatenate([y[..., :dn], rotary_embedding(
+                deinterleave(y[..., dn:]), self.rope_theta)], -1)
+
+        from analytics_zoo_tpu.ops import autotune
+        from analytics_zoo_tpu.ops.flash_attention import (flash_attention,
+                                                           on_tpu,
+                                                           pallas_interpret)
+        q, k, v = rotate(q), rotate(k), kv[..., dn:]
+        if on_tpu() or pallas_interpret():
+            out = flash_attention(q, k, v, True, *autotune.untuned_blocks(
+                dn + dr, q.dtype))
+        else:
+            out = _reference_attention(q, k, v, causal=True)
+        gate = jax.nn.sigmoid(dense("gate", h, x).astype(jnp.float32))
+        out = (out * gate[..., None]).astype(out.dtype)
+        return dense("o", hidden, out.reshape(b, s, h * dv))

@@ -133,12 +133,13 @@ def repeat_kv_heads(q, k, v):
 
 def blockwise_attention(q, k, v, causal: bool = False, block_k: int = 128,
                         return_lse: bool = False, mask=None):
-    """q,k,v: [b, s, h, d] → [b, s, h, d]; O(s·block_k) memory.
+    """q, k: [b, s, h, d], v: [b, s, h, d_v] → [b, s, h, d_v];
+    O(s·block_k) memory.
     ``return_lse``: also return the per-row logsumexp as [b·h, s] fp32
     (the layout the pallas kernels use). ``mask``: a static ``TileMask``
     in place of ``causal`` (``static_mask``)."""
     b, sq, h, d = q.shape
-    sk = k.shape[1]
+    sk, dv = k.shape[1], v.shape[3]
     mask = static_mask(causal, mask, sq, sk)
     block_k = min(block_k, sk)
     nk = (sk + block_k - 1) // block_k
@@ -178,11 +179,11 @@ def blockwise_attention(q, k, v, causal: bool = False, block_k: int = 128,
             "bhqk,bkhd->bhqd", p, v_blk.astype(jnp.float32))
         return (o_new, m_new, l_new), None
 
-    o0 = jnp.zeros((b, h, sq, d), jnp.float32)
+    o0 = jnp.zeros((b, h, sq, dv), jnp.float32)
     m0 = jnp.full((b, h, sq), NEG_INF, jnp.float32)
     l0 = jnp.zeros((b, h, sq), jnp.float32)
     k_blocks = k.reshape(b, nk, block_k, h, d).transpose(1, 0, 2, 3, 4)
-    v_blocks = v.reshape(b, nk, block_k, h, d).transpose(1, 0, 2, 3, 4)
+    v_blocks = v.reshape(b, nk, block_k, h, dv).transpose(1, 0, 2, 3, 4)
     (o, m, l), _ = jax.lax.scan(body, (o0, m0, l0),
                                 (k_blocks, v_blocks, jnp.arange(nk)))
     out = o / jnp.maximum(l, 1e-37)[..., None]
@@ -730,7 +731,8 @@ def _pad_blocks(q, k, v, block_q: int, block_k: int):
     """Clamp blocks to the (tile-rounded) sequence lengths, then pad seq
     dims to the block grid and the head dim to the lane width (nothing to
     pad at a head of 128 lanes or a multiple). Returns the padded
-    ``[b, seq, heads, d]`` tensors, effective blocks, and the padded dims.
+    ``[b, seq, heads, d]`` tensors (v at its own width, padded to the
+    lanes apart), effective blocks, and the padded dims.
     ``block_q`` is a lane multiple unless one block covers the whole query
     length: the backward reads the per-row statistics as lane-dense
     ``[1, block_q]`` rows, and a TPU block's last dim is a multiple of 128
@@ -743,7 +745,7 @@ def _pad_blocks(q, k, v, block_q: int, block_k: int):
     d_p = ceil_to(d, LANE)
     q = _pad_axis(_pad_axis(q, 1, sq_p), 3, d_p)
     k = _pad_axis(_pad_axis(k, 1, sk_p), 3, d_p)
-    v = _pad_axis(_pad_axis(v, 1, sk_p), 3, d_p)
+    v = _pad_axis(_pad_axis(v, 1, sk_p), 3, ceil_to(v.shape[3], LANE))
     return q, k, v, block_q, block_k, sq_p, sk_p, d_p
 
 
@@ -838,14 +840,17 @@ def rows_layout(head_dim: int) -> bool:
     return head_dim % LANE == 0
 
 
-def _operands(q, k) -> _Operands:
-    """The layout and grouping of a call over ``q`` [b, sq, h, d] and
-    ``k`` [b, sk, kv_heads, d]."""
+def _operands(q, k, v=None) -> _Operands:
+    """The layout and grouping of a call over ``q`` [b, sq, h, d], ``k``
+    [b, sk, kv_heads, d] and ``v`` [b, sk, kv_heads, d_v] (``d`` where
+    not given): rows where both widths are whole lanes."""
     h, kv_heads = q.shape[2], k.shape[2]
     if h % kv_heads:
         raise ValueError(f"{h} query heads are no multiple of {kv_heads} "
                          "key/value heads")
-    return _Operands(h, h // kv_heads, rows_layout(q.shape[3]))
+    return _Operands(h, h // kv_heads,
+                     rows_layout(q.shape[3])
+                     and (v is None or rows_layout(v.shape[3])))
 
 
 def _flash_fwd(q, k, v, causal: bool, block_q: int, block_k: int,
@@ -854,15 +859,16 @@ def _flash_fwd(q, k, v, causal: bool, block_q: int, block_k: int,
     from jax.experimental.pallas import tpu as pltpu
 
     b, sq, h, d = q.shape
-    sk = k.shape[1]
+    sk, dv = k.shape[1], v.shape[3]
     # the mask is defined by the ORIGINAL lengths (the causal one is
     # bottom-right aligned, see blockwise_attention); padding must not
     # shift it
     mask = static_mask(causal, mask, sq, sk)
     sm_scale = 1.0 / math.sqrt(d)
-    ops = _operands(q, k)
+    ops = _operands(q, k, v)
     q, k, v, block_q, block_k, sq_p, sk_p, d_p = _pad_blocks(
         q, k, v, block_q, block_k)
+    dv_p = v.shape[3]
     # the leading grid dim is (batch, head) folded; the second is the
     # head's live tiles in the table's order: k/v stream through VMEM one
     # block per step (pallas double-buffers the HBM loads, and a block the
@@ -877,8 +883,11 @@ def _flash_fwd(q, k, v, causal: bool, block_q: int, block_k: int,
              kv_len)
     q_spec = pl.BlockSpec((1, block_q, d_p), ops.q_block)
     k_spec = pl.BlockSpec((1, block_k, d_p), ops.k_block)
-    out_shape = [jax.ShapeDtypeStruct(qt.shape, q.dtype)]
-    out_specs = [q_spec]
+    v_spec = pl.BlockSpec((1, block_k, dv_p), ops.k_block)
+    o_spec = pl.BlockSpec((1, block_q, dv_p), ops.q_block)
+    out_shape = [jax.ShapeDtypeStruct(
+        qt.shape[:2] + (h * dv_p if ops.rows else dv_p,), q.dtype)]
+    out_specs = [o_spec]
     if return_lse:
         out_shape.append(
             jax.ShapeDtypeStruct((b * h, sq_p, LANE), jnp.float32))
@@ -889,16 +898,16 @@ def _flash_fwd(q, k, v, causal: bool, block_q: int, block_k: int,
                           kv_len=kv_len),
         tiles, b * h, ops,
         out_shape=tuple(out_shape),
-        in_specs=[q_spec, k_spec, k_spec],
+        in_specs=[q_spec, k_spec, v_spec],
         out_specs=tuple(out_specs),
         scratch_shapes=[
-            pltpu.VMEM((block_q, d_p), jnp.float32),
+            pltpu.VMEM((block_q, dv_p), jnp.float32),
             pltpu.VMEM((block_q, 1), jnp.float32),
             pltpu.VMEM((block_q, 1), jnp.float32),
         ],
     )(qt, kt, vt)
     out, lse = res if return_lse else (res[0], None)
-    out = ops.unfold(out, b)[:, :sq, :, :d]     # drop padded rows/lanes
+    out = ops.unfold(out, b)[:, :sq, :, :dv]    # drop padded rows/lanes
     if return_lse:
         return out, lse[:, :sq, 0]
     return out
@@ -1038,14 +1047,15 @@ def _flash_bwd(q, k, v, o, lse, g, causal: bool, block_q: int,
     from jax.experimental.pallas import tpu as pltpu
 
     b, sq, h, d = q.shape
-    sk = k.shape[1]
+    sk, dv = k.shape[1], v.shape[3]
     mask = static_mask(causal, mask, sq, sk)
     sm_scale = 1.0 / math.sqrt(d)
-    ops = _operands(q, k)
+    ops = _operands(q, k, v)
     q, k, v, block_q, block_k, sq_p, sk_p, d_p = _pad_blocks(
         q, k, v, block_q, block_k)
-    o = _pad_axis(_pad_axis(o, 1, sq_p), 3, d_p)
-    g = _pad_axis(_pad_axis(g, 1, sq_p), 3, d_p)
+    dv_p = v.shape[3]
+    o = _pad_axis(_pad_axis(o, 1, sq_p), 3, dv_p)
+    g = _pad_axis(_pad_axis(g, 1, sq_p), 3, dv_p)
     qt, kt, vt, dot = ops.fold(q), ops.fold(k), ops.fold(v), ops.fold(g)
     # Δ = rowsum(dO ⊙ O): cheap elementwise, stays outside the kernels,
     # taken in the operands' layout; its [b·h, seq] rows alone are
@@ -1078,8 +1088,10 @@ def _flash_bwd(q, k, v, o, lse, g, causal: bool, block_q: int,
     def in_specs(maps):
         q_spec = pl.BlockSpec((1, block_q, d_p), maps.q_block)
         k_spec = pl.BlockSpec((1, block_k, d_p), maps.k_block)
+        v_spec = pl.BlockSpec((1, block_k, dv_p), maps.k_block)
+        do_spec = pl.BlockSpec((1, block_q, dv_p), maps.q_block)
         r_spec = pl.BlockSpec((1, 1, block_q), maps.q_stats)
-        return [q_spec, k_spec, k_spec, q_spec, r_spec, r_spec]
+        return [q_spec, k_spec, v_spec, do_spec, r_spec, r_spec]
 
     dq = _tile_call(
         functools.partial(_flash_bwd_dq_kernel, **tile), tiles, b * h, ops,
@@ -1093,20 +1105,21 @@ def _flash_bwd(q, k, v, o, lse, g, causal: bool, block_q: int,
     # and dv come out at the key/value heads, summed over the group in
     # the float32 accumulators
     by_group = dataclasses.replace(ops, by_group=True)
-    kv_spec = pl.BlockSpec((1, block_k, d_p), by_group.k_block)
-    dk, dv = _tile_call(
+    dk_spec = pl.BlockSpec((1, block_k, d_p), by_group.k_block)
+    dv_spec = pl.BlockSpec((1, block_k, dv_p), by_group.k_block)
+    dk, d_v = _tile_call(
         functools.partial(_flash_bwd_dkv_kernel, groups=ops.groups, **tile),
         tiles, b * ops.kv_heads, by_group, key_major=True,
         out_shape=(jax.ShapeDtypeStruct(kt.shape, k.dtype),
                    jax.ShapeDtypeStruct(vt.shape, v.dtype)),
         in_specs=in_specs(by_group),
-        out_specs=(kv_spec, kv_spec),
+        out_specs=(dk_spec, dv_spec),
         scratch_shapes=[pltpu.VMEM((block_k, d_p), jnp.float32),
-                        pltpu.VMEM((block_k, d_p), jnp.float32)],
+                        pltpu.VMEM((block_k, dv_p), jnp.float32)],
     )(qt, kt, vt, dot, lse, delta)
     return (ops.unfold(dq, b)[:, :sq, :, :d],
             ops.unfold(dk, b, kv=True)[:, :sk, :, :d],
-            ops.unfold(dv, b, kv=True)[:, :sk, :, :d])
+            ops.unfold(d_v, b, kv=True)[:, :sk, :, :dv])
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
@@ -1119,10 +1132,12 @@ def flash_attention(q, k, v, causal: bool = False, block_q: int = 128,
     ``ops.autotune.auto_flash_attention`` instead of picking blocks.
     ``mask``: a static ``TileMask`` in place of ``causal``; its dead
     tiles are no grid step in any of the three kernels. ``q``
-    [b, sq, h, d]; ``k``, ``v`` [b, sk, kv_heads, d] with ``kv_heads``
-    dividing ``h``: each serves ``h // kv_heads`` consecutive query heads,
-    is read by them and not repeated, and gets its gradient at
-    ``kv_heads``."""
+    [b, sq, h, d]; ``k`` [b, sk, kv_heads, d] and ``v`` [b, sk, kv_heads,
+    d_v] with ``kv_heads`` dividing ``h``: each serves ``h // kv_heads``
+    consecutive query heads, is read by them and not repeated, and gets
+    its gradient at ``kv_heads``. The value width ``d_v`` may differ from
+    ``d`` (latent attention): v, the output and its cotangent take their
+    own blocks, and the scores are scaled by ``1/sqrt(d)``."""
     return _flash_fwd(q, k, v, causal, block_q, block_k, mask=mask)
 
 

@@ -131,14 +131,34 @@ def ep_param_rules() -> list:
 # all of them and computes its own experts' part of the result.
 
 def sigmoid_top_k_routing(logits, bias, k: int, normalize: bool = True,
-                          scale: float = 1.0):
+                          scale: float = 1.0, n_group: int = 1,
+                          topk_group: int = 1):
     """``logits`` [N, E] -> (expert ids [N, k] int32, weights [N, k] f32).
-    Scores are ``sigmoid(logits)``; the ``k`` experts with the largest
-    ``score + bias`` are selected (the bias steers the selection only and
+    Scores are ``s = sigmoid(logits)``; the ``k`` experts with the largest
+    ``s + bias`` are selected (the bias steers the selection only and
     carries no gradient); the weights are the scores at the selected,
-    divided by their sum (+1e-6) when ``normalize``, times ``scale``."""
+    divided by their sum (+1e-6) when ``normalize``, times ``scale``.
+
+    ``n_group`` > 1 limits the choice to groups, as ``transformers``'
+    ``deepseek_v3`` ``get_topk_indices`` (``noaux_tc``): the experts in
+    ``n_group`` groups of ``E / n_group`` consecutive ids, a group scored
+    by the sum of its two largest ``s + bias``, the ``k`` chosen within
+    the ``topk_group`` best groups (the other groups' ``s + bias`` set to
+    0, as ``masked_fill`` does). That code adds 1e-20 to the sum; the 1e-6
+    here moves weights that sum to order 1 by a millionth, under the
+    rounding of a bfloat16 step."""
     scores = jax.nn.sigmoid(logits.astype(jnp.float32))
-    _, ids = jax.lax.top_k(scores + jax.lax.stop_gradient(bias), k)
+    choice = scores + jax.lax.stop_gradient(bias)
+    if n_group > 1:
+        n, e = scores.shape
+        per_group = e // n_group
+        best_two, _ = jax.lax.top_k(choice.reshape(n, n_group, per_group), 2)
+        _, kept = jax.lax.top_k(jnp.sum(best_two, -1), topk_group)
+        in_kept = jnp.any(kept[:, :, None] == jnp.arange(n_group)[None, None],
+                          axis=1)
+        choice = jnp.where(jnp.repeat(in_kept, per_group, axis=1), choice,
+                           0.0)
+    _, ids = jax.lax.top_k(choice, k)
     weights = jnp.take_along_axis(scores, ids, axis=-1)
     if normalize:
         weights = weights / (jnp.sum(weights, -1, keepdims=True) + 1e-6)
@@ -323,7 +343,8 @@ class HeldExperts(nn.Module):
 class Router(nn.Module):
     """Scores over ALL experts in float32, and the selection, by
     ``scoring`` (``ROUTER_SCORINGS``): ``"sigmoid_bias"``
-    (``sigmoid_top_k_routing``, with an ``expert_bias`` leaf) or
+    (``sigmoid_top_k_routing``, with an ``expert_bias`` leaf, within the
+    ``topk_group`` best of ``n_group`` groups where ``n_group`` > 1) or
     ``"softmax"`` (``softmax_top_k_routing``, the kernel alone)."""
 
     n_experts: int
@@ -332,6 +353,8 @@ class Router(nn.Module):
     scale: float = 1.0
     kernel_init: nn.initializers.Initializer = nn.initializers.lecun_normal()
     scoring: str = "sigmoid_bias"
+    n_group: int = 1
+    topk_group: int = 1
 
     @nn.compact
     def __call__(self, x):
@@ -349,7 +372,8 @@ class Router(nn.Module):
             return softmax_top_k_routing(logits, self.k, self.normalize,
                                          self.scale)
         return sigmoid_top_k_routing(logits, bias, self.k, self.normalize,
-                                     self.scale)
+                                     self.scale, self.n_group,
+                                     self.topk_group)
 
 
 def _declare_step_metrics() -> None:
@@ -379,11 +403,32 @@ def sow_last(module: nn.Module, name: str, value) -> None:
                init_fn=lambda: 0)
 
 
+class GatedMLP(nn.Module):
+    """``w2(silu(w1 x) * w3 x)``."""
+
+    width: int
+    dtype: Optional[object] = None
+    kernel_init: nn.initializers.Initializer = nn.initializers.lecun_normal()
+
+    @nn.compact
+    def __call__(self, x):
+        def dense(name, features):
+            return nn.Dense(features, use_bias=False, dtype=self.dtype,
+                            kernel_init=self.kernel_init, name=name)
+        gated = nn.silu(dense("w1", self.width)(x)) \
+            * dense("w3", self.width)(x)
+        return dense("w2", x.shape[-1])(gated)
+
+
 class DroplessMoE(nn.Module):
     """Sparse feed-forward block that drops no token: a ``Router`` over
     ``n_experts`` (``scoring``: sigmoid scores with a selection-only
-    ``expert_bias``, or a softmax and no bias), ``k`` experts a token,
-    gated (SwiGLU) experts of width ``d_hidden``.
+    ``expert_bias``, over all experts or within the best ``topk_group``
+    of ``n_group`` groups, or a softmax and no bias), ``k`` experts a
+    token, gated (SwiGLU) experts of width ``d_hidden``, and where
+    ``shared_width`` a gated expert of that width that every token goes
+    through, added once and unweighted (``shared``, scope ``moe/shared``;
+    every chip of a deployment computes it alike).
 
     ``held``: the ids of the experts THIS layer holds (default: all, the
     published layer). It routes over all ``n_experts``, computes
@@ -410,6 +455,9 @@ class DroplessMoE(nn.Module):
     dtype: Optional[object] = None
     kernel_init: nn.initializers.Initializer = nn.initializers.lecun_normal()
     scoring: str = "sigmoid_bias"
+    n_group: int = 1
+    topk_group: int = 1
+    shared_width: int = 0
 
     @nn.compact
     def __call__(self, x):
@@ -418,6 +466,7 @@ class DroplessMoE(nn.Module):
         tokens = x.reshape(-1, x.shape[-1])
         ids, weights = Router(self.n_experts, self.k, self.normalize,
                               self.scale, self.kernel_init, self.scoring,
+                              self.n_group, self.topk_group,
                               name="router")(tokens)
         out, counts, window = HeldExperts(
             held, self.n_experts, self.d_hidden, self.dtype,
@@ -434,4 +483,8 @@ class DroplessMoE(nn.Module):
                  window - used)
         sow_last(self, "zoo_moe_load_imbalance",
                  jnp.max(counts) * (self.n_experts / n_all))
+        if self.shared_width:
+            dtype = self.dtype or x.dtype
+            out = out + GatedMLP(self.shared_width, dtype, self.kernel_init,
+                                 name="shared")(tokens.astype(dtype))
         return out.reshape(x.shape).astype(x.dtype)

@@ -1,9 +1,12 @@
-"""Decoder-only language model whose layers are of two kinds by a
-per-layer list: a gated short convolution (``ops/short_conv.py``) or
-grouped-query attention with rotary positions (``ops/attention.py``), each
-followed by a gated (SwiGLU) feed-forward block that is dense in the first
+"""Decoder-only language model whose layers are of four kinds by a
+per-layer list (``LAYER_TYPES``): a gated short convolution
+(``ops/short_conv.py``), grouped-query attention with rotary positions
+(``ops/attention.py``), Kimi delta attention (``ops/kda.py``) or latent
+attention (``ops/attention.LatentAttention``), each followed by a gated
+(SwiGLU) feed-forward block that is dense in the first
 ``num_dense_layers`` layers and a dropless mixture of experts
-(``ops/moe.DroplessMoE``) in the rest. Pre-norm with RMSNorm, no learned
+(``ops/moe.DroplessMoE``, with a shared expert where
+``shared_expert_size``) in the rest. Pre-norm with RMSNorm, no learned
 positions, no bias, no dropout; the head is the embedding transposed
 (``tie_embeddings``, the default) or a matrix of its own:
 
@@ -11,11 +14,13 @@ positions, no bias, no dropout; the head is the embedding transposed
     h = x + op(RMSNorm(x));  x = h + ffn(RMSNorm(h))      per layer
     logits = RMSNorm(x) @ embed.T          or  RMSNorm(x) @ lm_head
 
-The stack is the hybrid family of ``transformers``' ``lfm2_moe`` and,
-with attention layers only, a softmax router and an untied head, that of
-``qwen3_moe`` / ``sdar_moe``; a layer is told which experts it holds
-(``held_experts``), so that one chip of several that share each layer
-runs its share through the same module. The attention layers are causal
+The stack is the hybrid family of ``transformers``' ``lfm2_moe``; with
+attention layers only, a softmax router and an untied head, that of
+``qwen3_moe`` / ``sdar_moe``; with delta-rule and latent attention
+layers, grouped routing and a shared expert, that of ``bailing_hybrid``.
+A layer is told which experts it holds (``held_experts``), and the
+attention layers hold ``num_heads`` heads, so that one chip of several
+that share each layer runs its share through the same module. The attention layers are causal
 over positions 0..seq-1 unless the call hands down a static ``mask`` and
 ``positions`` (``text/block_diffusion.py`` does).
 """
@@ -29,12 +34,15 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
-from analytics_zoo_tpu.ops.attention import GroupedQueryAttention
+from analytics_zoo_tpu.ops.attention import (GroupedQueryAttention,
+                                             LatentAttention)
 from analytics_zoo_tpu.ops.flash_attention import RESIDUAL_NAMES
-from analytics_zoo_tpu.ops.moe import DroplessMoE
+from analytics_zoo_tpu.ops import kda
+from analytics_zoo_tpu.ops.moe import DroplessMoE, GatedMLP
 from analytics_zoo_tpu.ops.short_conv import GatedShortConv
 
-LAYER_TYPES = ("conv", "full_attention")
+LAYER_TYPES = ("conv", "full_attention", "linear_attention",
+               "latent_attention")
 
 
 @dataclass(frozen=True)
@@ -51,6 +59,8 @@ class HybridDecoderConfig:
     num_experts_per_tok: int
     # ids of the experts each sparse layer holds; None: all of them
     held_experts: Optional[Tuple[int, ...]] = None
+    # the taps of the short convolutions (``conv`` layers, and the q, k, v
+    # convolutions of ``linear_attention``)
     conv_taps: int = 3
     norm_eps: float = 1e-5
     rope_theta: float = 1e6
@@ -65,6 +75,20 @@ class HybridDecoderConfig:
     router_scoring: str = "sigmoid_bias"
     # the head is the embedding transposed; else a matrix ``lm_head``
     tie_embeddings: bool = True
+    # group-limited routing (``moe.sigmoid_top_k_routing``): groups of
+    # experts and groups kept; 1: no groups
+    n_group: int = 1
+    topk_group: int = 1
+    # width of the expert every token goes through beside the routed
+    # ones; 0: none
+    shared_expert_size: int = 0
+    # ``latent_attention``: the latent's width and a head's parts, given
+    # by the configuration that has such layers (``LatentAttention``
+    # refuses None)
+    kv_lora_rank: Optional[int] = None
+    qk_nope_head_dim: Optional[int] = None
+    qk_rope_head_dim: Optional[int] = None
+    v_head_dim: Optional[int] = None
 
     @property
     def head_dim(self) -> int:
@@ -93,22 +117,14 @@ _BLOCK_POLICY = jax.checkpoint_policies.save_from_both_policies(
     _products_saveable,
     jax.checkpoint_policies.save_only_these_names(*RESIDUAL_NAMES))
 
-
-class GatedMLP(nn.Module):
-    """``w2(silu(w1 x) * w3 x)``."""
-
-    width: int
-    dtype: Optional[object] = None
-    kernel_init: nn.initializers.Initializer = nn.initializers.lecun_normal()
-
-    @nn.compact
-    def __call__(self, x):
-        def dense(name, features):
-            return nn.Dense(features, use_bias=False, dtype=self.dtype,
-                            kernel_init=self.kernel_init, name=name)
-        gated = nn.silu(dense("w1", self.width)(x)) \
-            * dense("w3", self.width)(x)
-        return dense("w2", x.shape[-1])(gated)
+#: What a Kimi delta attention layer keeps: the delta rule's states at its
+#: chunks' starts and its output (``kda.RESIDUAL_NAMES``), 101 MB a layer
+#: at 8 heads and 8,192 positions, and nothing else: its projections and
+#: its expert layer are recomputed. Its products' results would be 889 MB
+#: a layer there, 524 MB of them the expert layer's second window, whose
+#: ``cond`` keeps the results of the branch it did not take as zeros.
+_LINEAR_ATTENTION_POLICY = jax.checkpoint_policies.save_only_these_names(
+    *kda.RESIDUAL_NAMES)
 
 
 class DecoderBlock(nn.Module):
@@ -136,6 +152,17 @@ class DecoderBlock(nn.Module):
                 cfg.num_heads, cfg.num_kv_heads, cfg.head_dim,
                 cfg.rope_theta, cfg.norm_eps, dtype=cfg.dtype,
                 kernel_init=init, name="attention")(y, positions, self.mask)
+        elif self.layer_type == "linear_attention":
+            y = kda.KimiDeltaAttention(
+                cfg.num_heads, cfg.head_dim, cfg.conv_taps,
+                norm_eps=cfg.norm_eps, dtype=cfg.dtype, kernel_init=init,
+                name="kda")(y)
+        elif self.layer_type == "latent_attention":
+            y = LatentAttention(
+                cfg.num_heads, cfg.qk_nope_head_dim, cfg.qk_rope_head_dim,
+                cfg.v_head_dim, cfg.kv_lora_rank, cfg.rope_theta,
+                cfg.norm_eps, dtype=cfg.dtype, kernel_init=init,
+                name="attention")(y)
         else:
             raise ValueError(f"layer type {self.layer_type!r} is not one "
                              f"of {LAYER_TYPES}")
@@ -147,7 +174,9 @@ class DecoderBlock(nn.Module):
                 cfg.moe_intermediate_size, cfg.held_experts,
                 cfg.norm_topk_prob, cfg.routed_scaling_factor,
                 dtype=cfg.dtype, kernel_init=init,
-                scoring=cfg.router_scoring, name="moe")(y)
+                scoring=cfg.router_scoring, n_group=cfg.n_group,
+                topk_group=cfg.topk_group,
+                shared_width=cfg.shared_expert_size, name="moe")(y)
         else:
             y = GatedMLP(cfg.intermediate_size, cfg.dtype, init,
                          name="mlp")(y)
@@ -172,10 +201,14 @@ class HybridDecoder(nn.Module):
                          embedding_init=nn.initializers.normal(
                              cfg.initializer_range), name="embed")
         x = embed(ids)
-        block_cls = nn.remat(DecoderBlock, policy=_BLOCK_POLICY)
+        block_cls = {}                      # one remat class a policy
         for i, layer_type in enumerate(cfg.layer_types):
-            x = block_cls(cfg, layer_type, i >= cfg.num_dense_layers, mask,
-                          name=f"block_{i}")(x, positions)
+            policy = _LINEAR_ATTENTION_POLICY \
+                if layer_type == "linear_attention" else _BLOCK_POLICY
+            if policy not in block_cls:
+                block_cls[policy] = nn.remat(DecoderBlock, policy=policy)
+            x = block_cls[policy](cfg, layer_type, i >= cfg.num_dense_layers,
+                                  mask, name=f"block_{i}")(x, positions)
         if head_rows is not None:
             x = x[:, head_rows[0]:head_rows[1]]
         x = nn.RMSNorm(epsilon=cfg.norm_eps, dtype=cfg.dtype,

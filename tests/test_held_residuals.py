@@ -551,6 +551,46 @@ def test_v5e_compiles_the_decoder_cells_grouped_launches(
         for key, n in zip(("pairs", "allowed"), per_head)}
 
 
+def test_v5e_compiles_the_latent_heads_launches(one_chip, monkeypatch):
+    """The three launches of the latent attention cell's layer — 8 heads,
+    q and k of 192 (128 + 64 rotary), v of 128, 8,192 causal positions —
+    compiled for the chip at the untuned 1,024 x 1,024 blocks within the
+    16 MiB of scoped VMEM: q and k take the heads layout at 256 lanes, v,
+    the output, its cotangent and dv their own 128, and dv comes out at
+    the value's width."""
+    import functools
+
+    import jax.experimental.pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    from analytics_zoo_tpu.common import profiling
+    from analytics_zoo_tpu.ops import autotune
+    from analytics_zoo_tpu.ops.flash_attention import flash_attention
+
+    monkeypatch.setattr(pl, "pallas_call", functools.partial(
+        pl.pallas_call,
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=16 << 20)))
+    blocks = autotune.untuned_blocks(192, jnp.bfloat16)
+    assert blocks == (1024, 1024)
+    q = jax.ShapeDtypeStruct((1, 8192, 8, 192), jnp.bfloat16,
+                             sharding=one_chip)
+    v = jax.ShapeDtypeStruct((1, 8192, 8, 128), jnp.bfloat16,
+                             sharding=one_chip)
+    grad = jax.jit(jax.grad(
+        lambda q, k, v: (flash_attention(q, k, v, True, *blocks)
+                         .astype(jnp.float32) ** 2).sum(),
+        argnums=(0, 1, 2)))
+    with compiled_outside_the_cache():
+        text = grad.lower(q, q, v).compile().as_text()
+    dq, dk, dv = jax.eval_shape(grad, q, q, v)
+    assert dq.shape == dk.shape == q.shape and dv.shape == v.shape
+    assert profiling.count_kernel_calls(text) == {
+        **dict.fromkeys(profiling.KERNEL_FUNCTIONS, 0),
+        **dict.fromkeys(profiling.FLASH_KERNELS, 1)}
+    assert profiling.count_flash_layouts(text) == {
+        f"{kernel}@heads,own": 1 for kernel in profiling.FLASH_KERNELS}
+
+
 @pytest.mark.parametrize("heads", [32, 4, 1], ids=["q", "k", "one_head"])
 def test_v5e_compiles_the_norm_rotary_kernels_at_the_cells_size(one_chip,
                                                                 heads):

@@ -158,3 +158,95 @@ class TestMoEModule:
         assert h8["loss"][-1] < h1["loss"][0]
         w1 = est._state["params"]["moe"]["w1"]
         assert "expert" in str(w1.sharding.spec), w1.sharding.spec
+
+
+# ------------------------------------- grouped routing and a shared expert
+
+def _get_topk_indices(scores, bias, n_group, topk_group, k):
+    """``transformers``' ``deepseek_v3`` ``DeepseekV3MoE.get_topk_indices``
+    transcribed in numpy, a token at a time."""
+    ids = []
+    for s in scores + bias:
+        groups = s.reshape(n_group, -1)
+        group_scores = np.sort(groups, -1)[:, -2:].sum(-1)
+        kept = np.argsort(-group_scores, kind="stable")[:topk_group]
+        mask = np.zeros(n_group, bool)
+        mask[kept] = True
+        choice = np.where(np.repeat(mask, groups.shape[1]), s, 0.0)
+        ids.append(np.argsort(-choice, kind="stable")[:k])
+    return np.asarray(ids)
+
+
+@pytest.mark.parametrize("n_group,topk_group,k", [(8, 4, 8), (4, 1, 2),
+                                                  (4, 4, 3)])
+def test_grouped_routing_is_deepseek_v3s(n_group, topk_group, k):
+    """The experts ``sigmoid_top_k_routing`` picks with ``n_group`` > 1
+    are ``get_topk_indices``'s and its weights ``s / sum * scale`` (its
+    1e-6 against that code's 1e-20 within the tolerance): no expert
+    outside the ``topk_group`` best groups is chosen."""
+    import jax.numpy as jnp
+    from analytics_zoo_tpu.ops.moe import sigmoid_top_k_routing
+    rng = np.random.default_rng(n_group * 10 + k)
+    E = 64
+    logits = rng.normal(size=(96, E)).astype(np.float32)
+    bias = rng.normal(scale=0.1, size=(E,)).astype(np.float32)
+    ids, weights = sigmoid_top_k_routing(
+        jnp.asarray(logits), jnp.asarray(bias), k, scale=2.5,
+        n_group=n_group, topk_group=topk_group)
+    scores = 1 / (1 + np.exp(-logits.astype(np.float64)))
+    want = _get_topk_indices(scores, bias, n_group, topk_group, k)
+    np.testing.assert_array_equal(np.sort(np.asarray(ids), -1),
+                                  np.sort(want, -1))
+    chosen = np.take_along_axis(scores, np.asarray(ids), -1)
+    np.testing.assert_allclose(
+        np.asarray(weights), 2.5 * chosen / (chosen.sum(-1, keepdims=True)
+                                             + 1e-20), rtol=1e-5)
+    groups_used = np.unique(np.asarray(ids) // (E // n_group), axis=None)
+    assert all(len(set(row // (E // n_group))) <= topk_group
+               for row in np.asarray(ids))
+    assert len(groups_used) > 1
+
+
+def test_keeping_every_group_is_the_ungrouped_choice():
+    """``topk_group == n_group`` masks nothing: the grouped selection is
+    the one over all experts, ids and weights alike."""
+    import jax.numpy as jnp
+    from analytics_zoo_tpu.ops.moe import sigmoid_top_k_routing
+    rng = np.random.default_rng(7)
+    logits = jnp.asarray(rng.normal(size=(64, 32)), jnp.float32)
+    bias = jnp.asarray(rng.normal(scale=0.1, size=(32,)), jnp.float32)
+    whole = sigmoid_top_k_routing(logits, bias, 4, scale=2.5)
+    grouped = sigmoid_top_k_routing(logits, bias, 4, scale=2.5, n_group=4,
+                                    topk_group=4)
+    for a, b in zip(whole, grouped):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+def _moe(held, shared, experts=16, k=4, n_group=4, topk_group=2):
+    from analytics_zoo_tpu.ops.moe import DroplessMoE
+    return DroplessMoE(experts, k, 12, held, scale=2.5,
+                       n_group=n_group, topk_group=topk_group,
+                       shared_width=shared)
+
+
+def test_a_shared_expert_is_added_once_beside_the_routed_ones():
+    """``shared_width``: a gated expert under ``moe/shared`` that every
+    token goes through, unweighted: the layer with it is the layer
+    without it plus that block of the same weights."""
+    import jax
+    import jax.numpy as jnp
+    from analytics_zoo_tpu.ops.moe import GatedMLP
+    x = jnp.asarray(np.random.default_rng(0).normal(size=(2, 24, 16)),
+                    jnp.float32)
+    layer = _moe(None, 20)
+    params = layer.init(jax.random.PRNGKey(0), x)["params"]
+    assert set(params) == {"router", "experts", "shared"}
+    assert params["shared"]["w1"]["kernel"].shape == (16, 20)
+    with jax.default_matmul_precision("highest"):
+        got = layer.apply({"params": params}, x)
+        routed = _moe(None, 0).apply(
+            {"params": {k: v for k, v in params.items() if k != "shared"}},
+            x)
+        shared = GatedMLP(20).apply({"params": params["shared"]}, x)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(routed + shared),
+                               rtol=1e-5, atol=1e-6)
